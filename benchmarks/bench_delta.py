@@ -179,6 +179,8 @@ def run(scales, fracs, reps: int, k: int, out_path: Path):
 
 
 def main() -> None:
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="one scale, best-of-2 (CI serve lane)")

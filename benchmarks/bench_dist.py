@@ -85,13 +85,13 @@ def bench_app(app: str, layout, engines, sources, reps: int):
 
 
 def run(scales, batches, reps: int, k: int, out_path: Path, backend=None):
-    from repro.dist.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.dist.engine import dc_wire_bytes
     from repro.graph import build_layout, rmat
     from repro.graph.shard import shard_layout
 
     D = jax.device_count()
-    mesh = make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
     k = max(k, D)
     results = []
     for scale in scales:
@@ -150,6 +150,8 @@ def run(scales, batches, reps: int, k: int, out_path: Path, backend=None):
 
 
 def main() -> None:
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="one small scale, best-of-2 (CI dist-serve lane)")

@@ -98,6 +98,8 @@ def run(scales, backends, reps: int, k: int, out_path: Path) -> dict:
 
 
 def main() -> None:
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny scale, 1 rep (CI artifact-emission check)")

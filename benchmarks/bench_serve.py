@@ -216,6 +216,8 @@ def run(scales, backends, batches, reps: int, k: int, out_path: Path):
 
 
 def main() -> None:
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="one small scale, best-of-2 (CI serve lane)")

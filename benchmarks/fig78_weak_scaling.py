@@ -31,4 +31,6 @@ def run(scales=(10, 11, 12, 13)):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     run()

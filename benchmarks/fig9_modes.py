@@ -54,4 +54,6 @@ def run(scale=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     run()

@@ -11,12 +11,16 @@ import time
 
 
 def main() -> None:
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--scale", type=int, default=None,
                     help="rmat scale for graph benchmarks")
     ap.add_argument("--skip-scaling", action="store_true",
-                    help="skip the multi-device subprocess benchmarks")
+                    help="skip the strong-scaling benchmark (on a CPU "
+                         "host it runs each device count in a child "
+                         "process; on an accelerator, in this one)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 

@@ -115,7 +115,7 @@ def bfs_seeded_program() -> VertexProgram:
     relaxation from any *upper-bound* initialization converges to the
     same least fixpoint as the cold run (see
     :mod:`repro.serve.cache` for the full argument).  Requires x64
-    (uint64 packing) — run inside ``jax.experimental.enable_x64()``.
+    (uint64 packing) — run inside ``jax.enable_x64(True)``.
     """
     mono = M.min_with_payload()
 
@@ -163,7 +163,7 @@ def bfs_seeded_multi(layout, sources, engine: Engine = None,
     one-hot sources."""
     sources = np.atleast_1d(np.asarray(sources, dtype=np.int64))
     B, n_pad = len(sources), layout.n_pad
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         src = jnp.asarray(sources, jnp.int32)
         lanes = jnp.arange(B)
         if seeds is not None:

@@ -43,7 +43,7 @@ def sssp_with_parents(layout, source: int, mode: str = "hybrid",
                       backend=None, engine: Engine = None,
                       max_iters: int = None):
     assert layout.weighted, "needs edge weights"
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         n_pad = layout.n_pad
         dist = jnp.full((n_pad,), jnp.inf, jnp.float32).at[source].set(0.0)
         parent = jnp.full((n_pad,), -1, jnp.int32).at[source].set(source)
@@ -69,7 +69,7 @@ def sssp_parents_multi(layout, sources, engine: Engine = None,
     ``engine`` too; its bf16 wire never engages for this monoid (uint64,
     not f32), so distributed results stay exact."""
     assert layout.weighted, "needs edge weights"
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sources = np.atleast_1d(np.asarray(sources, dtype=np.int64))
         B, n_pad = len(sources), layout.n_pad
         src = jnp.asarray(sources, jnp.int32)

@@ -26,7 +26,11 @@ as a *backend* choice instead of a compile-time constant:
 Backends register per ``(platform, kernel, monoid, dtype)`` support;
 :func:`repro.backend.registry.resolve` picks one from
 ``jax.default_backend()``, honours the ``REPRO_KERNEL_BACKEND`` override,
-and falls back to ``ref`` per call when a lowering is unsupported.  Tile
+and falls back to ``ref`` per call when a lowering is unsupported (with a
+warning on a TPU, where :data:`~repro.backend.registry.TPU_DEFAULTS`
+declares each kernel's lowering: Mosaic for ``fold``, and ``ref``, with
+the reason, for every kernel whose Mosaic form cannot serve a scale-22
+graph).  Tile
 geometry (``edge_tile``/``msg_tile``/``fold_tile`` — the §3.1
 partition-sizing rule) is swept empirically by
 :mod:`repro.backend.tuning` and cached on disk.
@@ -71,9 +75,9 @@ buckets of ``q`` (the ``q=`` argument — engines pass the layout's tuned
 ``fold_q`` — then ``REPRO_FOLD_Q``, then
 :data:`repro.kernels.fold_two_level.DEFAULT_FOLD_Q`), each bucket's
 ``[q]``-sized sub-accumulator folds VMEM-resident over the message
-stream with a ``[fold_tile, q]`` one-hot, and per-tile bucket-range
-predication skips off-bucket tiles (destination-sorted streams — the
-engines' dc_bin order — do ~no redundant work).  Both regimes are
+stream with a ``[fold_tile, q]`` one-hot.  The stream is sorted by
+segment first, so the grid walks only the (message block, bucket) pairs
+that hold messages — at most blocks + buckets steps.  Both regimes are
 Pallas lowerings with identical semantics; there is no silent handoff
 to ``ref`` at any segment count — ``REPRO_KERNEL_BACKEND=ref`` is the
 only way to get the ``jax.ops`` fold.  ``autotune()`` sweeps ``fold_q``
@@ -93,9 +97,11 @@ once.  The *fused* path is registry kernel ``fused_dc``
 ``(segment buckets × edge tiles)``, gathers each edge's source value
 straight from the VMEM-resident message table, applies the optional
 edge function, and folds into the two-level ``[fold_q]``
-sub-accumulators — neither intermediate ever materializes, and the
-input-block pipeline double-buffers edge-tile fetches against the
-combine.  Its stream contract mirrors the fold's::
+sub-accumulators — neither intermediate ever materializes.  Mosaic
+cannot lower that in-kernel gather, so on a TPU ``fused_dc`` defaults
+to its ``ref`` lowering (one XLA gather + one XLA segmented fold), an
+entry of :data:`~repro.backend.registry.TPU_DEFAULTS`.  Its stream
+contract mirrors the fold's::
 
     acc, touched = fused(table, table_valid, idx, edge_valid, dst,
                          num_segments[, w=, apply_weight=])
@@ -115,8 +121,9 @@ there is deliberately NO per-call ``ref`` fallback: a missing fused
 lowering silently keeps the engine on its composed path (same backend),
 which also remains the lowering for the SC and hybrid-SC streams, for
 ``pallas-native`` requests off-TPU, and for monoids outside the Pallas
-set.  ``autotune()`` observes the fused grid's ``edge_tile × fold_q``
-cross-product through the ``fused`` timing row, and the winners ride
+set (``pallas-native`` never registers it).  ``autotune()`` observes
+the fused grid's ``edge_tile × fold_q`` cross-product through the
+``fused`` timing row, and the winners ride
 the same cached :class:`~repro.backend.tuning.TileGeometry` the
 layouts are built from.
 
@@ -137,14 +144,14 @@ degrades the scope to a ``nullcontext``.  See :mod:`repro.obs`.
 """
 from __future__ import annotations
 
-from .registry import (BACKENDS, KernelBackend, available_backends,
-                       default_backend_name, make_kernels, resolve,
-                       supported)
+from .registry import (BACKENDS, TPU_DEFAULTS, KernelBackend,
+                       available_backends, default_backend_name,
+                       make_kernels, resolve, supported)
 from .tuning import (DEFAULT_GEOMETRY, TileGeometry, autotune,
                      resolve_geometry, tuned_layout)
 
 __all__ = [
-    "BACKENDS", "KernelBackend", "available_backends",
+    "BACKENDS", "TPU_DEFAULTS", "KernelBackend", "available_backends",
     "default_backend_name", "make_kernels", "resolve", "supported",
     "DEFAULT_GEOMETRY", "TileGeometry", "autotune", "resolve_geometry",
     "tuned_layout",

@@ -11,13 +11,17 @@ TPU only).  Selection order:
 
   1. an explicit ``backend=`` argument (``Engine(..., backend=...)``),
   2. the ``REPRO_KERNEL_BACKEND`` environment variable,
-  3. the platform default: ``pallas-native`` on TPU, ``ref`` elsewhere.
+  3. the platform default: :data:`TPU_DEFAULTS` on TPU (each kernel's
+     lowering named with the reason wherever it is not Mosaic), ``ref``
+     elsewhere.
 
 If the selected backend cannot lower a particular ``(kernel, monoid,
 dtype)`` combination (e.g. a ``min_with_payload`` uint64 fold, or any
 ``pallas-native`` call on a CPU host), that *call* falls back to ``ref``
-with a warning instead of failing — the rest of the engine keeps its
-chosen backend.
+instead of failing — the rest of the engine keeps its chosen backend.
+The fallback warns whenever the backend was asked for, and always on a
+TPU: there a silent ``ref`` would hide that the declared table did not
+hold.
 
 Kernel ``fold`` (the shard_map-side blocked segmented fold,
 :mod:`repro.kernels.fold_block` below ``REPRO_FOLD_MAX_SEGMENTS``
@@ -51,6 +55,27 @@ from ..kernels import ops as kops
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 KERNELS = ("gather", "scatter", "spmv", "fold", "fused_dc")
 PALLAS_MONOIDS = ("add", "min", "max")
+#: kernels whose Pallas bodies Mosaic compiles (``fused_dc`` gathers from
+#: its table inside the kernel, which Mosaic cannot lower)
+MOSAIC_KERNELS = ("gather", "scatter", "spmv", "fold")
+
+#: The TPU table: each kernel's default lowering on a TPU, and why, where
+#: it is not the Mosaic kernel.  ``chip_smoke.py`` prints it and fails
+#: when a kernel ran on anything else.  The composed DC kernels build a
+#: ``[tile, q]`` one-hot per edge tile, which at scale 22 with k = 32
+#: (q = 131072) is out of reach; they stay selectable by name.
+TPU_DEFAULTS = {
+    "gather": ("ref", "its [tile, q] one-hot needs 131.75 MiB of the 128 "
+               "MiB VMEM at q = 131072"),
+    "scatter": ("ref", "its one-hot select gather makes q = 131072 compares "
+                "per message and takes ~170 s to compile there"),
+    "spmv": ("ref", "its one-hot select gather makes q = 131072 compares "
+             "per edge and takes ~140 s to compile there"),
+    "fold": ("pallas-native", None),
+    "fused_dc": ("ref", "Mosaic lowers no in-kernel table gather ('Only 2D "
+                 "gather is supported'); ref is one XLA gather + one XLA "
+                 "segmented fold"),
+}
 
 
 def _monoid_obj(monoid):
@@ -121,7 +146,8 @@ class PallasBackend:
         self.interpret = interpret
 
     def supports(self, platform, kernel, monoid, dtype):
-        if not self.interpret and platform != "tpu":
+        if not self.interpret and (platform != "tpu"
+                                   or kernel not in MOSAIC_KERNELS):
             return False                     # Mosaic lowering is TPU-only
         dt = jnp.dtype(dtype)
         if kernel == "spmv":
@@ -177,9 +203,10 @@ def default_backend_name(platform: Optional[str] = None,
                          kernel: Optional[str] = None) -> str:
     """Platform default, after the ``REPRO_KERNEL_BACKEND`` override.
 
-    The default is per-kernel: ``fold`` (no efficient ``jax.ops``-free
-    lowering exists outside Pallas) defaults to the interpreted Pallas
-    kernel even on CPU hosts; everything else keeps ``ref`` off-TPU.
+    The default is per-kernel: on TPU it is :data:`TPU_DEFAULTS`; off-TPU
+    ``fold`` (no efficient ``jax.ops``-free lowering exists outside
+    Pallas) defaults to the interpreted Pallas kernel, everything else to
+    ``ref``.
     """
     env = os.environ.get(ENV_VAR)
     if env:
@@ -190,7 +217,7 @@ def default_backend_name(platform: Optional[str] = None,
         return env
     platform = platform or jax.default_backend()
     if platform == "tpu":
-        return "pallas-native"
+        return TPU_DEFAULTS[kernel][0] if kernel else "pallas-native"
     return "pallas-interpret" if kernel == "fold" else "ref"
 
 
@@ -211,9 +238,10 @@ def resolve(kernel: str, monoid, dtype=None, platform: Optional[str] = None,
     mono = _monoid_obj(monoid)
     dtype = mono.dtype if dtype is None else dtype
     platform = platform or jax.default_backend()
-    # a fallback is only worth a warning when the backend was *asked for*
-    # (argument or env override); platform defaults degrade silently
-    explicit = choice is not None or bool(os.environ.get(ENV_VAR))
+    # a fallback warns when the backend was *asked for* (argument or env
+    # override), and always on a TPU, where the declared table must hold
+    explicit = (choice is not None or bool(os.environ.get(ENV_VAR))
+                or platform == "tpu")
     if choice is None:
         name = default_backend_name(platform, kernel)
         backend = BACKENDS[name]

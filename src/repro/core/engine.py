@@ -12,11 +12,12 @@ Each iteration follows paper Alg. 3/4 exactly:
          the monoid identity, which makes them exact no-ops in the fold — the
          array-semantics equivalent of the paper's "scatter the whole
          partition" correctness contract.
-       - **SC stream**: active vertices of SC-mode partitions are compacted
-         (``nonzero``) and their CSR adjacency expanded into a `(value, dst)`
-         message list.  The buffers are sized by power-of-two *budgets* so the
-         compute really is proportional to the active edge count (rounded up)
-         — the static-shape realization of the paper's theoretical efficiency.
+       - **SC stream**: the CSR adjacency of the active vertices of SC-mode
+         partitions is expanded into a `(value, dst)` message list.  The
+         buffers are sized by power-of-four *budgets* (:func:`_sc_budget`)
+         so the compute is proportional to the active edge count (rounded
+         up) — the static-shape realization of the paper's theoretical
+         efficiency.
   2. *initFrontier*: ``init_fn`` on active vertices → selective continuity.
   3. *Gather*: one segmented monoid fold per stream into the (VMEM-resident,
      on TPU) vertex tile, plus a `touched` fold; ``apply_fn`` updates touched
@@ -30,6 +31,7 @@ predication inside the Pallas kernels skips edge tiles of inactive partitions
 """
 from __future__ import annotations
 
+import functools
 import time
 import warnings
 from typing import Optional, Union
@@ -54,6 +56,22 @@ def _tree_where(mask, new, old):
 
 def _next_pow2(x: int) -> int:
     return 1 if x <= 1 else 1 << (int(x - 1).bit_length())
+
+
+#: smallest SC edge budget; budgets grow by powers of four from here
+SC_MIN_BUDGET = 1 << 12
+
+
+def _sc_budget(edges: int, cap: int) -> int:
+    """Static SC stream size for ``edges`` active edges: the next power of
+    four from :data:`SC_MIN_BUDGET`, at most ``cap``.  Every distinct
+    budget compiles its own SC program — on a TPU the fold's bucket sort
+    takes seconds to compile — so the grid is coarse; the SC stream pays
+    at most 4x the active edges for it."""
+    b = SC_MIN_BUDGET
+    while b < edges:
+        b *= 4
+    return min(b, cap)
 
 
 # The per-iteration stat records live in the obs schema
@@ -185,35 +203,6 @@ class Engine:
         L = layout
         self.k, self.q, self.n_pad = L.k, L.q, L.n_pad
 
-        # device-resident static structure
-        self.png_src = jnp.asarray(L.png_src)                  # [NM]
-        self.png_part = jnp.asarray(
-            (L.png_src.astype(np.int64) // L.q).clip(0, L.k - 1)
-            .astype(np.int32))
-        self.msg_slot = jnp.asarray(L.msg_slot)                # [NE]
-        self.edge_dst = jnp.asarray(L.edge_dst)                # [NE]
-        self.edge_w = (jnp.asarray(L.edge_w)
-                       if L.edge_w is not None else None)
-        self.tile_src_part = jnp.asarray(L.tile_src_part)
-        self.csr_indptr = jnp.asarray(L.csr_indptr)
-        self.csr_indices = jnp.asarray(L.csr_indices)
-        self.csr_w = (jnp.asarray(L.csr_w)
-                      if L.csr_w is not None else None)
-        self.deg = jnp.asarray(L.deg.astype(np.int32))         # [n_pad]
-        self.vert_part = jnp.asarray(
-            (np.arange(L.n_pad, dtype=np.int64) // L.q).astype(np.int32))
-
-        # per-partition reductions used by the host-side mode decision
-        @jax.jit
-        def _part_stats(active):
-            a32 = active.astype(jnp.int32)
-            counts = jax.ops.segment_sum(a32, self.vert_part,
-                                         num_segments=L.k)
-            ea = jax.ops.segment_sum(a32 * self.deg, self.vert_part,
-                                     num_segments=L.k)
-            return counts, ea
-        self._part_stats = _part_stats
-
         # kernel construction goes through the backend registry; each of
         # gather/scatter may fall back to 'ref' on its own when the chosen
         # backend has no lowering for this (monoid, dtype, platform)
@@ -230,127 +219,175 @@ class Engine:
         # layout's tuned fold_tile/fold_q; budgets are static per
         # compiled step, so the stream shape is known at trace time)
         self._fold = kset.fold
-        # fused DC step (registry kernel 'fused_dc'): one Pallas call
-        # replacing scatter -> slot gather -> gather fold, selected when
-        # the backend provides it and REPRO_FUSED != 0; otherwise the
-        # composed path below runs (silently — that *is* the fallback)
+        # fused DC step (registry kernel 'fused_dc'): one call replacing
+        # scatter -> slot gather -> gather fold, selected when the
+        # backend provides it and REPRO_FUSED != 0; otherwise the
+        # composed path below runs
         from ..kernels.fused_step import fused_enabled
         self._fused = kset.fused if fused_enabled() else None
         if self._fused is not None:
             self._fused.apply_weight = (
                 program.apply_weight
                 if (program.apply_weight is not None
-                    and self.edge_w is not None) else None)
-        self._step_cache = {}                      # (bv, be) -> jitted step
+                    and L.edge_w is not None) else None)
+
+        # device-resident static structure: only what the selected DC
+        # lowering and the SC stream read.  Every jitted step takes these
+        # as arguments (see _args) — jit would embed closed-over arrays
+        # in the program as constants, gigabytes at chip scale.
+        arrays = {
+            "csr_indptr": L.csr_indptr, "csr_indices": L.csr_indices,
+            "csr_w": L.csr_w, "deg": L.deg.astype(np.int32),
+            "vert_part": (np.arange(L.n_pad, dtype=np.int64)
+                          // L.q).astype(np.int32)}
+        if self._fused is None:
+            arrays.update(
+                png_src=L.png_src,
+                png_part=(L.png_src.astype(np.int64) // L.q)
+                .clip(0, L.k - 1).astype(np.int32),
+                msg_slot=L.msg_slot, edge_w=L.edge_w)
+        self.arrays = {k: None if v is None else jnp.asarray(v)
+                       for k, v in arrays.items()}
+
+        # per-partition reductions used by the host-side mode decision
+        @jax.jit
+        def _part_stats(active, vert_part, deg):
+            a32 = active.astype(jnp.int32)
+            counts = jax.ops.segment_sum(a32, vert_part, num_segments=L.k)
+            ea = jax.ops.segment_sum(a32 * deg, vert_part, num_segments=L.k)
+            return counts, ea
+        self._part_stats = lambda active: _part_stats(
+            active, self.arrays["vert_part"], self.arrays["deg"])
+        self._step_cache = {}            # SC budget / phase -> jitted fn
+        self._sc_cap = _next_pow2(max(int(L.deg.sum()), 1))
+
+    def _args(self):
+        """Every device array a jitted step reads, passed as an argument:
+        the engine's own, then each kernel's on the selected DC path."""
+        if self._fused is not None:
+            return {"engine": self.arrays, "fused": self._fused.arrays}
+        return {"engine": self.arrays,
+                "gather": self._gather_kernel.arrays,
+                "scatter": self._scatter_kernel.arrays}
 
     # ------------------------------------------------------------------
-    def _step_fn(self, bv: int, be: int):
-        """Jitted iteration for static SC budgets (bv, be), cached per
-        instance (an lru_cache on the method would pin ``self`` — layout
-        arrays included — for the process lifetime)."""
-        fn = self._step_cache.get((bv, be))
+    def _step_fn(self):
+        """Jitted DC-only iteration ``fn(state, active, dc_mask, it,
+        args)``, ``args`` being :meth:`_args`, for the batched and
+        fixed-iteration loops; cached per instance (an lru_cache on the
+        method would pin ``self`` — layout arrays included — for the
+        process lifetime).  :meth:`run` runs its phases as separate
+        programs instead (:meth:`_phase_fns`)."""
+        fn = self._step_cache.get("step")
         if fn is None:
-            fn = self._build_step(bv, be)
-            self._step_cache[(bv, be)] = fn
+            def step(state, active, dc_mask, it, args):
+                state, keep, _, acc, touched = self._dc_phase(
+                    state, active, dc_mask, it, args)
+                return self._apply_phase(state, keep, acc, touched, it)
+            fn = self._step_cache["step"] = jax.jit(step)
         return fn
 
-    def _build_step(self, bv: int, be: int):
-        """Build the jitted iteration for static SC budgets (bv, be)."""
-        prog, L, mono = self.program, self.layout, self.program.monoid
-        n_pad, k, q = self.n_pad, self.k, self.q
-        ident = mono.identity
+    def _phase_fns(self, be: int):
+        """``(dc, sc, apply)`` jitted phases of one superstep.  The DC
+        stream and the apply phase compile once per engine; only the SC
+        stream compiles per budget, so a deep frontier's many budgets do
+        not each recompile the DC stream's whole-edge-set fold."""
+        if "dc" not in self._step_cache:
+            self._step_cache["dc"] = jax.jit(self._dc_phase)
+            self._step_cache["apply"] = jax.jit(self._apply_phase)
+        key = ("sc", be)
+        if be and key not in self._step_cache:
+            self._step_cache[key] = jax.jit(
+                functools.partial(self._sc_phase, be))
+        return (self._step_cache["dc"], self._step_cache.get(key),
+                self._step_cache["apply"])
 
-        def step(state, active, dc_mask, it):
-            msgs = prog.scatter_fn(state)                     # [n_pad]
-            msgs = msgs.astype(mono.dtype)
-            msgs_p = jnp.concatenate([msgs, mono.identity_array((1,))])
-            active_p = jnp.concatenate(
-                [active, jnp.zeros((1,), jnp.bool_)])
+    def _dc_phase(self, state, active, dc_mask, it, args):
+        """Scatter, initFrontier and the DC stream (paper Alg. 2:
+        values-only messages over the pre-written dc_bin adjacency).
+        Returns ``(state, keep, msgs_p, acc, touched)`` over ``n_pad + 1``
+        slots, the last the identity sentinel."""
+        prog, mono, n_pad = self.program, self.program.monoid, self.n_pad
+        A = args["engine"]
+        vert_part = A["vert_part"]
+        msgs = prog.scatter_fn(state).astype(mono.dtype)      # [n_pad]
+        msgs_p = jnp.concatenate([msgs, mono.identity_array((1,))])
 
-            # ---- initFrontier (selective continuity) ----
-            if prog.init_fn is not None:
-                st2, keep = prog.init_fn(state, it)
-                state = _tree_where(active, st2, state)
-                keep = keep & active
-            else:
-                keep = jnp.zeros((n_pad,), jnp.bool_)
+        # ---- initFrontier (selective continuity) ----
+        if prog.init_fn is not None:
+            st2, keep = prog.init_fn(state, it)
+            state = _tree_where(active, st2, state)
+            keep = keep & active
+        else:
+            keep = jnp.zeros((n_pad,), jnp.bool_)
 
-            # ---- DC stream (paper Alg. 2: values-only messages over the
-            # pre-written dc_bin adjacency) ----
-            if self._fused is not None:
-                # fused lowering: the kernel gathers each edge's source
-                # value from msgs_p itself and folds it straight into the
-                # two-level sub-accumulators — the [NM] bin buffer and
-                # the [NE] edge-value stream never materialize
-                table_valid = jnp.concatenate(
-                    [active & dc_mask[self.vert_part],
-                     jnp.zeros((1,), jnp.bool_)])
-                acc, touched = self._fused(msgs_p, table_valid)
-            else:
-                msg_data = self._scatter_kernel(
-                    msgs, active & dc_mask[self.vert_part])
-                dc_valid = (active_p[self.png_src]
-                            & dc_mask[self.png_part])         # [NM]
-                msg_data_p = jnp.concatenate(
-                    [msg_data, mono.identity_array((1,))])
-                dc_valid_p = jnp.concatenate(
-                    [dc_valid, jnp.zeros((1,), jnp.bool_)])
-                edge_vals = msg_data_p[self.msg_slot]         # [NE]
-                edge_valid = dc_valid_p[self.msg_slot]
-                if (prog.apply_weight is not None
-                        and self.edge_w is not None):
-                    edge_vals = prog.apply_weight(edge_vals, self.edge_w)
-                    edge_vals = jnp.where(edge_valid, edge_vals, ident)
-                acc, touched = self._gather_kernel(
-                    edge_vals, edge_valid, dc_mask.astype(jnp.int32))
-                acc = jnp.concatenate([acc, mono.identity_array((1,))])
-                touched = jnp.concatenate(
-                    [touched, jnp.zeros((1,), jnp.bool_)])
+        if self._fused is not None:
+            # fused lowering: the kernel gathers each edge's source value
+            # from msgs_p itself and folds it straight into the
+            # accumulators — the [NM] bin buffer and the [NE] edge-value
+            # stream never materialize
+            table_valid = jnp.concatenate(
+                [active & dc_mask[vert_part], jnp.zeros((1,), jnp.bool_)])
+            acc, touched = self._fused(msgs_p, table_valid,
+                                       arrays=args["fused"])
+            return state, keep, msgs_p, acc, touched
+        active_p = jnp.concatenate([active, jnp.zeros((1,), jnp.bool_)])
+        msg_data = self._scatter_kernel(msgs, active & dc_mask[vert_part],
+                                        arrays=args["scatter"])
+        dc_valid = active_p[A["png_src"]] & dc_mask[A["png_part"]]  # [NM]
+        msg_data_p = jnp.concatenate([msg_data, mono.identity_array((1,))])
+        dc_valid_p = jnp.concatenate([dc_valid, jnp.zeros((1,), jnp.bool_)])
+        edge_vals = msg_data_p[A["msg_slot"]]                 # [NE]
+        edge_valid = dc_valid_p[A["msg_slot"]]
+        if prog.apply_weight is not None and A["edge_w"] is not None:
+            edge_vals = prog.apply_weight(edge_vals, A["edge_w"])
+            edge_vals = jnp.where(edge_valid, edge_vals, mono.identity)
+        acc, touched = self._gather_kernel(
+            edge_vals, edge_valid, dc_mask.astype(jnp.int32),
+            arrays=args["gather"])
+        acc = jnp.concatenate([acc, mono.identity_array((1,))])
+        touched = jnp.concatenate([touched, jnp.zeros((1,), jnp.bool_)])
+        return state, keep, msgs_p, acc, touched
 
-            # ---- SC stream (static budgets; absent when be == 0) ----
-            if be > 0:
-                sc_active = active & ~dc_mask[self.vert_part]
-                ids = jnp.nonzero(sc_active, size=bv,
-                                  fill_value=n_pad)[0]         # [bv]
-                degs = jnp.where(ids < n_pad, self.deg[jnp.minimum(ids, n_pad - 1)], 0)
-                cum = jnp.cumsum(degs)
-                total = cum[-1]
-                j = jnp.arange(be, dtype=jnp.int32)
-                vi = jnp.searchsorted(cum, j, side="right")
-                vi = jnp.minimum(vi, bv - 1)
-                starts = cum - degs
-                src_v = ids[vi]
-                e_idx = (self.csr_indptr[jnp.minimum(src_v, n_pad)]
-                         + (j - starts[vi]))
-                valid = j < total
-                e_idx = jnp.where(valid, e_idx, 0)
-                dst = jnp.where(valid, self.csr_indices[e_idx],
-                                n_pad).astype(jnp.int32)
-                vals = msgs_p[jnp.minimum(src_v, n_pad)]
-                if prog.apply_weight is not None and self.csr_w is not None:
-                    vals = prog.apply_weight(vals, self.csr_w[e_idx])
-                vals = jnp.where(valid, vals, ident)
-                acc2, touched2 = self._fold(vals, valid, dst, n_pad + 1)
-                acc = mono.combine(acc, acc2)
-                touched = touched | touched2
+    def _sc_phase(self, be, msgs_p, active, dc_mask, acc, touched, args):
+        """The SC stream under a static edge budget ``be``: the CSR rows
+        of the active vertices of SC partitions, expanded into ``be``
+        (value, dst) slots and folded into ``acc`` / ``touched``."""
+        prog, mono, n_pad = self.program, self.program.monoid, self.n_pad
+        A = args["engine"]
+        sc_active = active & ~dc_mask[A["vert_part"]]
+        degs = jnp.where(sc_active, A["deg"], 0)               # [n_pad]
+        cum = jnp.cumsum(degs)
+        total = cum[-1]
+        j = jnp.arange(be, dtype=jnp.int32)
+        # slot j belongs to the first vertex whose running degree sum
+        # passes j; slots past the total are padding
+        src_v = jnp.minimum(jnp.searchsorted(cum, j, side="right"),
+                            n_pad - 1)
+        valid = j < total
+        e_idx = jnp.where(valid, A["csr_indptr"][src_v]
+                          + (j - (cum - degs)[src_v]), 0)
+        dst = jnp.where(valid, A["csr_indices"][e_idx],
+                        n_pad).astype(jnp.int32)
+        vals = msgs_p[src_v]
+        if prog.apply_weight is not None and A["csr_w"] is not None:
+            vals = prog.apply_weight(vals, A["csr_w"][e_idx])
+        vals = jnp.where(valid, vals, mono.identity)
+        acc2, touched2 = self._fold(vals, valid, dst, n_pad + 1)
+        return mono.combine(acc, acc2), touched | touched2
 
-            acc = acc[:n_pad]
-            touched = touched[:n_pad]
-
-            # ---- Gather apply ----
-            st3, activated = prog.apply_fn(state, acc, touched, it)
-            state = _tree_where(touched, st3, state)
-            activated = activated & touched
-
-            # ---- filterFrontier on the union frontier ----
-            new_active = keep | activated
-            if prog.filter_fn is not None:
-                st4, fkeep = prog.filter_fn(state, it)
-                state = _tree_where(new_active, st4, state)
-                new_active = new_active & fkeep
-            return state, new_active
-
-        return jax.jit(step)
+    def _apply_phase(self, state, keep, acc, touched, it):
+        """Gather apply and filterFrontier on the union frontier."""
+        prog, n_pad = self.program, self.n_pad
+        acc, touched = acc[:n_pad], touched[:n_pad]
+        st3, activated = prog.apply_fn(state, acc, touched, it)
+        state = _tree_where(touched, st3, state)
+        new_active = keep | (activated & touched)
+        if prog.filter_fn is not None:
+            st4, fkeep = prog.filter_fn(state, it)
+            state = _tree_where(new_active, st4, state)
+            new_active = new_active & fkeep
+        return state, new_active
 
     # ------------------------------------------------------------------
     def run(self, state=None, frontier=None, max_iters: int = 10_000,
@@ -432,13 +469,20 @@ class Engine:
             else:
                 dc_mask = self.cost.choose_dc(ea, has_active)
             sc_sel = (~dc_mask) & has_active
-            bv = _next_pow2(int(counts[sc_sel].sum())) if sc_sel.any() else 0
-            be = _next_pow2(int(ea[sc_sel].sum())) if sc_sel.any() else 0
-            if sc_sel.any() and be == 0:
-                be, bv = 1, max(bv, 1)      # active vertices with degree 0
+            # SC edge budget: the active edges of SC partitions, rounded
+            # up to the budget grid (0: no SC stream this step)
+            be = (_sc_budget(int(ea[sc_sel].sum()), self._sc_cap)
+                  if sc_sel.any() else 0)
             t0 = time.perf_counter()
-            state, active = self._step_fn(bv, be)(
-                state, active, jnp.asarray(dc_mask), jnp.int32(it))
+            dc_fn, sc_fn, apply_fn = self._phase_fns(be)
+            args, mask, it32 = self._args(), jnp.asarray(dc_mask), \
+                jnp.int32(it)
+            state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,
+                                                      it32, args)
+            if be:
+                acc, touched = sc_fn(msgs_p, active, mask, acc, touched,
+                                     args)
+            state, active = apply_fn(state, keep, acc, touched, it32)
             jax.block_until_ready(active)
             if collect_stats:
                 b = self.cost.bytes_for(dc_mask, ea, has_active)
@@ -468,20 +512,20 @@ class Engine:
         fn = self._step_cache.get(key)
         if fn is not None:
             return fn
-        step = self._step_fn(0, 0)        # DC-only step (no SC budgets)
+        step = self._step_fn()
         k, q = self.k, self.q
 
-        def one(state, active, it):
+        def one(state, active, it, args):
             # per-lane gPartList: partitions with >=1 active vertex run DC,
             # empty partitions are excluded entirely (same decision `run`
             # makes in mode='dc', but computed in-graph so it can vmap)
             counts = active.astype(jnp.int32).reshape(k, q).sum(axis=1)
-            return step(state, active, counts > 0, it)
+            return step(state, active, counts > 0, it, args)
 
-        def batched(states, active, it):
+        def batched(states, active, it, args):
             done = ~active.any(axis=1)                         # [B]
             new_states, new_active = jax.vmap(
-                one, in_axes=(0, 0, None))(states, active, it)
+                one, in_axes=(0, 0, None, None))(states, active, it, args)
             # freeze converged lanes: an empty frontier is already a
             # no-op for every phase (all updates are masked on active /
             # touched), but the explicit freeze makes the contract
@@ -491,7 +535,10 @@ class Engine:
             new_active = new_active & keep[:, None]
             return new_states, new_active
 
-        fn = jax.jit(batched)
+        jitted = jax.jit(batched)
+
+        def fn(states, active, it):
+            return jitted(states, active, it, self._args())
         self._step_cache[key] = fn
         return fn
 
@@ -529,20 +576,21 @@ class Engine:
         This is the PageRank-style path: all partitions scatter DC every
         iteration (paper §6.2.2: "PageRank always uses DC mode").
         """
-        step = self._step_fn(0, 0)
+        step = self._step_fn()
         dc_mask = jnp.ones((self.k,), jnp.bool_)
 
         @jax.jit
-        def loop(state, active):
+        def loop(state, active, args):
             def body(it, carry):
                 st, act = carry
-                return step(st, act, dc_mask, it)
+                return step(st, act, dc_mask, it, args)
             return jax.lax.fori_loop(0, iters, body, (state, active))
 
         if not obs.enabled():
-            return loop(state, jnp.asarray(frontier, jnp.bool_))
+            return loop(state, jnp.asarray(frontier, jnp.bool_),
+                        self._args())
         t0 = time.perf_counter()
-        out = loop(state, jnp.asarray(frontier, jnp.bool_))
+        out = loop(state, jnp.asarray(frontier, jnp.bool_), self._args())
         jax.block_until_ready(out)
         obs.event("fused_run", engine="core", program=self.program.name,
                   iters=iters, wall_s=time.perf_counter() - t0)

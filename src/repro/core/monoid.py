@@ -66,7 +66,7 @@ def or_() -> Monoid:
 def min_with_payload() -> Monoid:
     """min over packed uint64 = (f32-key bits << 32) | uint32 payload.
 
-    Requires x64 (``jax.experimental.enable_x64()`` or JAX_ENABLE_X64);
+    Requires x64 (``jax.enable_x64(True)`` or JAX_ENABLE_X64);
     without it JAX silently truncates uint64 to uint32."""
     return Monoid("min_with_payload", jnp.dtype(jnp.uint64),
                   np.uint64(np.iinfo(np.uint64).max),

@@ -1,4 +1,4 @@
-"""Multi-device machinery: sharding rules, JAX-compat shims, PPM engine.
+"""Multi-device machinery: sharding rules and the PPM engine.
 
 GPOP executes graph algorithms as partition-parallel BSP supersteps
 (paper §3; DESIGN.md §2), and each superstep maps onto the mesh like so:
@@ -19,16 +19,13 @@ group: ``pod``/``data`` axes carry batch-parallel + FSDP work and
 ``model`` carries tensor-parallel shards (``sharding.default_rules``).
 
 Modules:
-  compat    version shims (AxisType, AbstractMesh, make_mesh, shard_map)
-            installed into ``jax``/``jax.sharding`` on import;
   sharding  logical-axis -> mesh-axis rules, spec construction, activation
             constraints, whole-tree param shardings;
   engine    ``DistEngine`` — the multi-device PPM engine itself.
 """
-from . import compat  # noqa: F401  (installs the version shims)
 from .sharding import (batch_spec, constrain, default_rules, graph_spec,
                        param_shardings, set_activation_mesh, spec_for)
 
-__all__ = ["compat", "batch_spec", "constrain", "default_rules",
+__all__ = ["batch_spec", "constrain", "default_rules",
            "graph_spec", "param_shardings", "set_activation_mesh",
            "spec_for"]

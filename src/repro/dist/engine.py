@@ -27,12 +27,12 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..backend import registry as kregistry
 from ..core.engine import _run_batched_loop, _tree_where
 from ..core.program import VertexProgram
-from .compat import NamedSharding, PartitionSpec as P, shard_map
 from .sharding import graph_spec
 
 
@@ -561,18 +561,20 @@ class DistEngine:
         fused, self.fused_backend_name = _resolve_fused(
             program, backend, tile=getattr(sharded, "fold_tile", None),
             q=getattr(sharded, "fold_q", None))
+        self.fold, self.fused = fold, fused
         meta = dict(nv=sharded.nv, S=sharded.S, D=sharded.D,
                     cap_in=sharded.cap_in, cap_pair=sharded.cap_pair,
                     kpd=sharded.kpd, weighted=sharded.weighted)
         self.meta = meta
         spec_arr = graph_spec(mesh)
         shard = NamedSharding(mesh, spec_arr)
+        # host arrays go straight to their shards (a jnp.asarray first
+        # would stage every whole array on device 0)
         self.arrays = jax.tree_util.tree_map(
-            lambda a: jax.device_put(jnp.asarray(a), shard),
-            self.sl.arrays())
+            lambda a: jax.device_put(a, shard), self.sl.arrays())
         deg = np.zeros(sharded.D * sharded.nv, np.int32)
         deg[:len(sharded.deg)] = sharded.deg
-        self.deg = jax.device_put(jnp.asarray(deg), shard)
+        self.deg = jax.device_put(deg, shard)
 
         dc_body = build_dc_step(program, meta, self.axes, fold=fold,
                                 fused=fused, wire_bf16=wire_bf16,
@@ -582,20 +584,20 @@ class DistEngine:
 
         def wrap(body):
             def fn(state, active, arrays, it):
-                return shard_map(
+                return jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(spec_arr, spec_arr, spec_arr, P()),
-                    out_specs=(spec_arr, spec_arr),
+                    out_specs=(spec_arr, spec_arr), check_vma=False,
                 )(state, active, arrays, it)
             return jax.jit(fn)
         self._dc = wrap(dc_body)
         self._sc = wrap(sc_body)
 
         def hy_fn(state, active, arrays, it, dc_mask):
-            return shard_map(
+            return jax.shard_map(
                 hy_body, mesh=mesh,
                 in_specs=(spec_arr, spec_arr, spec_arr, P(), spec_arr),
-                out_specs=(spec_arr, spec_arr),
+                out_specs=(spec_arr, spec_arr), check_vma=False,
             )(state, active, arrays, it, dc_mask)
         self._hy = jax.jit(hy_fn)
 
@@ -612,10 +614,10 @@ class DistEngine:
 
         def dcb_fn(states, active, arrays, it):
             done = ~active.any(axis=1)                         # [B]
-            new_states, new_active = shard_map(
+            new_states, new_active = jax.shard_map(
                 dcb_body, mesh=mesh,
                 in_specs=(bspec, bspec, spec_arr, P()),
-                out_specs=(bspec, bspec),
+                out_specs=(bspec, bspec), check_vma=False,
             )(states, active, arrays, it)
             # freeze converged lanes (cf. Engine._batched_step_fn): an
             # empty frontier is already a no-op for every phase, the
@@ -638,15 +640,17 @@ class DistEngine:
         # Float never wraps, and its ~1e-7 relative rounding cannot flip
         # a float threshold comparison
         fdt = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+        # the degrees ride as an argument: jit would embed a closed-over
+        # array in the program as a constant
         deg_f = self.deg.astype(fdt)
 
         @jax.jit
-        def _part_stats(active):
+        def _part_stats(active, deg_f):
             a32 = active.astype(jnp.int32)
             counts = a32.reshape(k_glob, q).sum(axis=1)
             ea = (active.astype(fdt) * deg_f).reshape(k_glob, q).sum(axis=1)
             return counts, ea
-        self._pstats = _part_stats
+        self._pstats = lambda active: _part_stats(active, deg_f)
         from ..core.cost import CostModel
         dc_cost = (sharded.part_msgs * 4 + k_glob * 4
                    + 2 * sharded.part_msgs * 4 + sharded.part_edges * 4)
@@ -659,12 +663,12 @@ class DistEngine:
                                   bw_ratio=bw_ratio)
 
         @jax.jit
-        def _stats(active):
+        def _stats(active, deg_f):
             # vertex count fits int32 (n < 2**31); the edge-degree sum
             # does not — accumulate it in float (see fdt above)
             return (jnp.sum(active.astype(jnp.int32)),
                     jnp.sum(active.astype(fdt) * deg_f))
-        self._stats = _stats
+        self._stats = lambda active: _stats(active, deg_f)
 
         # aggregated Eq. 1 threshold: average DC cost per (all) edge vs the
         # per-active-edge SC cost

@@ -21,7 +21,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from .compat import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # Activation-constraint mesh, a one-element box so model code can read the
 # *current* mesh at trace time (``_ACT_MESH[0]``).

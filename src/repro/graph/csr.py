@@ -73,20 +73,22 @@ def from_edges(src, dst, n: Optional[int] = None, weights=None,
     dst = np.asarray(dst, dtype=np.int64)
     if n is None:
         n = int(max(src.max(initial=-1), dst.max(initial=-1)) + 1)
-    if dedup and len(src):
-        key = src * n + dst
-        _, keep = np.unique(key, return_index=True)
-        src, dst = src[keep], dst[keep]
-        if weights is not None:
-            weights = np.asarray(weights)[keep]
-    order = np.lexsort((dst, src))
-    src, dst = src[order], dst[order]
-    indptr = np.zeros(n + 1, dtype=np.int64)
-    np.add.at(indptr, src + 1, 1)
-    indptr = np.cumsum(indptr)
+    # one int64 key per edge orders by (src, dst): a single sort replaces
+    # a lexsort, and dedup comes from the same sort
+    key = src * n + dst
     w = None
-    if weights is not None:
+    if weights is None:
+        key = np.unique(key) if dedup else np.sort(key)
+    else:
+        if dedup:
+            key, order = np.unique(key, return_index=True)
+        else:
+            order = np.argsort(key, kind="stable")
+            key = key[order]
         w = np.asarray(weights, dtype=np.float32)[order]
+    src, dst = np.divmod(key, max(n, 1))
+    indptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(np.bincount(src, minlength=n), out=indptr[1:])
     return Graph(indptr, dst.astype(np.int32), w)
 
 

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .segment_combine import _identity_val
+from .segment_combine import _identity_val, from_signed, to_signed
 
 DEFAULT_FOLD_TILE = 256
 ENV_FOLD_TILE = "REPRO_FOLD_TILE"
@@ -53,6 +53,7 @@ ENV_FOLD_TILE = "REPRO_FOLD_TILE"
 DEFAULT_FOLD_MAX_SEGMENTS = 4096
 ENV_FOLD_MAX_SEGMENTS = "REPRO_FOLD_MAX_SEGMENTS"
 _LANES = 128
+_BLOCK = 1024
 
 
 def default_fold_tile() -> int:
@@ -71,30 +72,32 @@ def max_fold_segments() -> int:
     return int(env) if env else DEFAULT_FOLD_MAX_SEGMENTS
 
 
-def _kernel(vals_ref, valid_ref, ids_ref,              # VMEM in (one block)
-            acc_ref, touched_ref,                      # VMEM out (resident)
-            *, monoid: str, nsp: int):
-    t = pl.program_id(0)
+def dma_block(tile: int, n: int) -> int:
+    """Messages per grid step for an ``n``-message stream: whole fold
+    tiles, at least ``_BLOCK`` — or the whole stream when it is shorter.
+
+    XLA lays a large 1-D array out in 1024-element tiles and Mosaic
+    refuses a block that splits one, so the kernels fetch ``dma_block``
+    messages per step and run the one-hot combine per ``tile`` of them.
+    A block spanning the whole (padded) stream is always legal."""
+    return min(tile * max(1, -(-_BLOCK // tile)),
+               tile * max(1, -(-n // tile)))
+
+
+def combine_tile(acc_ref, touched_ref, vals, onehot, *, monoid: str):
+    """Fold one message tile into the resident ``[1, width]`` accumulator.
+
+    ``onehot`` is the ``[T, width]`` message-to-column match.  Every
+    monoid folds as a masked VPU reduce, NOT a one-hot MXU matmul: inf*0
+    = NaN in a matmul would pollute every other segment's lane the moment
+    one message diverges, where the ref fold confines it to its segment
+    (and the f32 round trip truncates int32 payloads above 2**24)."""
     ident = _identity_val(monoid, acc_ref.dtype)
-
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, ident)
-        touched_ref[...] = jnp.zeros_like(touched_ref)
-
-    vals = vals_ref[...]                                # [T]
-    valid = valid_ref[...] > 0                          # [T]
-    ids = ids_ref[...]                                  # [T]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], nsp), 1)
-    onehot = (ids[:, None] == cols) & valid[:, None]    # [T, nsp]
     if monoid == "add":
-        # masked VPU sum, NOT a one-hot MXU matmul: inf*0 = NaN in a
-        # matmul would pollute every other segment's lane the moment one
-        # message diverges, where the ref fold confines it to its segment
         masked = jnp.where(onehot, vals[:, None],
                            jnp.zeros((), acc_ref.dtype))
-        contrib = jnp.sum(masked, axis=0)
-        acc_ref[...] = acc_ref[...] + contrib.astype(acc_ref.dtype)[None, :]
+        acc_ref[...] = acc_ref[...] \
+            + jnp.sum(masked, axis=0).astype(acc_ref.dtype)[None, :]
     elif monoid == "min":
         masked = jnp.where(onehot, vals[:, None], ident)
         acc_ref[...] = jnp.minimum(acc_ref[...],
@@ -104,8 +107,27 @@ def _kernel(vals_ref, valid_ref, ids_ref,              # VMEM in (one block)
         acc_ref[...] = jnp.maximum(acc_ref[...],
                                    jnp.max(masked, axis=0)[None, :])
     touched_ref[...] = jnp.maximum(
-        touched_ref[...],
-        jnp.max(onehot.astype(jnp.int32), axis=0)[None, :])
+        touched_ref[...], jnp.max(onehot.astype(jnp.int32), axis=0)[None, :])
+
+
+def _kernel(vals_ref, ids_ref,                         # VMEM in (one block)
+            acc_ref, touched_ref,                      # VMEM out (resident)
+            *, monoid: str, nsp: int, tile: int):
+    t = pl.program_id(0)
+
+    @pl.when(t == 0)
+    def _init():
+        acc_ref[...] = jnp.full_like(acc_ref,
+                                     _identity_val(monoid, acc_ref.dtype))
+        touched_ref[...] = jnp.zeros_like(touched_ref)
+
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, nsp), 1)
+    for c in range(vals_ref.shape[0] // tile):
+        part = pl.ds(c * tile, tile)
+        # validity is folded into the ids (-1 matches no column): Mosaic
+        # cannot relayout a bool [T] vector into a [T, 1] column
+        combine_tile(acc_ref, touched_ref, vals_ref[part],
+                     ids_ref[part][:, None] == cols, monoid=monoid)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "monoid",
@@ -122,28 +144,33 @@ def blocked_segment_fold(vals, valid, ids, num_segments: int, *,
              ``[0, num_segments)`` contribute nothing (the engines point
              sentinel slots at the overflow bin ``num_segments - 1``).
       num_segments: static segment count (engines pass ``nv + 1``).
-      fold_tile: messages per grid step (the VMEM block size).
+      fold_tile: messages per one-hot combine (a grid step fetches
+             :func:`dma_block` of them).
     Returns:
       acc [num_segments] monoid fold, touched [num_segments] bool.
     """
     n = vals.shape[0]
-    nt = max(1, -(-n // fold_tile))
-    n_pad = nt * fold_tile
+    blk = dma_block(fold_tile, n)
+    nt = max(1, -(-n // blk))
+    n_pad = nt * blk
     nsp = -(-num_segments // _LANES) * _LANES
+    dtype = vals.dtype
+    vals = to_signed(vals, monoid)
     ident = _identity_val(monoid, vals.dtype)
+    ids = ids.astype(jnp.int32)
+    ok = (valid > 0) & (ids >= 0) & (ids < num_segments)
+    ids = jnp.pad(jnp.where(ok, ids, -1), (0, n_pad - n), constant_values=-1)
     vals = jnp.pad(vals, (0, n_pad - n), constant_values=ident)
-    valid = jnp.pad(valid.astype(jnp.int32), (0, n_pad - n))
-    ids = jnp.pad(ids.astype(jnp.int32), (0, n_pad - n))
     acc, touched = pl.pallas_call(
-        functools.partial(_kernel, monoid=monoid, nsp=nsp),
+        functools.partial(_kernel, monoid=monoid, nsp=nsp, tile=fold_tile),
         grid=(nt,),
-        in_specs=[pl.BlockSpec((fold_tile,), lambda t: (t,)),
-                  pl.BlockSpec((fold_tile,), lambda t: (t,)),
-                  pl.BlockSpec((fold_tile,), lambda t: (t,))],
+        in_specs=[pl.BlockSpec((blk,), lambda t: (t,)),
+                  pl.BlockSpec((blk,), lambda t: (t,))],
         out_specs=[pl.BlockSpec((1, nsp), lambda t: (0, 0)),
                    pl.BlockSpec((1, nsp), lambda t: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, nsp), vals.dtype),
                    jax.ShapeDtypeStruct((1, nsp), jnp.int32)],
         interpret=interpret,
-    )(vals, valid, ids)
-    return acc[0, :num_segments], touched[0, :num_segments] > 0
+    )(vals, ids)
+    return (from_signed(acc[0, :num_segments], monoid, dtype),
+            touched[0, :num_segments] > 0)

@@ -6,34 +6,30 @@ VMEM footprint grows linearly in the segment count and it stops being
 lowerable past ``REPRO_FOLD_MAX_SEGMENTS``.  This kernel lifts that cap by
 hierarchical accumulation ("Making Caches Work for Graph Analytics",
 Zhang et al.): segment ids are split two-level into a *coarse bucket*
-``id // q`` and an *offset within the bucket* ``id % q``, and the fold
-runs over a ``(num_buckets, num_tiles)`` grid —
+``id // q`` and an *offset within the bucket* ``id % q``:
 
-  * the inner grid dimension streams ``fold_tile``-sized message blocks,
-    exactly like the flat fold;
-  * the outer dimension walks the ``nb = ceil(num_segments / q)`` coarse
-    buckets; bucket ``b``'s ``[q]``-sized sub-accumulator is the revisited
-    output block, VMEM-resident across the whole inner sweep;
-  * the one-hot combine is ``[fold_tile, q]`` — sized by the *bucket*
-    width, not the segment count, so VMEM stays bounded for any
-    ``num_segments``;
-  * a per-tile bucket range ``[bmin, bmax]`` (computed from the valid ids
-    before the ``pallas_call``) predicates each grid step: a tile whose
-    messages cannot land in bucket ``b`` is skipped.  The engines' DC
-    streams are destination-major *sorted* (the pre-written ``dc_bin``
-    reads bin columns in order), so each tile covers O(1) buckets and the
-    effective work collapses from ``nb x nt`` to ``~nb + nt`` body runs —
-    the paper's cache- and work-efficiency, transposed to buckets.
+  * the stream is first sorted by segment id (invalid slots last), so
+    every message block (:func:`~repro.kernels.fold_block.dma_block`
+    messages, one grid step) covers a contiguous bucket
+    range and the (tile, bucket) pairs that hold any message number at
+    most ``num_tiles + num_buckets``;
+  * the grid is one axis over those pairs, in bucket order: the tile and
+    the bucket of each step, whether it starts its bucket and whether it
+    holds work come from scalar-prefetched (SMEM) arrays;
+  * bucket ``b``'s ``[q]``-sized sub-accumulator is the output block,
+    VMEM-resident over the consecutive steps of its bucket, and the
+    one-hot combine is ``[fold_tile, q]`` — sized by the *bucket* width,
+    not the segment count, so VMEM stays bounded for any
+    ``num_segments``.  A bucket no message reaches gets one step that
+    only initializes it.
 
 Stage 2 — combining the per-bucket partials into the flat
-``[num_segments]`` output — is where the hierarchy pays off: buckets tile
-the segment space disjointly, so the combine is a relayout of the
-``[nb, q]`` partials, not another reduction pass.  No
-``jax.ops.segment_*``, no scatter anywhere in the lowering, so the kernel
-traces inside ``shard_map`` bodies just like the flat fold (same registry
-contract, same monoids, same masked-VPU combine — the MXU one-hot matmul
-stays off the table for the NaN/int-truncation reasons documented in
-:mod:`repro.kernels.fold_block`).
+``[num_segments]`` output — is a relayout, since buckets tile the segment
+space disjointly.  No ``jax.ops.segment_*``, no scatter anywhere in the
+lowering, so the kernel traces inside ``shard_map`` bodies just like the
+flat fold (same registry contract, same monoids, same masked-VPU combine
+— the MXU one-hot matmul stays off the table for the NaN/int-truncation
+reasons documented in :mod:`repro.kernels.fold_block`).
 
 ``q`` need not divide the segment count, be a power of two, or be
 lane-aligned (TPU-native callers should keep it a multiple of 128); the
@@ -47,8 +43,10 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .segment_combine import _identity_val
+from .fold_block import combine_tile, dma_block
+from .segment_combine import _identity_val, from_signed, to_signed
 
 # Bucket width of the two-level fold: how many consecutive segments one
 # VMEM-resident sub-accumulator covers.  256 keeps the [fold_tile, q]
@@ -57,6 +55,10 @@ from .segment_combine import _identity_val
 # jointly with fold_tile (Eq. 1's cost model predicts the interaction).
 DEFAULT_FOLD_Q = 256
 ENV_FOLD_Q = "REPRO_FOLD_Q"
+
+#: SMEM bytes the scalar-prefetched work list (four int32 per grid step)
+#: may take of a v5e core's 1 MiB
+WORK_LIST_SMEM = 768 * 1024
 
 
 def default_fold_q() -> int:
@@ -67,49 +69,60 @@ def default_fold_q() -> int:
     return int(env) if env else DEFAULT_FOLD_Q
 
 
-def _kernel(vals_ref, valid_ref, ids_ref,              # VMEM in (one tile)
-            bmin_ref, bmax_ref,                        # VMEM in (per tile)
-            acc_ref, touched_ref,                      # VMEM out (resident)
-            *, monoid: str, q: int):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    ident = _identity_val(monoid, acc_ref.dtype)
+def _kernel(wb_ref, wfirst_ref, wact_ref, wt_ref,      # SMEM work list
+            vals_ref, ids_ref,                         # VMEM in (one block)
+            acc_ref, touched_ref,                      # VMEM out (bucket)
+            *, monoid: str, q: int, tile: int):
+    w = pl.program_id(0)
 
-    @pl.when(t == 0)
+    @pl.when(wfirst_ref[w] > 0)
     def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, ident)
+        acc_ref[...] = jnp.full_like(acc_ref,
+                                     _identity_val(monoid, acc_ref.dtype))
         touched_ref[...] = jnp.zeros_like(touched_ref)
 
-    # bucket-range predication: tiles with no message in bucket b are
-    # skipped — for the engines' destination-sorted streams this is the
-    # 2-level active list of the paper, applied to coarse buckets
-    @pl.when((bmin_ref[0] <= b) & (b <= bmax_ref[0]))
+    @pl.when(wact_ref[w] > 0)
     def _body():
-        vals = vals_ref[...]                            # [T]
-        valid = valid_ref[...] > 0                      # [T]
-        ids = ids_ref[...]                              # [T]
-        bucket = ids // q
-        off = ids - bucket * q
-        cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], q), 1)
-        onehot = ((off[:, None] == cols) & (bucket == b)[:, None]
-                  & valid[:, None])                     # [T, q]
-        if monoid == "add":
-            masked = jnp.where(onehot, vals[:, None],
-                               jnp.zeros((), acc_ref.dtype))
-            contrib = jnp.sum(masked, axis=0)
-            acc_ref[...] = acc_ref[...] \
-                + contrib.astype(acc_ref.dtype)[None, :]
-        elif monoid == "min":
-            masked = jnp.where(onehot, vals[:, None], ident)
-            acc_ref[...] = jnp.minimum(acc_ref[...],
-                                       jnp.min(masked, axis=0)[None, :])
-        elif monoid == "max":
-            masked = jnp.where(onehot, vals[:, None], ident)
-            acc_ref[...] = jnp.maximum(acc_ref[...],
-                                       jnp.max(masked, axis=0)[None, :])
-        touched_ref[...] = jnp.maximum(
-            touched_ref[...],
-            jnp.max(onehot.astype(jnp.int32), axis=0)[None, :])
+        base = wb_ref[w] * q
+        cols = jax.lax.broadcasted_iota(jnp.int32, (tile, q), 1)
+        for c in range(vals_ref.shape[0] // tile):
+            part = pl.ds(c * tile, tile)
+            # offset inside bucket b; ids of other buckets and the
+            # invalid sentinel fall outside [0, q) and match no column
+            off = ids_ref[part] - base
+            combine_tile(acc_ref, touched_ref, vals_ref[part],
+                         off[:, None] == cols, monoid=monoid)
+
+
+def bucket_work_list(ids_sorted, num_buckets: int, q: int, tile: int):
+    """Static-length work list of a bucket-sorted, tile-padded id stream.
+
+    Returns int32 arrays ``(bucket, first, active, tile)`` of length
+    ``num_tiles + num_buckets``, in bucket order: one entry per (tile,
+    bucket) pair that holds messages, one init-only entry per bucket no
+    message reaches, and inactive padding at the end (parked on the last
+    bucket, so no output block is ever revisited out of order).  Ids
+    ``>= num_buckets * q`` are the invalid sentinel."""
+    nb = num_buckets
+    nt = ids_sorted.shape[0] // tile
+    kb = ids_sorted.reshape(nt, tile) // q
+    bmin = kb[:, 0]                       # sorted: first and last of a tile
+    bmax = jnp.minimum(kb[:, -1], nb - 1)  # an all-invalid tile: bmin = nb
+    b = jnp.arange(nb, dtype=jnp.int32)
+    tlo = jnp.searchsorted(bmax, b, side="left").astype(jnp.int32)
+    cnt = jnp.maximum(
+        jnp.searchsorted(bmin, b, side="right").astype(jnp.int32) - tlo, 0)
+    items = jnp.maximum(cnt, 1)
+    start = jnp.cumsum(items) - items
+    w = jnp.arange(nt + nb, dtype=jnp.int32)
+    wb = jnp.clip(jnp.searchsorted(start, w, side="right") - 1,
+                  0, nb - 1).astype(jnp.int32)
+    j = w - start[wb]
+    # inactive steps keep the previous tile index, so no block is fetched
+    wt = jnp.clip(tlo[wb] + jnp.minimum(j, jnp.maximum(cnt[wb] - 1, 0)),
+                  0, nt - 1)
+    return (wb, (j == 0).astype(jnp.int32),
+            (j < cnt[wb]).astype(jnp.int32), wt.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "monoid",
@@ -131,45 +144,72 @@ def two_level_segment_fold(vals, valid, ids, num_segments: int, *,
              sentinel slots at the overflow bin ``num_segments - 1``).
       num_segments: static segment count (engines pass ``nv + 1``) — any
              size; VMEM use is bounded by ``fold_tile x fold_q``.
-      fold_tile: messages per grid step.
+      fold_tile: messages per one-hot combine.
       fold_q: segments per coarse bucket (the sub-accumulator width).
     Returns:
       acc [num_segments] monoid fold, touched [num_segments] bool.
     """
     n = vals.shape[0]
     q = int(fold_q)
-    nt = max(1, -(-n // fold_tile))
-    n_pad = nt * fold_tile
+    blk = dma_block(fold_tile, n)
     nb = max(1, -(-num_segments // q))
+    # the work list lives in SMEM: at most ``chunk`` blocks per kernel
+    # call, longer streams fold chunk by chunk
+    chunk = WORK_LIST_SMEM // 16 - nb
+    if chunk < 1:
+        raise ValueError(f"{nb} buckets of {q} segments overflow the SMEM "
+                         "work list; raise fold_q")
+    nt = max(1, -(-n // blk))
+    nc = -(-nt // chunk)
+    nt = -(-nt // nc)                     # blocks per chunk
+    n_pad = nc * nt * blk
+    dtype = vals.dtype
+    vals = to_signed(vals, monoid)
     ident = _identity_val(monoid, vals.dtype)
+    sentinel = nb * q                     # sorts last, lands in no bucket
+    ids = ids.astype(jnp.int32)
+    ok = (valid > 0) & (ids >= 0) & (ids < num_segments)
+    ids = jnp.pad(jnp.where(ok, ids, sentinel), (0, n_pad - n),
+                  constant_values=sentinel)
     vals = jnp.pad(vals, (0, n_pad - n), constant_values=ident)
-    valid = jnp.pad(valid.astype(jnp.int32), (0, n_pad - n))
-    ids = jnp.pad(ids.astype(jnp.int32), (0, n_pad - n))
+    # unstable: the fold does not care about order within a segment, and
+    # a stable two-operand sort compiles ~5x slower on a TPU (90 s at
+    # 2^25 messages, against 18 s)
+    ids, vals = jax.lax.sort((ids, vals), num_keys=1, is_stable=False)
 
-    # per-tile coarse-bucket ranges over the *valid* slots only: an
-    # all-invalid tile gets the empty range [nb, -1] and is never entered
-    vb = valid > 0
-    bt = jnp.where(vb, ids // q, -1)
-    bmax = jnp.clip(jnp.max(bt.reshape(nt, fold_tile), axis=1), -1, nb - 1)
-    bmin = jnp.clip(
-        jnp.min(jnp.where(vb, ids // q, nb).reshape(nt, fold_tile), axis=1),
-        0, nb)
+    tile_spec = pl.BlockSpec((blk,), lambda w, wb, wf, wa, wt: (wt[w],))
+    acc_spec = pl.BlockSpec((None, 1, q),
+                            lambda w, wb, wf, wa, wt: (wb[w], 0, 0))
+    fold = pl.pallas_call(
+        functools.partial(_kernel, monoid=monoid, q=q, tile=fold_tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(nt + nb,),
+            in_specs=[tile_spec, tile_spec],
+            out_specs=[acc_spec, acc_spec]),
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, q), vals.dtype),
+                   jax.ShapeDtypeStruct((nb, 1, q), jnp.int32)],
+        interpret=interpret)
 
-    acc, touched = pl.pallas_call(
-        functools.partial(_kernel, monoid=monoid, q=q),
-        grid=(nb, nt),
-        in_specs=[pl.BlockSpec((fold_tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((fold_tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((fold_tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((1,), lambda b, t: (t,)),
-                  pl.BlockSpec((1,), lambda b, t: (t,))],
-        out_specs=[pl.BlockSpec((1, q), lambda b, t: (b, 0)),
-                   pl.BlockSpec((1, q), lambda b, t: (b, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, q), vals.dtype),
-                   jax.ShapeDtypeStruct((nb, q), jnp.int32)],
-        interpret=interpret,
-    )(vals, valid, ids, bmin.astype(jnp.int32), bmax.astype(jnp.int32))
+    def fold_chunk(ids, vals):
+        return fold(*bucket_work_list(ids, nb, q, blk), vals, ids)
+
+    if nc == 1:
+        acc, touched = fold_chunk(ids, vals)
+    else:
+        # each chunk of the sorted stream is sorted: fold one after
+        # another and combine the partials
+        combine = {"add": jnp.add, "min": jnp.minimum,
+                   "max": jnp.maximum}[monoid]
+
+        def step(carry, chunk_ids_vals):
+            a, t = fold_chunk(*chunk_ids_vals)
+            return (combine(carry[0], a), jnp.maximum(carry[1], t)), None
+
+        init = (jnp.full((nb, 1, q), ident, vals.dtype),
+                jnp.zeros((nb, 1, q), jnp.int32))
+        (acc, touched), _ = jax.lax.scan(
+            step, init, (ids.reshape(nc, -1), vals.reshape(nc, -1)))
     # stage 2: buckets tile the segment space disjointly, so combining the
     # per-bucket partials into the flat output is a relayout, not a fold
-    return (acc.reshape(-1)[:num_segments],
+    return (from_signed(acc.reshape(-1)[:num_segments], monoid, dtype),
             touched.reshape(-1)[:num_segments] > 0)

@@ -25,13 +25,14 @@ Structure (the two-level fold, with the gather pulled inside):
   * the message table (``[n_pad + 1]`` vertex values + identity sentinel)
     rides along as a constant-index-map input block, resident across the
     whole grid;
-  * bucket ``b``'s ``[1, fold_q]`` sub-accumulator is the revisited
-    output block (initialized at ``t == 0``, accumulated across the
-    inner sweep);
+  * bucket ``b``'s ``[1, fold_q]`` sub-accumulator (a row of the
+    ``[nb, 1, fold_q]`` output) is the revisited output block
+    (initialized at ``t == 0``, accumulated across the inner sweep);
   * per-tile bucket ranges ``[bmin, bmax]`` — computed from the
-    *structurally valid* destinations before the ``pallas_call`` —
-    predicate each grid step, so the destination-sorted dc_bin streams
-    do ~``nb + nt`` body runs, not ``nb × nt``;
+    *structurally valid* destinations before the ``pallas_call`` and
+    scalar-prefetched into SMEM — predicate each grid step, so the
+    destination-sorted dc_bin streams do ~``nb + nt`` body runs, not
+    ``nb × nt``;
   * the combine is the same masked one-hot VPU reduction as the fold
     kernels (the MXU one-hot matmul stays off the table for the
     NaN/int-truncation reasons documented in
@@ -44,10 +45,12 @@ the host never materializes a per-edge validity stream either.
 
 The in-kernel table gather (``table[idx]``) is an arbitrary dynamic
 vector gather.  Interpret mode executes it as a plain jnp gather on any
-host; Mosaic support for arbitrary VMEM gathers is generation-dependent,
-so the ``pallas-native`` registration shares the usual caveat of this
-repo's TPU path (untested here — the TPU CI lane is still an open
-ROADMAP item).
+host; Mosaic refuses it ("Only 2D gather is supported": the TPU lowers
+only in-register gathers), and the resident ``[n_pad + 1]`` table alone
+would be 16 MiB per array at 2^22 vertices.  So ``pallas-native`` does
+not register this kernel, and the TPU default for ``fused_dc`` is the
+``ref`` lowering (one XLA gather + one XLA segmented fold), named in
+:data:`repro.backend.registry.TPU_DEFAULTS`.
 
 Env: ``REPRO_FUSED=0`` opts the engines out of fused selection entirely
 (they silently fall back to the composed scatter→fold path, which also
@@ -61,8 +64,9 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .fold_block import default_fold_tile
+from .fold_block import combine_tile, default_fold_tile
 from .fold_two_level import default_fold_q
 from .segment_combine import _identity_val
 
@@ -75,55 +79,37 @@ def fused_enabled() -> bool:
     return os.environ.get(ENV_FUSED, "1") != "0"
 
 
-def _kernel(table_ref, tvalid_ref,                     # resident table in
+def _kernel(bmin_ref, bmax_ref,                        # SMEM (per tile)
+            table_ref, tvalid_ref,                     # resident table in
             idx_ref, evalid_ref, dst_ref, w_ref,       # VMEM in (one tile)
-            bmin_ref, bmax_ref,                        # VMEM in (per tile)
-            acc_ref, touched_ref,                      # VMEM out (resident)
+            acc_ref, touched_ref,                      # VMEM out (bucket)
             *, monoid: str, q: int, apply_weight):
     b = pl.program_id(0)
     t = pl.program_id(1)
-    ident = _identity_val(monoid, acc_ref.dtype)
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, ident)
+        acc_ref[...] = jnp.full_like(acc_ref,
+                                     _identity_val(monoid, acc_ref.dtype))
         touched_ref[...] = jnp.zeros_like(touched_ref)
 
     # bucket-range predication over the structurally valid destinations:
     # tiles that cannot land a message in bucket b are skipped entirely
-    @pl.when((bmin_ref[0] <= b) & (b <= bmax_ref[0]))
+    @pl.when((bmin_ref[t] <= b) & (b <= bmax_ref[t]))
     def _body():
         idx = idx_ref[...]                              # [T]
         # the fused gather: source values pulled straight from the
         # resident message table — no [NE] edge-value stream in HBM
         vals = table_ref[...][idx]                      # [T]
-        valid = ((tvalid_ref[...][idx] > 0)
-                 & (evalid_ref[...] > 0))               # [T]
+        valid = (tvalid_ref[...][idx] > 0) & (evalid_ref[...] > 0)
         if apply_weight is not None:
             vals = apply_weight(vals, w_ref[...]).astype(acc_ref.dtype)
-        ids = dst_ref[...]
-        bucket = ids // q
-        off = ids - bucket * q
+        # offset inside bucket b; invalid edges carry -1 and match no
+        # column (a bool [T] -> [T, 1] relayout does not lower on Mosaic)
+        off = jnp.where(valid, dst_ref[...], -1) - b * q
         cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], q), 1)
-        onehot = ((off[:, None] == cols) & (bucket == b)[:, None]
-                  & valid[:, None])                     # [T, q]
-        if monoid == "add":
-            masked = jnp.where(onehot, vals[:, None],
-                               jnp.zeros((), acc_ref.dtype))
-            contrib = jnp.sum(masked, axis=0)
-            acc_ref[...] = acc_ref[...] \
-                + contrib.astype(acc_ref.dtype)[None, :]
-        elif monoid == "min":
-            masked = jnp.where(onehot, vals[:, None], ident)
-            acc_ref[...] = jnp.minimum(acc_ref[...],
-                                       jnp.min(masked, axis=0)[None, :])
-        elif monoid == "max":
-            masked = jnp.where(onehot, vals[:, None], ident)
-            acc_ref[...] = jnp.maximum(acc_ref[...],
-                                       jnp.max(masked, axis=0)[None, :])
-        touched_ref[...] = jnp.maximum(
-            touched_ref[...],
-            jnp.max(onehot.astype(jnp.int32), axis=0)[None, :])
+        combine_tile(acc_ref, touched_ref, vals, off[:, None] == cols,
+                     monoid=monoid)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "monoid",
@@ -189,25 +175,21 @@ def fused_scatter_fold(table, table_valid, idx, edge_valid, dst,
         0, nb)
 
     m = table.shape[0]
+    tile_spec = pl.BlockSpec((tile,), lambda b, t, lo, hi: (t,))
+    table_spec = pl.BlockSpec((m,), lambda b, t, lo, hi: (0,))
+    acc_spec = pl.BlockSpec((None, 1, q), lambda b, t, lo, hi: (b, 0, 0))
     acc, touched = pl.pallas_call(
         functools.partial(_kernel, monoid=monoid, q=q,
                           apply_weight=apply_weight),
-        grid=(nb, nt),
-        in_specs=[pl.BlockSpec((m,), lambda b, t: (0,)),
-                  pl.BlockSpec((m,), lambda b, t: (0,)),
-                  pl.BlockSpec((tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((tile,), lambda b, t: (t,)),
-                  pl.BlockSpec((1,), lambda b, t: (t,)),
-                  pl.BlockSpec((1,), lambda b, t: (t,))],
-        out_specs=[pl.BlockSpec((1, q), lambda b, t: (b, 0)),
-                   pl.BlockSpec((1, q), lambda b, t: (b, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, q), table.dtype),
-                   jax.ShapeDtypeStruct((nb, q), jnp.int32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nb, nt),
+            in_specs=[table_spec, table_spec] + [tile_spec] * 4,
+            out_specs=[acc_spec, acc_spec]),
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, q), table.dtype),
+                   jax.ShapeDtypeStruct((nb, 1, q), jnp.int32)],
         interpret=interpret,
-    )(table, table_valid.astype(jnp.int32), idx, evalid, dst, w,
-      bmin.astype(jnp.int32), bmax.astype(jnp.int32))
+    )(bmin.astype(jnp.int32), bmax.astype(jnp.int32),
+      table, table_valid.astype(jnp.int32), idx, evalid, dst, w)
     # buckets tile the segment space disjointly: stage 2 is a relayout
     return (acc.reshape(-1)[:ns], touched.reshape(-1)[:ns] > 0)
 

@@ -12,6 +12,11 @@ Engines do not pick between them directly: construct kernels through
 :func:`repro.backend.registry.make_kernels` (or the :func:`make_kernels`
 convenience re-export below), which resolves the backend from the platform,
 the ``REPRO_KERNEL_BACKEND`` override, and per-combination support.
+
+Every layout-bound kernel keeps the layout arrays it reads in
+:attr:`LayoutBound.arrays` and takes them back as an ``arrays=`` call
+argument: the engines thread them into their jitted steps as arguments,
+because jit embeds a closed-over array in the program as a constant.
 """
 from __future__ import annotations
 
@@ -31,95 +36,129 @@ from .segment_combine import segment_combine, _identity_val
 from .spmv_block import spmv_block
 
 
-class GatherKernel:
+class LayoutBound:
+    """A kernel bound to one layout.
+
+    :attr:`arrays` holds the device copies of the layout arrays the
+    kernel reads, uploaded on first use, so a lowering the engine never
+    calls costs no device memory.  ``__call__`` takes them back as
+    ``arrays=`` (default: :attr:`arrays`); the engines pass them through
+    their jitted steps as arguments — closed over, jit would embed every
+    one of them in the compiled program as a constant."""
+
+    def __init__(self, layout):
+        self.L = layout
+        self._arrays = None
+
+    def host_arrays(self) -> dict:
+        """The numpy arrays to upload (``None`` entries stay ``None``)."""
+        raise NotImplementedError
+
+    @property
+    def arrays(self) -> dict:
+        if self._arrays is None:
+            self._arrays = {k: None if v is None else jnp.asarray(v)
+                            for k, v in self.host_arrays().items()}
+        return self._arrays
+
+    def _args(self, arrays):
+        return self.arrays if arrays is None else arrays
+
+
+class GatherKernel(LayoutBound):
     """Gather-phase fold bound to a layout (acc + touched over [n_pad])."""
 
     def __init__(self, layout, monoid_name: str, dtype,
                  interpret: bool = True):
-        self.L = layout
+        super().__init__(layout)
         self.monoid = monoid_name
         self.dtype = jnp.dtype(dtype)
         self.interpret = interpret
-        self.tile_dst_part = jnp.asarray(layout.tile_dst_part)
-        self.tile_src_part = jnp.asarray(layout.tile_src_part)
-        self.tile_first = jnp.asarray(layout.tile_first.astype(np.int32))
-        self.edge_dst_local = jnp.asarray(layout.edge_dst_local)
-        self.has_tiles = jnp.asarray(
-            layout.part_has_tiles.astype(np.int32))[:, None]
         self.ident = _identity_val(monoid_name, self.dtype)
 
-    def __call__(self, edge_vals, edge_valid, part_active):
+    def host_arrays(self):
         L = self.L
+        return {"tile_dst_part": L.tile_dst_part,
+                "tile_src_part": L.tile_src_part,
+                "tile_first": L.tile_first.astype(np.int32),
+                "edge_dst_local": L.edge_dst_local,
+                "has_tiles": L.part_has_tiles.astype(np.int32)[:, None]}
+
+    def __call__(self, edge_vals, edge_valid, part_active, arrays=None):
+        L, A = self.L, self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.gather")):
             acc, touched = segment_combine(
-                edge_vals, edge_valid, self.edge_dst_local,
-                self.tile_dst_part, self.tile_src_part, self.tile_first,
+                edge_vals, edge_valid, A["edge_dst_local"],
+                A["tile_dst_part"], A["tile_src_part"], A["tile_first"],
                 part_active, k=L.k, q=L.q, edge_tile=L.edge_tile,
                 monoid=self.monoid, interpret=self.interpret)
             # destination partitions with no incoming tiles were never
             # visited
-            acc = jnp.where(self.has_tiles > 0, acc, self.ident)
-            touched = jnp.where(self.has_tiles > 0, touched, 0)
+            acc = jnp.where(A["has_tiles"] > 0, acc, self.ident)
+            touched = jnp.where(A["has_tiles"] > 0, touched, 0)
             return acc.reshape(-1), touched.reshape(-1) > 0
 
 
-class ScatterKernel:
+class ScatterKernel(LayoutBound):
     """DC scatter-phase message materialization bound to a layout."""
 
     def __init__(self, layout, monoid_name: str, dtype,
                  interpret: bool = True):
-        self.L = layout
+        super().__init__(layout)
         self.monoid = monoid_name
         self.dtype = jnp.dtype(dtype)
         self.interpret = interpret
-        self.png_src_local = jnp.asarray(layout.png_src_local)
-        self.png_valid = jnp.asarray(
-            (layout.png_src < layout.n_pad).astype(np.int32))
-        self.png_tile_part = jnp.asarray(layout.png_tile_part)
 
-    def __call__(self, x_flat, active_flat):
+    def host_arrays(self):
         L = self.L
+        return {"png_src_local": L.png_src_local,
+                "png_valid": (L.png_src < L.n_pad).astype(np.int32),
+                "png_tile_part": L.png_tile_part}
+
+    def __call__(self, x_flat, active_flat, arrays=None):
+        L, A = self.L, self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.scatter")):
             return dc_gather(
                 x_flat.reshape(L.k, L.q),
                 active_flat.astype(jnp.int32).reshape(L.k, L.q),
-                self.png_src_local, self.png_valid, self.png_tile_part,
+                A["png_src_local"], A["png_valid"], A["png_tile_part"],
                 k=L.k, q=L.q, msg_tile=L.msg_tile, monoid=self.monoid,
                 interpret=self.interpret)
 
 
-class SpmvKernel:
+class SpmvKernel(LayoutBound):
     """Fused partition-centric SpMV bound to a layout (PageRank DC loop)."""
 
     def __init__(self, layout, interpret: bool = True, weighted=None):
-        self.L = layout
+        super().__init__(layout)
         self.interpret = interpret
-        self.weighted = layout.weighted if weighted is None else weighted
-        self.edge_src_local = jnp.asarray(layout.edge_src_local)
-        self.edge_dst_local = jnp.asarray(layout.edge_dst_local)
-        self.edge_valid = jnp.asarray(layout.edge_valid.astype(np.int32))
-        self.edge_w = (jnp.asarray(layout.edge_w)
-                       if (self.weighted and layout.edge_w is not None)
-                       else None)
-        self.tile_dst_part = jnp.asarray(layout.tile_dst_part)
-        self.tile_src_part = jnp.asarray(layout.tile_src_part)
-        self.tile_first = jnp.asarray(layout.tile_first.astype(np.int32))
-        self.has_tiles = jnp.asarray(
-            layout.part_has_tiles.astype(np.int32))[:, None]
+        self.weighted = ((layout.weighted if weighted is None else weighted)
+                         and layout.edge_w is not None)
 
-    def __call__(self, x_flat):
+    def host_arrays(self):
         L = self.L
+        return {"edge_src_local": L.edge_src_local,
+                "edge_dst_local": L.edge_dst_local,
+                "edge_valid": L.edge_valid.astype(np.int32),
+                "edge_w": L.edge_w if self.weighted else None,
+                "tile_dst_part": L.tile_dst_part,
+                "tile_src_part": L.tile_src_part,
+                "tile_first": L.tile_first.astype(np.int32),
+                "has_tiles": L.part_has_tiles.astype(np.int32)[:, None]}
+
+    def __call__(self, x_flat, arrays=None):
+        L, A = self.L, self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.spmv")):
             y = spmv_block(
-                x_flat.reshape(L.k, L.q), self.edge_src_local,
-                self.edge_dst_local, self.edge_valid, self.edge_w,
-                self.tile_dst_part, self.tile_src_part, self.tile_first,
+                x_flat.reshape(L.k, L.q), A["edge_src_local"],
+                A["edge_dst_local"], A["edge_valid"], A["edge_w"],
+                A["tile_dst_part"], A["tile_src_part"], A["tile_first"],
                 k=L.k, q=L.q, edge_tile=L.edge_tile,
-                weighted=self.edge_w is not None, interpret=self.interpret)
-            return jnp.where(self.has_tiles > 0, y, 0.0).reshape(-1)
+                weighted=self.weighted, interpret=self.interpret)
+            return jnp.where(A["has_tiles"] > 0, y, 0.0).reshape(-1)
 
 
 class FoldKernel:
@@ -197,7 +236,7 @@ class RefFold:
             return acc, touched
 
 
-class RefGather:
+class RefGather(LayoutBound):
     """Pure-jnp gather fold with GatherKernel's exact call contract.
 
     Unlike the raw :func:`repro.kernels.ref.segment_combine_ref` oracle it
@@ -215,35 +254,41 @@ class RefGather:
     """
 
     def __init__(self, layout, monoid):
+        super().__init__(layout)
         self.monoid = monoid
         self.n_pad = layout.n_pad
-        self.edge_dst = jnp.asarray(layout.edge_dst)
-        # every edge tile lies inside one (p', p) block: per-edge source
-        # partition is the tile's, repeated
-        self.edge_src_part = jnp.asarray(
-            np.repeat(layout.tile_src_part, layout.edge_tile))
         call = jax.custom_batching.custom_vmap(self._single)
         call.def_vmap(self._vmap_rule)
         self._call = call
 
-    def __call__(self, edge_vals, edge_valid, part_active):
+    def host_arrays(self):
+        L = self.L
+        # every edge tile lies inside one (p', p) block: per-edge source
+        # partition is the tile's, repeated
+        return {"edge_dst": L.edge_dst,
+                "edge_src_part": np.repeat(L.tile_src_part, L.edge_tile)}
+
+    def __call__(self, edge_vals, edge_valid, part_active, arrays=None):
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.gather.ref")):
-            return self._call(edge_vals, edge_valid, part_active)
+            return self._call(edge_vals, edge_valid, part_active,
+                              self._args(arrays))
 
-    def _single(self, edge_vals, edge_valid, part_active):
+    def _single(self, edge_vals, edge_valid, part_active, A):
         mono = self.monoid
         valid = (edge_valid.astype(bool)
-                 & (part_active[self.edge_src_part] > 0))
+                 & (part_active[A["edge_src_part"]] > 0))
         vals = jnp.where(valid, edge_vals.astype(mono.dtype), mono.identity)
-        acc = mono.segment_fold(vals, self.edge_dst, self.n_pad + 1)
-        touched = jax.ops.segment_max(valid.astype(jnp.int32), self.edge_dst,
+        acc = mono.segment_fold(vals, A["edge_dst"], self.n_pad + 1)
+        touched = jax.ops.segment_max(valid.astype(jnp.int32), A["edge_dst"],
                                       num_segments=self.n_pad + 1) > 0
         return acc[:self.n_pad], touched[:self.n_pad]
 
     def _vmap_rule(self, axis_size, in_batched, edge_vals, edge_valid,
-                   part_active):
-        ev_b, evd_b, pa_b = in_batched
+                   part_active, A):
+        ev_b, evd_b, pa_b, a_b = in_batched
+        assert not any(jax.tree_util.tree_leaves(a_b)), \
+            "layout arrays are shared by every lane"
         if not ev_b:
             edge_vals = jnp.broadcast_to(
                 edge_vals, (axis_size,) + edge_vals.shape)
@@ -254,30 +299,59 @@ class RefGather:
             part_active = jnp.broadcast_to(
                 part_active, (axis_size,) + part_active.shape)
         mono = self.monoid
-        B, ns = axis_size, self.n_pad + 1
-        valid = (edge_valid.astype(bool)
-                 & (jnp.take(part_active, self.edge_src_part, axis=1) > 0))
-        vals = jnp.where(valid, edge_vals.astype(mono.dtype), mono.identity)
-        # flattened segment space: lane b owns segments [b*ns, (b+1)*ns).
-        # The ids stay int32 (segment ops silently drop out-of-range ids,
-        # and int64 is unavailable without x64), so lanes are folded in
-        # chunks whose flattened space fits int32 — one chunk in practice.
-        lanes_per_chunk = max(1, (2**31 - 1) // ns)
-        accs, toucheds = [], []
-        for lo in range(0, B, lanes_per_chunk):
-            bc = min(lanes_per_chunk, B - lo)
-            fids = (jnp.arange(bc, dtype=jnp.int32)[:, None] * ns
-                    + self.edge_dst[None, :]).reshape(-1)
-            v = vals[lo:lo + bc]
-            accs.append(mono.segment_fold(
-                v.reshape(-1), fids, bc * ns).reshape(bc, ns))
-            toucheds.append(jax.ops.segment_max(
-                valid[lo:lo + bc].astype(jnp.int32).reshape(-1), fids,
-                num_segments=bc * ns).reshape(bc, ns) > 0)
-        acc = jnp.concatenate(accs) if len(accs) > 1 else accs[0]
-        touched = (jnp.concatenate(toucheds) if len(toucheds) > 1
-                   else toucheds[0])
+        edge_dst = A["edge_dst"]
+
+        def lanes(vals, valid, active):
+            valid = (valid.astype(bool)
+                     & (jnp.take(active, A["edge_src_part"], axis=1) > 0))
+            vals = jnp.where(valid, vals.astype(mono.dtype), mono.identity)
+            return vals, valid, edge_dst[None, :]
+
+        acc, touched = _fold_lanes_flat(
+            mono, lanes, (edge_vals, edge_valid, part_active),
+            self.n_pad + 1, edge_dst.shape[0])
         return (acc[:, :self.n_pad], touched[:, :self.n_pad]), (True, True)
+
+
+#: elements of one ``[lanes, edges]`` stream in a batched ref fold: lanes
+#: fold in chunks of at most this many, one chunk after another, so a
+#: batch never holds ``B`` edge-sized streams at once (8 lanes of a
+#: 2^27-edge graph would need 4 GiB per stream)
+LANE_CHUNK_ELEMS = 1 << 27
+
+
+def _fold_lanes_flat(mono, lanes, xs, ns, width):
+    """Fold a batch of lanes through one flattened ``lane * ns + id``
+    segment space per chunk of lanes.  ``xs`` are the per-lane inputs,
+    each ``[B, ...]``; ``lanes(*chunk)`` turns a chunk of them into the
+    ``(vals, valid, ids)`` streams, each ``[lanes, width]`` (``ids`` may
+    broadcast over lanes).  A chunk's flattened ids stay inside int32
+    (segment ops silently drop out-of-range ids, and int64 is unavailable
+    without x64), and its streams hold at most :data:`LANE_CHUNK_ELEMS`
+    elements; several chunks run in a ``lax.map``, which reuses one
+    chunk's memory for the next."""
+    B = xs[0].shape[0]
+    per = max(1, min((2**31 - 1) // ns, LANE_CHUNK_ELEMS // max(width, 1)))
+
+    def fold(chunk):
+        vals, valid, ids = lanes(*chunk)
+        bc = vals.shape[0]
+        fids = (jnp.arange(bc, dtype=jnp.int32)[:, None] * ns
+                + jnp.broadcast_to(ids, (bc, width))).reshape(-1)
+        acc = mono.segment_fold(vals.reshape(-1), fids, bc * ns)
+        touched = jax.ops.segment_max(valid.astype(jnp.int32).reshape(-1),
+                                      fids, num_segments=bc * ns) > 0
+        return acc.reshape(bc, ns), touched.reshape(bc, ns)
+
+    if per >= B:
+        return fold(xs)
+    nc = -(-B // per)
+    chunks = [jnp.concatenate([x, jnp.broadcast_to(
+        x[:1], (nc * per - B,) + x.shape[1:])]).reshape(
+            (nc, per) + x.shape[1:]) for x in xs]
+    acc, touched = jax.lax.map(fold, chunks)
+    return (acc.reshape(nc * per, ns)[:B],
+            touched.reshape(nc * per, ns)[:B])
 
 
 def _edge_src_global(layout) -> np.ndarray:
@@ -293,7 +367,7 @@ def _edge_src_global(layout) -> np.ndarray:
     return np.clip(src, 0, layout.n_pad).astype(np.int32)
 
 
-class FusedDCKernel:
+class FusedDCKernel(LayoutBound):
     """Fused DC scatter→fold bound to a layout (registry ``fused_dc``).
 
     One Pallas call replaces the composed scatter kernel + slot gather +
@@ -311,33 +385,34 @@ class FusedDCKernel:
 
     def __init__(self, layout, monoid_name: str, dtype,
                  interpret: bool = True):
-        self.L = layout
+        super().__init__(layout)
         self.monoid = monoid_name
         self.dtype = jnp.dtype(dtype)
         self.interpret = interpret
         self.n_pad = layout.n_pad
         self.edge_tile = layout.edge_tile
         self.fold_q = layout.fold_q
-        self.edge_src = jnp.asarray(_edge_src_global(layout))
-        self.edge_valid = jnp.asarray(layout.edge_valid.astype(np.int32))
-        self.edge_dst = jnp.asarray(layout.edge_dst)
-        self.edge_w = (jnp.asarray(layout.edge_w)
-                       if layout.edge_w is not None else None)
         self.apply_weight = None               # engine-configured
 
-    def __call__(self, table, table_valid):
-        aw = self.apply_weight
+    def host_arrays(self):
+        L = self.L
+        return {"edge_src": _edge_src_global(L),
+                "edge_valid": L.edge_valid.astype(np.int32),
+                "edge_dst": L.edge_dst, "edge_w": L.edge_w}
+
+    def __call__(self, table, table_valid, arrays=None):
+        aw, A = self.apply_weight, self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.fused_dc")):
             return fused_scatter_fold(
-                table, table_valid, self.edge_src, self.edge_valid,
-                self.edge_dst, self.n_pad + 1, monoid=self.monoid,
+                table, table_valid, A["edge_src"], A["edge_valid"],
+                A["edge_dst"], self.n_pad + 1, monoid=self.monoid,
                 edge_tile=self.edge_tile, fold_q=self.fold_q,
                 interpret=self.interpret, apply_weight=aw,
-                w=self.edge_w if aw is not None else None)
+                w=A["edge_w"] if aw is not None else None)
 
 
-class RefFusedDC:
+class RefFusedDC(LayoutBound):
     """Pure-jnp fused DC step with FusedDCKernel's exact call contract —
     the composed oracle collapsed to one gather + one segmented fold.
 
@@ -349,66 +424,56 @@ class RefFusedDC:
     """
 
     def __init__(self, layout, monoid):
+        super().__init__(layout)
         self.monoid = monoid
         self.n_pad = layout.n_pad
-        self.edge_src = jnp.asarray(_edge_src_global(layout))
-        self.edge_valid = jnp.asarray(layout.edge_valid)
-        self.edge_dst = jnp.asarray(layout.edge_dst)
-        self.edge_w = (jnp.asarray(layout.edge_w)
-                       if layout.edge_w is not None else None)
         self.apply_weight = None               # engine-configured
         call = jax.custom_batching.custom_vmap(self._single)
         call.def_vmap(self._vmap_rule)
         self._call = call
 
-    def __call__(self, table, table_valid):
+    def host_arrays(self):
+        L = self.L
+        return {"edge_src": _edge_src_global(L), "edge_valid": L.edge_valid,
+                "edge_dst": L.edge_dst, "edge_w": L.edge_w}
+
+    def __call__(self, table, table_valid, arrays=None):
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.fused_dc.ref")):
-            return self._call(table, table_valid)
+            return self._call(table, table_valid, self._args(arrays))
 
-    def _single(self, table, table_valid):
+    def _single(self, table, table_valid, A):
         aw = self.apply_weight
         return ref_fused_scatter_fold(
-            self.monoid, table, table_valid, self.edge_src,
-            self.edge_valid, self.edge_dst, self.n_pad + 1,
-            apply_weight=aw, w=self.edge_w if aw is not None else None)
+            self.monoid, table, table_valid, A["edge_src"],
+            A["edge_valid"], A["edge_dst"], self.n_pad + 1,
+            apply_weight=aw, w=A["edge_w"] if aw is not None else None)
 
-    def _vmap_rule(self, axis_size, in_batched, table, table_valid):
-        tb, tvb = in_batched
+    def _vmap_rule(self, axis_size, in_batched, table, table_valid, A):
+        tb, tvb, a_b = in_batched
+        assert not any(jax.tree_util.tree_leaves(a_b)), \
+            "layout arrays are shared by every lane"
         if not tb:
             table = jnp.broadcast_to(table, (axis_size,) + table.shape)
         if not tvb:
             table_valid = jnp.broadcast_to(
                 table_valid, (axis_size,) + table_valid.shape)
         mono = self.monoid
-        B, ns = axis_size, self.n_pad + 1
-        vals = jnp.take(table, self.edge_src, axis=1).astype(mono.dtype)
-        valid = (jnp.take(table_valid.astype(bool), self.edge_src, axis=1)
-                 & self.edge_valid[None, :])
-        if self.apply_weight is not None:
-            vals = self.apply_weight(
-                vals, self.edge_w[None, :]).astype(mono.dtype)
-        vals = jnp.where(valid, vals, mono.identity)
-        ids = jnp.where(valid, self.edge_dst[None, :], ns - 1)
-        # flattened segment space, chunked so bc * ns fits int32 (cf.
-        # RefGather._vmap_rule — segment ops silently drop out-of-range
-        # ids and int64 is unavailable without x64)
-        lanes_per_chunk = max(1, (2**31 - 1) // ns)
-        accs, toucheds = [], []
-        for lo in range(0, B, lanes_per_chunk):
-            bc = min(lanes_per_chunk, B - lo)
-            fids = (jnp.arange(bc, dtype=jnp.int32)[:, None] * ns
-                    + ids[lo:lo + bc]).reshape(-1)
-            accs.append(mono.segment_fold(
-                vals[lo:lo + bc].reshape(-1), fids, bc * ns)
-                .reshape(bc, ns))
-            toucheds.append(jax.ops.segment_max(
-                valid[lo:lo + bc].astype(jnp.int32).reshape(-1), fids,
-                num_segments=bc * ns).reshape(bc, ns) > 0)
-        acc = jnp.concatenate(accs) if len(accs) > 1 else accs[0]
-        touched = (jnp.concatenate(toucheds) if len(toucheds) > 1
-                   else toucheds[0])
-        return (acc, touched), (True, True)
+        ns, src = self.n_pad + 1, A["edge_src"]
+
+        def lanes(table, table_valid):
+            vals = jnp.take(table, src, axis=1).astype(mono.dtype)
+            valid = (jnp.take(table_valid.astype(bool), src, axis=1)
+                     & A["edge_valid"][None, :])
+            if self.apply_weight is not None:
+                vals = self.apply_weight(
+                    vals, A["edge_w"][None, :]).astype(mono.dtype)
+            vals = jnp.where(valid, vals, mono.identity)
+            return vals, valid, jnp.where(valid, A["edge_dst"][None, :],
+                                          ns - 1)
+
+        return (_fold_lanes_flat(mono, lanes, (table, table_valid), ns,
+                                 src.shape[0]), (True, True))
 
 
 class FusedStreamKernel:
@@ -457,45 +522,50 @@ class RefFusedStream:
                 int(num_segments), apply_weight=apply_weight, w=w)
 
 
-class RefScatter:
+class RefScatter(LayoutBound):
     """Pure-jnp DC scatter with ScatterKernel's exact call contract."""
 
     def __init__(self, layout, monoid):
+        super().__init__(layout)
         self.monoid = monoid
         self.n_pad = layout.n_pad
-        self.png_src = jnp.asarray(layout.png_src)
-        self.png_valid = jnp.asarray(layout.png_src < layout.n_pad)
 
-    def __call__(self, x_flat, active_flat):
-        mono = self.monoid
+    def host_arrays(self):
+        L = self.L
+        return {"png_src": L.png_src, "png_valid": L.png_src < L.n_pad}
+
+    def __call__(self, x_flat, active_flat, arrays=None):
+        mono, A = self.monoid, self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.scatter.ref")):
-            src = jnp.minimum(self.png_src, self.n_pad - 1)
-            ok = self.png_valid & (active_flat.astype(bool)[src])
+            src = jnp.minimum(A["png_src"], self.n_pad - 1)
+            ok = A["png_valid"] & (active_flat.astype(bool)[src])
             return jnp.where(ok, x_flat.astype(mono.dtype)[src],
                              mono.identity)
 
 
-class RefSpmv:
+class RefSpmv(LayoutBound):
     """Pure-jnp partition-centric SpMV with SpmvKernel's call contract."""
 
     def __init__(self, layout, weighted=None):
+        super().__init__(layout)
         self.n_pad = layout.n_pad
-        self.weighted = layout.weighted if weighted is None else weighted
-        self.msg_slot = jnp.asarray(layout.msg_slot)
-        self.png_src = jnp.asarray(layout.png_src)
-        self.edge_dst = jnp.asarray(layout.edge_dst)
-        self.edge_valid = jnp.asarray(layout.edge_valid)
-        self.edge_w = (jnp.asarray(layout.edge_w)
-                       if (self.weighted and layout.edge_w is not None)
-                       else None)
+        self.weighted = ((layout.weighted if weighted is None else weighted)
+                         and layout.edge_w is not None)
 
-    def __call__(self, x_flat):
+    def host_arrays(self):
+        L = self.L
+        return {"msg_slot": L.msg_slot, "png_src": L.png_src,
+                "edge_dst": L.edge_dst, "edge_valid": L.edge_valid,
+                "edge_w": L.edge_w if self.weighted else None}
+
+    def __call__(self, x_flat, arrays=None):
+        A = self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.spmv.ref")):
             return kref.spmv_block_ref(
-                x_flat, self.msg_slot, self.png_src, self.edge_dst,
-                self.edge_valid, self.edge_w, self.n_pad)
+                x_flat, A["msg_slot"], A["png_src"], A["edge_dst"],
+                A["edge_valid"], A["edge_w"], self.n_pad)
 
 
 def make_kernels(layout, monoid, backend=None, platform=None,
@@ -506,8 +576,8 @@ def make_kernels(layout, monoid, backend=None, platform=None,
                                  platform=platform, with_spmv=with_spmv)
 
 
-__all__ = ["GatherKernel", "ScatterKernel", "SpmvKernel", "FoldKernel",
-           "FusedDCKernel", "FusedStreamKernel", "RefGather", "RefScatter",
+__all__ = ["LayoutBound", "GatherKernel", "ScatterKernel", "SpmvKernel",
+           "FoldKernel", "FusedDCKernel", "FusedStreamKernel", "RefGather", "RefScatter",
            "RefSpmv", "RefFold", "RefFusedDC", "RefFusedStream",
            "make_kernels", "segment_combine", "dc_gather", "spmv_block",
            "blocked_segment_fold", "two_level_segment_fold",

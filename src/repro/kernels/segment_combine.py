@@ -39,9 +39,46 @@ def _identity_val(monoid: str, dtype):
     raise ValueError(monoid)
 
 
+_SIGN = np.int32(-2**31)
+
+
+def to_signed(x, monoid: str):
+    """Order-preserving int32 view of a uint32 payload for the kernels.
+
+    Mosaic has no reductions over unsigned integers.  Flipping the sign
+    bit maps the unsigned order onto the signed one, so min/max commute
+    with the view; add wraps identically on the plain bitcast.  Other
+    dtypes pass through unchanged."""
+    if x.dtype != jnp.uint32:
+        return x
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits if monoid == "add" else bits ^ _SIGN
+
+
+def from_signed(x, monoid: str, dtype):
+    """Inverse of :func:`to_signed` back to ``dtype``."""
+    if jnp.dtype(dtype) != jnp.uint32:
+        return x
+    if monoid != "add":
+        x = x ^ _SIGN
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+
+#: edges per one-hot combine inside a grid step; the step's DMA block is
+#: the layout's whole edge tile (a multiple of 1024 on TPU, where XLA
+#: lays large 1-D arrays out in 1024-element tiles)
+CHUNK = 256
+
+
+def chunks(n: int):
+    """Static ``pl.ds`` slices covering a block of ``n`` elements."""
+    c = min(CHUNK, n)
+    return [pl.ds(i, min(c, n - i)) for i in range(0, n, c)]
+
+
 def _kernel(tile_dst_ref, tile_src_ref, tile_first_ref,   # scalar prefetch
             part_active_ref,                               # scalar prefetch
-            vals_ref, valid_ref, dstl_ref,                 # VMEM in
+            vals_ref, dstl_ref,                            # VMEM in
             acc_ref, touched_ref,                          # VMEM out
             *, monoid: str, q: int):
     t = pl.program_id(0)
@@ -56,35 +93,40 @@ def _kernel(tile_dst_ref, tile_src_ref, tile_first_ref,   # scalar prefetch
     # 2-level active list: skip tiles whose source partition is inactive
     @pl.when(part_active_ref[tile_src_ref[t]] > 0)
     def _body():
-        vals = vals_ref[...]                                # [T]
-        valid = valid_ref[...] > 0                          # [T]
-        dstl = dstl_ref[...]                                # [T]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], q), 1)
-        onehot = (dstl[:, None] == cols) & valid[:, None]   # [T, q]
-        if monoid == "add":
-            if jnp.issubdtype(acc_ref.dtype, jnp.floating):
-                contrib = jnp.dot(
-                    jnp.where(valid, vals, 0).astype(jnp.float32)[None, :],
-                    onehot.astype(jnp.float32),
-                    preferred_element_type=jnp.float32)[0]
-            else:
-                # 32-bit integer state: the f32 MXU round trip truncates
-                # above 2**24, so fold on the VPU in the native dtype
-                masked = jnp.where(onehot, vals[:, None],
-                                   jnp.zeros((), acc_ref.dtype))
-                contrib = jnp.sum(masked, axis=0)
-            acc_ref[...] = acc_ref[...] + contrib.astype(acc_ref.dtype)[None, :]
-        elif monoid == "min":
-            masked = jnp.where(onehot, vals[:, None], ident)
-            acc_ref[...] = jnp.minimum(acc_ref[...],
-                                       jnp.min(masked, axis=0)[None, :])
-        elif monoid == "max":
-            masked = jnp.where(onehot, vals[:, None], ident)
-            acc_ref[...] = jnp.maximum(acc_ref[...],
-                                       jnp.max(masked, axis=0)[None, :])
-        touched_ref[...] = jnp.maximum(
-            touched_ref[...],
-            jnp.max(onehot.astype(jnp.int32), axis=0)[None, :])
+        for part in chunks(vals_ref.shape[0]):
+            vals = vals_ref[part]                           # [T]
+            # invalid slots carry dst -1 (matches no column): Mosaic
+            # cannot relayout a bool [T] vector into a [T, 1] column
+            dstl = dstl_ref[part]                           # [T]
+            cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], q), 1)
+            onehot = dstl[:, None] == cols                  # [T, q]
+            if monoid == "add":
+                if jnp.issubdtype(acc_ref.dtype, jnp.floating):
+                    contrib = jnp.dot(
+                        jnp.where(dstl >= 0, vals, 0).astype(
+                            jnp.float32)[None, :],
+                        onehot.astype(jnp.float32),
+                        preferred_element_type=jnp.float32)[0]
+                else:
+                    # 32-bit integer state: the f32 MXU round trip
+                    # truncates above 2**24, so fold on the VPU in the
+                    # native dtype
+                    masked = jnp.where(onehot, vals[:, None],
+                                       jnp.zeros((), acc_ref.dtype))
+                    contrib = jnp.sum(masked, axis=0)
+                acc_ref[...] = acc_ref[...] \
+                    + contrib.astype(acc_ref.dtype)[None, :]
+            elif monoid == "min":
+                masked = jnp.where(onehot, vals[:, None], ident)
+                acc_ref[...] = jnp.minimum(acc_ref[...],
+                                           jnp.min(masked, axis=0)[None, :])
+            elif monoid == "max":
+                masked = jnp.where(onehot, vals[:, None], ident)
+                acc_ref[...] = jnp.maximum(acc_ref[...],
+                                           jnp.max(masked, axis=0)[None, :])
+            touched_ref[...] = jnp.maximum(
+                touched_ref[...],
+                jnp.max(onehot.astype(jnp.int32), axis=0)[None, :])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "q", "edge_tile", "monoid",
@@ -106,26 +148,27 @@ def segment_combine(edge_vals, edge_valid, edge_dst_local,
     """
     nt = tile_dst_part.shape[0]
     dtype = edge_vals.dtype
+    vals = to_signed(edge_vals, monoid)
+    dstl = jnp.where(edge_valid > 0, edge_dst_local.astype(jnp.int32), -1)
+    # accumulator rows are [k, 1, q] so a (1, q) block spans the array's
+    # last two dims whole — the (8, 128) block rule holds for any k, q
+    acc_spec = pl.BlockSpec((None, 1, q),
+                            lambda t, td, ts, tf, pa: (td[t], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
             pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
-            pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, q), lambda t, td, ts, tf, pa: (td[t], 0)),
-            pl.BlockSpec((1, q), lambda t, td, ts, tf, pa: (td[t], 0)),
-        ],
+        out_specs=[acc_spec, acc_spec],
     )
     acc, touched = pl.pallas_call(
         functools.partial(_kernel, monoid=monoid, q=q),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((k, q), dtype),
-                   jax.ShapeDtypeStruct((k, q), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((k, 1, q), vals.dtype),
+                   jax.ShapeDtypeStruct((k, 1, q), jnp.int32)],
         interpret=interpret,
     )(tile_dst_part, tile_src_part, tile_first.astype(jnp.int32),
-      part_active.astype(jnp.int32),
-      edge_vals, edge_valid.astype(jnp.int32), edge_dst_local)
-    return acc, touched
+      part_active.astype(jnp.int32), vals, dstl)
+    return from_signed(acc.reshape(k, q), monoid, dtype), touched.reshape(k, q)

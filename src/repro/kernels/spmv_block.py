@@ -22,9 +22,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .segment_combine import chunks
+
 
 def _kernel(tile_dst_ref, tile_src_ref, tile_first_ref,   # scalar prefetch
-            x_ref, srcl_ref, dstl_ref, valid_ref, w_ref,  # VMEM in
+            x_ref, srcl_ref, dstl_ref, w_ref,             # VMEM in
             y_ref, *, q: int, weighted: bool):
     t = pl.program_id(0)
 
@@ -32,16 +34,21 @@ def _kernel(tile_dst_ref, tile_src_ref, tile_first_ref,   # scalar prefetch
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    x = x_ref[0, :]                                        # [q]
-    vals = x[srcl_ref[...]]                                # [T]
-    if weighted:
-        vals = vals * w_ref[...]
-    vals = jnp.where(valid_ref[...] > 0, vals, 0.0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], q), 1)
-    onehot = (dstl_ref[...][:, None] == cols).astype(jnp.float32)
-    contrib = jnp.dot(vals.astype(jnp.float32)[None, :], onehot,
-                      preferred_element_type=jnp.float32)
-    y_ref[...] = y_ref[...] + contrib.astype(y_ref.dtype)
+    x = x_ref[...]                                         # [1, q]
+    for part in chunks(srcl_ref.shape[0]):
+        srcl = srcl_ref[part]                              # [T]
+        dstl = dstl_ref[part]                              # [T], -1 = pad
+        cols = jax.lax.broadcasted_iota(jnp.int32, (srcl.shape[0], q), 1)
+        # one-hot select gather of the source values (Mosaic lowers no
+        # arbitrary vector gather); pads select nothing and contribute 0
+        vals = jnp.sum(jnp.where((srcl[:, None] == cols)
+                                 & (dstl[:, None] >= 0), x, 0.0), axis=1)
+        if weighted:
+            vals = vals * w_ref[part]
+        onehot = (dstl[:, None] == cols).astype(jnp.float32)
+        y_ref[...] = y_ref[...] + jnp.dot(
+            vals.astype(jnp.float32)[None, :], onehot,
+            preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "q", "edge_tile",
@@ -54,23 +61,23 @@ def spmv_block(x, edge_src_local, edge_dst_local, edge_valid, edge_w,
     nt = tile_dst_part.shape[0]
     if edge_w is None:
         edge_w = jnp.ones_like(x, shape=(edge_src_local.shape[0],))
+    dstl = jnp.where(edge_valid > 0, edge_dst_local.astype(jnp.int32), -1)
+    # [k, 1, q] rows: a (1, q) block spans the last two dims whole, which
+    # satisfies the (8, 128) block rule for any k, q
+    src_row = pl.BlockSpec((None, 1, q), lambda t, td, ts, tf: (ts[t], 0, 0))
+    dst_row = pl.BlockSpec((None, 1, q), lambda t, td, ts, tf: (td[t], 0, 0))
+    edge = pl.BlockSpec((edge_tile,), lambda t, *pf: (t,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, q), lambda t, td, ts, tf: (ts[t], 0)),
-            pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
-            pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
-            pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
-            pl.BlockSpec((edge_tile,), lambda t, *pf: (t,)),
-        ],
-        out_specs=pl.BlockSpec((1, q), lambda t, td, ts, tf: (td[t], 0)),
+        in_specs=[src_row, edge, edge, edge],
+        out_specs=dst_row,
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         functools.partial(_kernel, q=q, weighted=weighted),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, q), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, 1, q), x.dtype),
         interpret=interpret,
     )(tile_dst_part, tile_src_part, tile_first.astype(jnp.int32),
-      x, edge_src_local, edge_dst_local, edge_valid.astype(jnp.int32),
-      edge_w)
+      x.reshape(k, 1, q), edge_src_local, dstl, edge_w)
+    return y.reshape(k, q)

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import setup_compile_cache
 from ..configs import get_config, get_smoke_config
 from ..dist.sharding import param_shardings, set_activation_mesh
 from ..models.transformer import init_lm
@@ -82,6 +83,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.graph:
         return serve_graph(args)

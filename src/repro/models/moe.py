@@ -204,7 +204,7 @@ def moe_fwd_ppm_ep_sharded(p, cfg, x, *, dtype=jnp.bfloat16):
     if mesh is None or "model" not in mesh.axis_names \
             or cfg.moe_experts % mesh.shape["model"] != 0:
         return moe_fwd_dense(p, cfg, x, dtype=dtype)
-    from ..dist.compat import PartitionSpec as P, shard_map
+    from jax.sharding import PartitionSpec as P
     db = _collapse(_data_axes(mesh))
 
     def spec_of(path_leaf):
@@ -225,7 +225,7 @@ def moe_fwd_ppm_ep_sharded(p, cfg, x, *, dtype=jnp.bfloat16):
     # tokens are sequence-split over the model axis: each shard dispatches
     # ONLY its S/Dm token slice (x replicated over model would make every
     # shard bin the same tokens - a 16x compute redundancy, observed)
-    return shard_map(
+    return jax.shard_map(
         lambda pp, xx: fn(pp, x=xx),
         mesh=mesh,
         in_specs=(p_specs, P(db, "model", None)),

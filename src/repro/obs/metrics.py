@@ -179,7 +179,8 @@ class Histogram:
             if frac <= 0 or k + 1 >= self.n:
                 return lo
             hi = self._rep(self._order_stat_bucket(k + 1))
-            return lo * (1.0 - frac) + hi * frac
+            # lo*(1-frac) + hi*frac rounds below lo when lo == hi
+            return min(max(lo + (hi - lo) * frac, lo), hi)
 
     @property
     def p50(self):

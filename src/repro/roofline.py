@@ -10,7 +10,9 @@ all-reduce / reduce-scatter / all-to-all / collective-permute op contributes
 its operand size scaled by the ring-algorithm factor for its replica-group
 size N (ag/rs/a2a: (N-1)/N, ar: 2(N-1)/N, cp: 1).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link.
+Hardware constants come from :data:`PEAKS`, keyed by the
+``jax.devices()[0].device_kind`` string; a kind missing from the table is
+an error, never a default.
 """
 from __future__ import annotations
 
@@ -18,9 +20,28 @@ import dataclasses
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+#: Published per-chip peaks, keyed by ``device_kind``: bf16 FLOP/s, HBM
+#: bytes/s, and bytes/s per ICI link (the chip's interconnect total over
+#: its four links).
+PEAKS = {
+    "TPU v5 lite": dict(
+        flops=197e12, hbm_bw=819e9, link_bw=1600e9 / 8 / 4,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip "
+               "interconnect"),
+}
+
+#: the chip the AOT dry-runs project onto (a v5e pod slice)
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -99,10 +120,12 @@ def collective_bytes(hlo_text: str, default_group: int) -> CollectiveStats:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   wire_bytes_per_dev: float) -> dict:
-    ct = flops_per_dev / PEAK_FLOPS
-    mt = bytes_per_dev / HBM_BW
-    lt = wire_bytes_per_dev / LINK_BW
+                   wire_bytes_per_dev: float,
+                   device_kind: str = TARGET_KIND) -> dict:
+    pk = peaks(device_kind)
+    ct = flops_per_dev / pk["flops"]
+    mt = bytes_per_dev / pk["hbm_bw"]
+    lt = wire_bytes_per_dev / pk["link_bw"]
     dom = max(("compute", ct), ("memory", mt), ("collective", lt),
               key=lambda kv: kv[1])
     total = max(ct, mt, lt)

@@ -16,10 +16,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["REPRO_TUNING_DIR"] = tempfile.mkdtemp(
     prefix="repro-tuning-test-")
 
-# Install the JAX version shims (jax.sharding.AxisType, new-style
-# AbstractMesh, make_mesh(axis_types=...)) before test modules import them.
-import repro.dist.compat  # noqa: E402,F401
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

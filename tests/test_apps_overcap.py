@@ -104,15 +104,16 @@ def test_dist_cc_overcap_shard_map(monkeypatch):
     import sys
     import textwrap
     code = """
+    import jax
     import numpy as np
-    from repro.dist.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.graph import rmat, build_layout
     from repro.graph.shard import shard_layout
     from repro.dist.engine import DistEngine
     from repro.apps.cc import cc_program
     import jax.numpy as jnp
     D = 2
-    mesh = make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
     g = rmat(8, 8, seed=5)
     L = build_layout(g, k=4, edge_tile=64, msg_tile=32)
     SL = shard_layout(L, D)
@@ -132,6 +133,7 @@ def test_dist_cc_overcap_shard_map(monkeypatch):
     """
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                REPRO_FOLD_MAX_SEGMENTS="16",
                PYTHONPATH=os.path.join(repo, "src"))

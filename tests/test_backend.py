@@ -392,6 +392,58 @@ def test_supported_matrix():
     assert registry.supported("cpu", "spmv", "min", jnp.float32) == ()
 
 
+def test_tpu_defaults_follow_the_table(monkeypatch):
+    monkeypatch.delenv(registry.ENV_VAR, raising=False)
+    for kernel, (backend, _) in registry.TPU_DEFAULTS.items():
+        assert registry.default_backend_name("tpu", kernel) == backend
+        assert registry.resolve(kernel, "add", jnp.float32,
+                                platform="tpu").name == backend
+    # Mosaic cannot lower fused_dc: pallas-native never claims it
+    assert "pallas-native" not in registry.supported(
+        "tpu", "fused_dc", "min", jnp.uint32)
+
+
+def test_tpu_fallback_to_ref_warns_even_for_defaults(monkeypatch):
+    """Off the table on a TPU (here: a monoid no Pallas kernel lowers)
+    the per-call ref fallback is never silent."""
+    monkeypatch.delenv(registry.ENV_VAR, raising=False)
+    with pytest.warns(RuntimeWarning, match="falling back to 'ref'"):
+        b = registry.resolve("fold", M.min_with_payload(), platform="tpu")
+    assert b.name == "ref"
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        registry.resolve("fold", M.min_with_payload(), platform="cpu")
+
+
+@pytest.mark.parametrize("kernel", ["gather", "fused_dc"])
+def test_batched_ref_fold_lane_chunks_agree(layout, rng, monkeypatch,
+                                            kernel):
+    """The batched ref folds split lanes into memory-bounded chunks run
+    one after another; any chunking gives the one-chunk answer."""
+    from repro.kernels import ops as kops
+    mono = M.min_(jnp.uint32)
+    ref = registry.BACKENDS["ref"]
+    B, L = 5, layout
+    if kernel == "gather":
+        k = ref.gather(L, mono)
+        args = (jnp.asarray(rng.integers(0, 99, (B, L.num_edges))
+                            .astype(np.uint32)),
+                jnp.asarray(rng.random((B, L.num_edges)) < 0.6),
+                jnp.asarray((rng.random((B, L.k)) < 0.7).astype(np.int32)))
+        width = L.num_edges
+    else:
+        k = ref.fused_dc(L, mono)
+        args = (jnp.asarray(rng.integers(0, 99, (B, L.n_pad + 1))
+                            .astype(np.uint32)),
+                jnp.asarray(rng.random((B, L.n_pad + 1)) < 0.6))
+        width = L.num_edges
+    whole = jax.vmap(k)(*args)
+    monkeypatch.setattr(kops, "LANE_CHUNK_ELEMS", 2 * width)
+    chunked = jax.vmap(k)(*args)             # 3 chunks of 2, one padded
+    for w, c in zip(whole, chunked):
+        assert np.array_equal(np.asarray(w), np.asarray(c))
+
+
 # ----------------------------------------------------------------------
 # engine rewiring: use_pallas alias, per-instance step cache
 # ----------------------------------------------------------------------
@@ -408,8 +460,8 @@ def test_step_cache_is_per_instance(layout):
     assert not hasattr(Engine._step_fn, "cache_info"), \
         "lru_cache on a method pins self (layout arrays) process-wide"
     eng = Engine(layout, bfs_program(), backend="ref")
-    fn = eng._step_fn(0, 0)
-    assert eng._step_fn(0, 0) is fn and (0, 0) in eng._step_cache
+    fn = eng._step_fn()
+    assert eng._step_fn() is fn and "step" in eng._step_cache
     other = Engine(layout, bfs_program(), backend="ref")
     assert other._step_cache == {}          # cache is not shared
     ref = weakref.ref(eng)

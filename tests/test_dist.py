@@ -13,6 +13,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(code: str):
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -24,13 +25,13 @@ def _run(code: str):
 
 COMMON = """
 import jax, numpy as np, jax.numpy as jnp
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.graph import rmat, build_layout, to_scipy
 from repro.graph.shard import shard_layout
 from repro.dist.engine import DistEngine
 import scipy.sparse.csgraph as csg
 D = 8
-mesh = make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
+mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
 g = rmat(10, 8, seed=1)
 L = build_layout(g, k=16, edge_tile=64, msg_tile=32)
 SL = shard_layout(L, D)
@@ -160,7 +161,7 @@ def test_dist_equivalence_random_graphs():
     on random graphs (one subprocess, several seeds)."""
     out = _run("""
 import numpy as np, jax
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.graph import uniform_random, build_layout
 from repro.graph.shard import shard_layout
 from repro.dist.engine import DistEngine
@@ -168,7 +169,7 @@ from repro.apps.bfs import bfs_program
 from repro.apps import bfs as bfs_single
 
 D = 8
-mesh = make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
+mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
 for seed in (3, 17, 91):
     g = uniform_random(300, 2500, seed=seed)
     L = build_layout(g, k=16, edge_tile=32, msg_tile=16)

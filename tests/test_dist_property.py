@@ -27,10 +27,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.mark.slow
 def test_hybrid_pp_parity_random_graphs_and_frontiers():
     code = textwrap.dedent("""
+    import jax
     import numpy as np
     from hypothesis import given, settings, strategies as st
 
-    from repro.dist.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.dist.engine import DistEngine
     from repro.graph import build_layout, from_edges
     from repro.graph.shard import shard_layout
@@ -38,7 +39,7 @@ def test_hybrid_pp_parity_random_graphs_and_frontiers():
     from repro.apps.cc import cc_program
 
     D = 4
-    mesh = make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
 
     def run_app(app, SL, N, frontier, mode):
         if app == "bfs":
@@ -88,6 +89,7 @@ def test_hybrid_pp_parity_random_graphs_and_frontiers():
     print("OK")
     """)
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

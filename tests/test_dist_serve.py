@@ -17,13 +17,14 @@ hypothesis, 4 virtual devices).
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.apps.bfs import bfs, bfs_program
 from repro.apps.sssp import sssp, sssp_program
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.dist.engine import (DistEngine, _pack_bf16_pairs, _pack_bits,
                                _unpack_bf16_pairs, _unpack_bits,
                                dc_wire_bytes)
@@ -33,7 +34,7 @@ from repro.graph.shard import shard_layout
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return make_mesh((1,), ("dev",), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((1,), ("dev",), axis_types=(AxisType.Auto,))
 
 
 @pytest.fixture(scope="module")

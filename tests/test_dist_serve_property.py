@@ -30,6 +30,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(code: str):
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -39,14 +40,15 @@ def _run(code: str):
 
 
 COMMON = """
+import jax
 import numpy as np
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.dist.engine import DistEngine
 from repro.graph import build_layout, from_edges
 from repro.graph.shard import shard_layout
 
 D = 4
-mesh = make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
+mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
 
 def random_sharded(data, st, weighted):
     n = data.draw(st.integers(8, 96))
@@ -63,7 +65,7 @@ def random_sharded(data, st, weighted):
 
 @pytest.mark.slow
 def test_dist_run_batched_equals_per_query_runs():
-    _run(COMMON + """
+    _run(COMMON + textwrap.dedent("""
     from hypothesis import given, settings, strategies as st
     from repro.apps.bfs import bfs_program
     from repro.apps.sssp import sssp_program
@@ -108,12 +110,12 @@ def test_dist_run_batched_equals_per_query_runs():
                         assert same, (app, wire, k, i)
     prop()
     print("OK")
-    """)
+    """))
 
 
 @pytest.mark.slow
 def test_wire_bf16_exact_for_id_monoids():
-    _run(COMMON + """
+    _run(COMMON + textwrap.dedent("""
     from hypothesis import given, settings, strategies as st
     from repro.apps.bfs import bfs_program
 
@@ -146,12 +148,12 @@ def test_wire_bf16_exact_for_id_monoids():
             assert np.array_equal(outs[False][k], outs[True][k]), k
     prop()
     print("OK")
-    """)
+    """))
 
 
 @pytest.mark.slow
 def test_graph_server_dist_backed_matches_single_device():
-    _run(COMMON + """
+    _run(COMMON + textwrap.dedent("""
     from repro.graph import rmat
     from repro.apps.bfs import bfs
     from repro.serve import GraphQuery, GraphQueryServer
@@ -175,4 +177,4 @@ def test_graph_server_dist_backed_matches_single_device():
         assert np.array_equal(q.result["level"], seq["level"]), q.qid
         assert np.array_equal(q.result["parent"], seq["parent"]), q.qid
     print("OK")
-    """)
+    """))

@@ -101,7 +101,7 @@ def test_monoid_laws(mk, a, b, c):
 
 def test_min_with_payload_packing():
     import jax
-    with jax.experimental.enable_x64():       # uint64 lattice needs x64
+    with jax.enable_x64(True):       # uint64 lattice needs x64
         key = jnp.asarray([0.5, 0.25, 3.0], jnp.float32)
         pay = jnp.asarray([7, 9, 11], jnp.uint32)
         packed = M.pack_key_payload(key, pay)

@@ -134,3 +134,27 @@ def test_two_level_fold_out_of_range_ids_contribute_nothing():
         want[[0, 5, 9]] = 1.0
         assert np.array_equal(np.asarray(acc), want)
         assert np.array_equal(np.asarray(touched), want > 0)
+
+
+@pytest.mark.parametrize("monoid", ["add", "min", "max"])
+def test_two_level_fold_chunks_a_long_stream(monoid, monkeypatch):
+    """A stream whose work list would overflow SMEM folds chunk by chunk
+    (here 3 chunks of 2 message blocks) and still equals the oracle."""
+    from kernel_harness import MONOIDS
+    from repro.kernels import fold_two_level as ftl
+    mono = MONOIDS[(monoid, "uint32")]()
+    ns, q, tile = 1000, 64, 64              # 16 buckets, 1024-message blocks
+    monkeypatch.setattr(ftl, "WORK_LIST_SMEM", 16 * (16 + 2))
+    rng = np.random.default_rng(7)
+    n = 5000 + ["add", "min", "max"].index(monoid)   # a fresh trace each
+    vals = jnp.asarray(rng.integers(0, 2**32, n, dtype=np.uint32))
+    valid = jnp.asarray(rng.random(n) < 0.8)
+    ids = jnp.asarray(rng.integers(-2, ns + 2, n).astype(np.int32))
+    ok = valid & (ids >= 0) & (ids < ns)
+    assert_kernel_equiv(
+        lambda v, va, i: ftl.two_level_segment_fold(
+            v, va, i, ns, monoid=monoid, fold_tile=tile, fold_q=q,
+            interpret=True),
+        lambda v, va, i: segment_oracle(mono, v, ok, jnp.where(ok, i, 0),
+                                        ns),
+        (vals, valid, ids))

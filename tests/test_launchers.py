@@ -9,7 +9,10 @@ import tempfile
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+# the launchers place JAX's compile cache; point it away from the repo
+ENV = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+           JAX_COMPILATION_CACHE_DIR=tempfile.mkdtemp(
+               prefix="jax-cache-test-"))
 
 
 def _run(args, timeout=600):
