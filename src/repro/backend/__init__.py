@@ -30,7 +30,8 @@ and falls back to ``ref`` per call when a lowering is unsupported (with a
 warning on a TPU, where :data:`~repro.backend.registry.TPU_DEFAULTS`
 declares each kernel's lowering: Mosaic for ``fold``, and ``ref``, with
 the reason, for every kernel whose Mosaic form cannot serve a scale-22
-graph).  Tile
+graph, and :data:`~repro.backend.registry.TPU_REF_MONOIDS` sends the
+packed uint64 monoid to ``ref`` for every kernel).  Tile
 geometry (``edge_tile``/``msg_tile``/``fold_tile`` — the §3.1
 partition-sizing rule) is swept empirically by
 :mod:`repro.backend.tuning` and cached on disk.
@@ -144,14 +145,16 @@ degrades the scope to a ``nullcontext``.  See :mod:`repro.obs`.
 """
 from __future__ import annotations
 
-from .registry import (BACKENDS, TPU_DEFAULTS, KernelBackend,
+from .registry import (BACKENDS, TPU_DEFAULTS, TPU_REF_MONOIDS,
+                       KernelBackend,
                        available_backends, default_backend_name,
                        make_kernels, resolve, supported)
 from .tuning import (DEFAULT_GEOMETRY, TileGeometry, autotune,
                      resolve_geometry, tuned_layout)
 
 __all__ = [
-    "BACKENDS", "TPU_DEFAULTS", "KernelBackend", "available_backends",
+    "BACKENDS", "TPU_DEFAULTS", "TPU_REF_MONOIDS", "KernelBackend",
+    "available_backends",
     "default_backend_name", "make_kernels", "resolve", "supported",
     "DEFAULT_GEOMETRY", "TileGeometry", "autotune", "resolve_geometry",
     "tuned_layout",

@@ -12,12 +12,14 @@ TPU only).  Selection order:
   1. an explicit ``backend=`` argument (``Engine(..., backend=...)``),
   2. the ``REPRO_KERNEL_BACKEND`` environment variable,
   3. the platform default: :data:`TPU_DEFAULTS` on TPU (each kernel's
-     lowering named with the reason wherever it is not Mosaic), ``ref``
+     lowering named with the reason wherever it is not Mosaic, and
+     :data:`TPU_REF_MONOIDS` for monoids no Mosaic kernel folds), ``ref``
      elsewhere.
 
 If the selected backend cannot lower a particular ``(kernel, monoid,
-dtype)`` combination (e.g. a ``min_with_payload`` uint64 fold, or any
-``pallas-native`` call on a CPU host), that *call* falls back to ``ref``
+dtype)`` combination (e.g. a ``min_with_payload`` uint64 fold asked of
+Pallas, or any ``pallas-native`` call on a CPU host), that *call* falls
+back to ``ref``
 instead of failing — the rest of the engine keeps its chosen backend.
 The fallback warns whenever the backend was asked for, and always on a
 TPU: there a silent ``ref`` would hide that the declared table did not
@@ -75,6 +77,14 @@ TPU_DEFAULTS = {
     "fused_dc": ("ref", "Mosaic lowers no in-kernel table gather ('Only 2D "
                  "gather is supported'); ref is one XLA gather + one XLA "
                  "segmented fold"),
+}
+
+
+#: Monoids the TPU table sends to ``ref`` for every kernel, and why: the
+#: server's semantic cache and the parent-tracking apps fold this one.
+TPU_REF_MONOIDS = {
+    "min_with_payload": "its packed (key, payload) lattice is uint64, and "
+                        "Mosaic has no 64-bit integer vectors",
 }
 
 
@@ -200,13 +210,14 @@ def available_backends() -> tuple[str, ...]:
 
 
 def default_backend_name(platform: Optional[str] = None,
-                         kernel: Optional[str] = None) -> str:
+                         kernel: Optional[str] = None,
+                         monoid: Optional[str] = None) -> str:
     """Platform default, after the ``REPRO_KERNEL_BACKEND`` override.
 
-    The default is per-kernel: on TPU it is :data:`TPU_DEFAULTS`; off-TPU
-    ``fold`` (no efficient ``jax.ops``-free lowering exists outside
-    Pallas) defaults to the interpreted Pallas kernel, everything else to
-    ``ref``.
+    The default is per-kernel: on TPU it is :data:`TPU_DEFAULTS`, or
+    ``ref`` for a monoid in :data:`TPU_REF_MONOIDS`; off-TPU ``fold`` (no
+    efficient ``jax.ops``-free lowering exists outside Pallas) defaults
+    to the interpreted Pallas kernel, everything else to ``ref``.
     """
     env = os.environ.get(ENV_VAR)
     if env:
@@ -217,6 +228,8 @@ def default_backend_name(platform: Optional[str] = None,
         return env
     platform = platform or jax.default_backend()
     if platform == "tpu":
+        if monoid in TPU_REF_MONOIDS:
+            return "ref"
         return TPU_DEFAULTS[kernel][0] if kernel else "pallas-native"
     return "pallas-interpret" if kernel == "fold" else "ref"
 
@@ -243,7 +256,7 @@ def resolve(kernel: str, monoid, dtype=None, platform: Optional[str] = None,
     explicit = (choice is not None or bool(os.environ.get(ENV_VAR))
                 or platform == "tpu")
     if choice is None:
-        name = default_backend_name(platform, kernel)
+        name = default_backend_name(platform, kernel, mono.name)
         backend = BACKENDS[name]
     elif isinstance(choice, str):
         if choice not in BACKENDS:
@@ -326,7 +339,8 @@ def make_kernels(layout, monoid, backend=None, platform=None,
     fused = None
     platform_r = platform or jax.default_backend()
     if backend is None:
-        xb = BACKENDS[default_backend_name(platform_r, "fused_dc")]
+        xb = BACKENDS[default_backend_name(platform_r, "fused_dc",
+                                           mono.name)]
     elif isinstance(backend, str):
         xb = BACKENDS[backend]
     else:

@@ -287,26 +287,33 @@ class Engine:
             fn = self._step_cache["step"] = jax.jit(step)
         return fn
 
-    def _phase_fns(self, be: int):
+    def _phase_fns(self, be: int, dc: bool = True):
         """``(dc, sc, apply)`` jitted phases of one superstep.  The DC
         stream and the apply phase compile once per engine; only the SC
         stream compiles per budget, so a deep frontier's many budgets do
-        not each recompile the DC stream's whole-edge-set fold."""
+        not each recompile the DC stream's whole-edge-set fold.  With
+        ``dc=False`` (no DC partition this step) the first phase skips
+        the DC stream and returns identity accumulators."""
         if "dc" not in self._step_cache:
             self._step_cache["dc"] = jax.jit(self._dc_phase)
+            self._step_cache["no_dc"] = jax.jit(
+                functools.partial(self._dc_phase, stream=False))
             self._step_cache["apply"] = jax.jit(self._apply_phase)
         key = ("sc", be)
         if be and key not in self._step_cache:
             self._step_cache[key] = jax.jit(
                 functools.partial(self._sc_phase, be))
-        return (self._step_cache["dc"], self._step_cache.get(key),
-                self._step_cache["apply"])
+        return (self._step_cache["dc" if dc else "no_dc"],
+                self._step_cache.get(key), self._step_cache["apply"])
 
-    def _dc_phase(self, state, active, dc_mask, it, args):
+    def _dc_phase(self, state, active, dc_mask, it, args,
+                  stream: bool = True):
         """Scatter, initFrontier and the DC stream (paper Alg. 2:
         values-only messages over the pre-written dc_bin adjacency).
         Returns ``(state, keep, msgs_p, acc, touched)`` over ``n_pad + 1``
-        slots, the last the identity sentinel."""
+        slots, the last the identity sentinel.  ``stream=False`` skips
+        the DC stream, whose result is the identity when ``dc_mask`` is
+        all false."""
         prog, mono, n_pad = self.program, self.program.monoid, self.n_pad
         A = args["engine"]
         vert_part = A["vert_part"]
@@ -321,6 +328,9 @@ class Engine:
         else:
             keep = jnp.zeros((n_pad,), jnp.bool_)
 
+        if not stream:
+            return (state, keep, msgs_p, mono.identity_array((n_pad + 1,)),
+                    jnp.zeros((n_pad + 1,), jnp.bool_))
         if self._fused is not None:
             # fused lowering: the kernel gathers each edge's source value
             # from msgs_p itself and folds it straight into the
@@ -474,7 +484,7 @@ class Engine:
             be = (_sc_budget(int(ea[sc_sel].sum()), self._sc_cap)
                   if sc_sel.any() else 0)
             t0 = time.perf_counter()
-            dc_fn, sc_fn, apply_fn = self._phase_fns(be)
+            dc_fn, sc_fn, apply_fn = self._phase_fns(be, dc_mask.any())
             args, mask, it32 = self._args(), jnp.asarray(dc_mask), \
                 jnp.int32(it)
             state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,

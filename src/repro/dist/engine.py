@@ -141,9 +141,9 @@ def _resolve_fold(program: VertexProgram, backend=None, tile=None, q=None):
     elsewhere; :mod:`repro.kernels.fold_block` up to
     ``REPRO_FOLD_MAX_SEGMENTS`` per-device segments and the two-level
     :mod:`repro.kernels.fold_two_level` (bucket width ``q``) beyond —
-    which traces cleanly inside the shard_map step bodies; monoids
-    outside the Pallas set (e.g. the packed uint64 ``min_with_payload``)
-    fall back to ``ref`` per call."""
+    which traces cleanly inside the shard_map step bodies; the packed
+    uint64 ``min_with_payload`` folds through ``ref`` (declared in the
+    TPU table, a per-call fallback elsewhere)."""
     b = kregistry.resolve("fold", program.monoid, choice=backend)
     fold = b.segment_fold(program.monoid, tile=tile, q=q)
     return kregistry._tag_scope(fold, "fold", b.name), b.name
@@ -166,7 +166,8 @@ def _resolve_fused(program: VertexProgram, backend=None, tile=None, q=None):
     platform = jax.default_backend()
     if backend is None:
         b = kregistry.BACKENDS[
-            kregistry.default_backend_name(platform, "fused_dc")]
+            kregistry.default_backend_name(platform, "fused_dc",
+                                           mono.name)]
     elif isinstance(backend, str):
         if backend not in kregistry.BACKENDS:
             raise ValueError(

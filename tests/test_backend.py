@@ -404,15 +404,23 @@ def test_tpu_defaults_follow_the_table(monkeypatch):
 
 
 def test_tpu_fallback_to_ref_warns_even_for_defaults(monkeypatch):
-    """Off the table on a TPU (here: a monoid no Pallas kernel lowers)
-    the per-call ref fallback is never silent."""
+    """Off the table on a TPU (here: a monoid no Pallas kernel lowers and
+    the table does not name) the per-call ref fallback is never silent;
+    a monoid the table sends to ref resolves there without a word."""
     monkeypatch.delenv(registry.ENV_VAR, raising=False)
     with pytest.warns(RuntimeWarning, match="falling back to 'ref'"):
-        b = registry.resolve("fold", M.min_with_payload(), platform="tpu")
+        b = registry.resolve("fold", M.or_(), platform="tpu")
     assert b.name == "ref"
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        registry.resolve("fold", M.min_with_payload(), platform="cpu")
+        registry.resolve("fold", M.or_(), platform="cpu")
+        for kernel in registry.KERNELS:
+            if kernel != "spmv":
+                assert registry.resolve(kernel, M.min_with_payload(),
+                                        platform="tpu").name == "ref"
+        assert registry.default_backend_name(
+            "tpu", "fold", "min_with_payload") == "ref"
+    assert set(registry.TPU_REF_MONOIDS) == {"min_with_payload"}
 
 
 @pytest.mark.parametrize("kernel", ["gather", "fused_dc"])
@@ -468,6 +476,19 @@ def test_step_cache_is_per_instance(layout):
     del eng, fn
     gc.collect()
     assert ref() is None, "engine must be collectable once dropped"
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+def test_sc_only_supersteps_skip_the_dc_stream(layout, backend):
+    """A superstep with no DC partition runs the phase without the DC
+    stream (never tracing it) and gives the DC-mode answer."""
+    eng = Engine(layout, bfs_program(), mode="sc", backend=backend)
+    got = bfs(layout, source=3, engine=eng)
+    assert eng._step_cache["dc"]._cache_size() == 0
+    assert eng._step_cache["no_dc"]._cache_size() == 1
+    want = bfs(layout, source=3, mode="dc", backend=backend)
+    assert np.array_equal(got["level"], want["level"])
+    assert np.array_equal(got["parent"], want["parent"])
 
 
 # ----------------------------------------------------------------------
