@@ -8,8 +8,9 @@ Times one compiled call of each of ``gather`` (segment_combine), ``scatter``
 (dc_gather), ``spmv`` (spmv_block), ``fold`` (fold_block — the blocked
 segmented fold behind the distributed gather), ``fold2`` (fold_two_level
 — the same fold on an over-cap segment count, where the two-level bucketed
-kernel runs) and ``fused`` (fused_step — the single-launch fused DC step
-that replaces scatter→gather→fold) for every backend the registry can
+kernel runs) and ``fused`` (fused_step — the fused DC step, a table
+gather and the two-level fold in place of scatter→gather→fold) for
+every backend the registry can
 lower on this platform,
 across rmat graph scales, and writes the results to ``BENCH_kernels.json``
 at the repo root — the perf-trajectory artifact every hot-path PR

@@ -20,7 +20,9 @@ engine:
       equal to a float64 numpy power iteration within 1e-5 and 1e-4
       relative per vertex, 1e-5 relative in l1;
   (c) serving: ``GraphQueryServer`` answers 8 Zipf-drawn BFS queries in
-      one batched run; each answer equals a per-source ``bfs``.
+      one batched run (its hybrid engine steps the lanes in lockstep,
+      each through its own DC/SC split); each answer equals a
+      per-source ``bfs``.
 
 ``--chips 4`` runs only the distributed phase: ``DistEngine`` BFS and 10
 PageRank iterations over ``shard_layout(L, 4)`` on a 4-device mesh,
@@ -74,7 +76,13 @@ def require(ok, msg: str):
         raise CheckFailed(msg)
 
 
+T0 = time.perf_counter()
+
+
 def log(tag: str, **fields):
+    """One tagged JSON line; ``t`` is host seconds since the script
+    started."""
+    fields["t"] = time.perf_counter() - T0
     print(f"[{tag}] " + json.dumps(fields, default=str), flush=True)
 
 
@@ -234,14 +242,14 @@ def engine_kernels(eng, dc_only: bool = False) -> dict:
 # ----------------------------------------------------------------------
 
 def phase_bfs(g, L, roots, want_levels, keys, platform: str):
-    """(a) hybrid BFS through ``repro.apps.bfs`` from every root, twice:
-    the first pass compiles, the second is warm."""
+    """(a) hybrid BFS through ``repro.apps.bfs`` from every root (the
+    first pass, which compiles), then from the first root again (warm)."""
     from repro.apps.bfs import bfs, bfs_program
     from repro.core.engine import Engine
     eng = Engine(L, bfs_program(), mode="hybrid")
     check_lowerings(eng.backend_names, engine_kernels(eng), platform, "bfs")
-    for pass_name in ("first", "warm"):
-        for i, r in enumerate(roots):
+    for pass_name, todo in (("first", roots), ("warm", roots[:1])):
+        for i, r in enumerate(todo):
             res, sec = timed(bfs, L, r, engine=eng)
             reached = check_bfs_tree(g, keys, r, res["level"],
                                      res["parent"], want_levels[i])
@@ -250,6 +258,7 @@ def phase_bfs(g, L, roots, want_levels, keys, platform: str):
             log(f"bfs.{pass_name}", root=r, seconds=sec,
                 supersteps=len(modes), reached=reached,
                 dc_sc_parts=[[s.dc_parts, s.sc_parts] for s in res["stats"]],
+                step_s=[s.wall_s for s in res["stats"]],
                 modes={m: modes.count(m) for m in sorted(set(modes))},
                 teps=edges / sec)
     return eng
@@ -278,7 +287,9 @@ def phase_serve(L, sources, bfs_engine, platform: str):
     from repro.apps.bfs import bfs
     from repro.serve import GraphQuery, GraphQueryServer, ServeConfig
     srv = GraphQueryServer(L, ServeConfig(max_batch=len(sources)))
-    want = {s: bfs(L, s, engine=bfs_engine) for s in set(sources)}
+    want, sec = timed(lambda: {s: bfs(L, s, engine=bfs_engine)
+                               for s in set(sources)})
+    log("serve.reference", seconds=sec, sources=len(want))
     for pass_name in ("first", "warm"):
         srv.clear_cache()
         for i, s in enumerate(sources):
@@ -297,8 +308,8 @@ def phase_serve(L, sources, bfs_engine, platform: str):
         log(f"serve.{pass_name}", seconds=sec, queries=len(sources),
             distinct_sources=len(set(sources)), qps=len(sources) / sec)
     eng = srv._engines["bfs"]
-    check_lowerings(eng.backend_names, engine_kernels(eng, dc_only=True),
-                    platform, "serve")
+    check_lowerings(eng.backend_names, engine_kernels(eng), platform,
+                    "serve")
 
 
 def phase_dist(g, L, roots, want_levels, keys, want_pr, devices,

@@ -94,14 +94,13 @@ gather re-reads it into an ``[NE]`` per-edge value stream, and the
 gather-side fold collapses that into the per-partition accumulators —
 two HBM round-trips per superstep for data that is only ever consumed
 once.  The *fused* path is registry kernel ``fused_dc``
-(:mod:`repro.kernels.fused_step`): one Pallas launch whose grid walks
-``(segment buckets × edge tiles)``, gathers each edge's source value
-straight from the VMEM-resident message table, applies the optional
-edge function, and folds into the two-level ``[fold_q]``
-sub-accumulators — neither intermediate ever materializes.  Mosaic
-cannot lower that in-kernel gather, so on a TPU ``fused_dc`` defaults
-to its ``ref`` lowering (one XLA gather + one XLA segmented fold), an
-entry of :data:`~repro.backend.registry.TPU_DEFAULTS`.  Its stream
+(:mod:`repro.kernels.fused_step`): XLA gathers each edge's source value
+straight from the message table, the optional edge function runs on
+it, and the two-level Pallas fold folds the stream into the
+``[fold_q]`` sub-accumulators — no bin buffer, no XLA scatter.  The
+layout-bound kernel keeps its static edge stream in destination order,
+so its fold needs no per-call sort; Mosaic lowers it, and it is the
+TPU default (:data:`~repro.backend.registry.TPU_DEFAULTS`).  Its stream
 contract mirrors the fold's::
 
     acc, touched = fused(table, table_valid, idx, edge_valid, dst,

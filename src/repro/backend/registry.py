@@ -57,9 +57,9 @@ from ..kernels import ops as kops
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 KERNELS = ("gather", "scatter", "spmv", "fold", "fused_dc")
 PALLAS_MONOIDS = ("add", "min", "max")
-#: kernels whose Pallas bodies Mosaic compiles (``fused_dc`` gathers from
-#: its table inside the kernel, which Mosaic cannot lower)
-MOSAIC_KERNELS = ("gather", "scatter", "spmv", "fold")
+#: kernels whose Pallas bodies Mosaic compiles (``fused_dc`` gathers in
+#: XLA and folds with the two-level kernel)
+MOSAIC_KERNELS = ("gather", "scatter", "spmv", "fold", "fused_dc")
 
 #: The TPU table: each kernel's default lowering on a TPU, and why, where
 #: it is not the Mosaic kernel.  ``chip_smoke.py`` prints it and fails
@@ -74,9 +74,7 @@ TPU_DEFAULTS = {
     "spmv": ("ref", "its one-hot select gather makes q = 131072 compares "
              "per edge and takes ~140 s to compile there"),
     "fold": ("pallas-native", None),
-    "fused_dc": ("ref", "Mosaic lowers no in-kernel table gather ('Only 2D "
-                 "gather is supported'); ref is one XLA gather + one XLA "
-                 "segmented fold"),
+    "fused_dc": ("pallas-native", None),
 }
 
 

@@ -26,11 +26,12 @@ jointly with ``fold_tile``: the two-level one-hot block is
 grid revisits) couples the two knobs.  The ``fold2`` kernel row times the
 two-level path on an over-cap synthetic stream so the sweep can actually
 observe ``fold_q`` (below the cap the registry fold never runs it), and
-the ``fused`` row times the fused scatter→fold DC step
+the ``fused`` row times the fused DC step
 (:mod:`repro.kernels.fused_step`) on the layout's real edge stream —
-its grid is ``(segments/fold_q, edges/edge_tile)``, so that row sweeps
-the ``edge_tile × fold_q`` cross-product directly; winners land in the
-same cached geometry :func:`repro.graph.layout.build_layout` consults.
+its fold runs ``[fold_tile, fold_q]`` one-hots over the
+destination-sorted stream, so that row sweeps the ``fold_tile ×
+fold_q`` cross-product on real data; winners land in the same cached
+geometry :func:`repro.graph.layout.build_layout` consults.
 
 Cache entries are keyed by (platform, backend, log2-bucketed graph size,
 partition count): geometry is a property of the memory hierarchy and the
@@ -159,10 +160,9 @@ def time_layout(layout, backend_name: str, platform: str,
     because the fold's default backend (Pallas everywhere) differs from
     the other kernels' and ``RefFold``/``RefFusedDC`` ignore the tile
     knobs — sweeping them through ref would select the winner by timing
-    jitter.  The ``fused`` row times the fused scatter→fold DC step on
-    the layout's real edge stream, so the sweep observes the
-    ``edge_tile × fold_q`` cross-product the fused kernel's grid is
-    built from."""
+    jitter.  The ``fused`` row times the fused DC step on the layout's
+    real edge stream, so the sweep observes the ``fold_tile × fold_q``
+    cross-product its fold is built from."""
     rng = np.random.default_rng(0)
     out = {}
     dtype = jnp.float32
@@ -219,9 +219,9 @@ def time_layout(layout, backend_name: str, platform: str,
                    np.sort(rng.integers(0, ns2 - 1, layout.num_edges))
                    .astype(np.int32))
     if "fused" in kernels:
-        # the fused DC step over the layout's real edge stream: its grid
-        # is (segments/fold_q, edges/edge_tile), so this row is the one
-        # place the sweep observes the edge_tile × fold_q cross-product
+        # the fused DC step over the layout's real edge stream: its fold
+        # walks the destination-sorted stream in [fold_tile, fold_q]
+        # one-hots, the fold geometry on real data
         b = registry.resolve("fused_dc", monoid, dtype=dtype,
                              platform=platform,
                              choice=fold_backend or backend_name)

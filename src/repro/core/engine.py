@@ -249,15 +249,17 @@ class Engine:
         self.arrays = {k: None if v is None else jnp.asarray(v)
                        for k, v in arrays.items()}
 
-        # per-partition reductions used by the host-side mode decision
+        # per-partition reductions used by the host-side mode decision;
+        # partitions are contiguous q-vertex ranges, so a reshape-sum
+        # (over a leading lane axis too, for the lockstep batched loop)
         @jax.jit
-        def _part_stats(active, vert_part, deg):
+        def _part_stats(active, deg):
             a32 = active.astype(jnp.int32)
-            counts = jax.ops.segment_sum(a32, vert_part, num_segments=L.k)
-            ea = jax.ops.segment_sum(a32 * deg, vert_part, num_segments=L.k)
-            return counts, ea
-        self._part_stats = lambda active: _part_stats(
-            active, self.arrays["vert_part"], self.arrays["deg"])
+            lead = a32.shape[:-1]
+            return (a32.reshape(lead + (L.k, L.q)).sum(-1),
+                    (a32 * deg).reshape(lead + (L.k, L.q)).sum(-1))
+        self._part_stats = lambda active: _part_stats(active,
+                                                      self.arrays["deg"])
         self._step_cache = {}            # SC budget / phase -> jitted fn
         self._sc_cap = _next_pow2(max(int(L.deg.sum()), 1))
 
@@ -367,16 +369,19 @@ class Engine:
         A = args["engine"]
         sc_active = active & ~dc_mask[A["vert_part"]]
         degs = jnp.where(sc_active, A["deg"], 0)               # [n_pad]
-        cum = jnp.cumsum(degs)
-        total = cum[-1]
+        start = jnp.cumsum(degs) - degs       # first slot of each row
+        total = start[-1] + degs[-1]
         j = jnp.arange(be, dtype=jnp.int32)
-        # slot j belongs to the first vertex whose running degree sum
-        # passes j; slots past the total are padding
-        src_v = jnp.minimum(jnp.searchsorted(cum, j, side="right"),
-                            n_pad - 1)
+        # slot j belongs to the last nonempty row starting at or before
+        # j: mark each row's first slot with its vertex and carry the
+        # marks forward (a binary search would gather log2(n_pad) times
+        # per slot); slots past the total are padding
+        first = jnp.where((degs > 0) & (start < be), start, be)
+        src_v = jax.lax.cummax(jnp.zeros((be,), jnp.int32).at[first].max(
+            jnp.arange(n_pad, dtype=jnp.int32), mode="drop"))
         valid = j < total
-        e_idx = jnp.where(valid, A["csr_indptr"][src_v]
-                          + (j - (cum - degs)[src_v]), 0)
+        row_off = A["csr_indptr"][:n_pad].astype(jnp.int32) - start
+        e_idx = jnp.where(valid, j + row_off[src_v], 0)
         dst = jnp.where(valid, A["csr_indices"][e_idx],
                         n_pad).astype(jnp.int32)
         vals = msgs_p[src_v]
@@ -471,48 +476,59 @@ class Engine:
             n_active = int(counts.sum())
             if until_empty and n_active == 0:
                 break
-            has_active = counts > 0
-            if self.mode == "dc":
-                dc_mask = has_active
-            elif self.mode == "sc":
-                dc_mask = np.zeros(self.k, bool)
-            else:
-                dc_mask = self.cost.choose_dc(ea, has_active)
-            sc_sel = (~dc_mask) & has_active
-            # SC edge budget: the active edges of SC partitions, rounded
-            # up to the budget grid (0: no SC stream this step)
-            be = (_sc_budget(int(ea[sc_sel].sum()), self._sc_cap)
-                  if sc_sel.any() else 0)
             t0 = time.perf_counter()
-            dc_fn, sc_fn, apply_fn = self._phase_fns(be, dc_mask.any())
-            args, mask, it32 = self._args(), jnp.asarray(dc_mask), \
-                jnp.int32(it)
-            state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,
-                                                      it32, args)
-            if be:
-                acc, touched = sc_fn(msgs_p, active, mask, acc, touched,
-                                     args)
-            state, active = apply_fn(state, keep, acc, touched, it32)
+            state, active, dc_mask, sc_sel = self._superstep(
+                state, active, it, counts, ea)
             jax.block_until_ready(active)
             if collect_stats:
-                b = self.cost.bytes_for(dc_mask, ea, has_active)
-                dc_p, sc_p = int(dc_mask.sum()), int(sc_sel.sum())
-                mode_str = ("dc" if sc_p == 0 else
-                            "sc" if dc_p == 0 else "hybrid")
-                st = obs.IterStats(
-                    it=it, n_active=n_active, e_active=int(ea.sum()),
-                    dc_parts=dc_p, sc_parts=sc_p,
-                    dc_bytes=b["dc_bytes"], sc_bytes=b["sc_bytes"],
-                    wall_s=time.perf_counter() - t0,
-                    mode=mode_str, program=self.program.name)
-                stats.append(st)
-                # dc_e/sc_e split the active-edge count by stream: pure
-                # dc/sc steps give the online Eq. 1 calibration clean
-                # single-mode (size, time) points
-                obs.record_engine_iter(
-                    "core", st,
-                    dc_e=int(ea[dc_mask].sum()), sc_e=int(ea[sc_sel].sum()))
+                stats.append(self._record_iter(it, n_active, ea, counts,
+                                               dc_mask, sc_sel,
+                                               time.perf_counter() - t0))
         return state, active, stats
+
+    def _superstep(self, state, active, it: int, counts, ea):
+        """One superstep from the host-side partition stats ``counts`` /
+        ``ea`` (active vertices / active edges per partition): the Eq. 1
+        split, then the DC, SC and apply phases.  Returns ``(state,
+        active, dc_mask, sc_sel)``."""
+        has_active = counts > 0
+        if self.mode == "dc":
+            dc_mask = has_active
+        elif self.mode == "sc":
+            dc_mask = np.zeros(self.k, bool)
+        else:
+            dc_mask = self.cost.choose_dc(ea, has_active)
+        sc_sel = (~dc_mask) & has_active
+        # SC edge budget: the active edges of SC partitions, rounded up to
+        # the budget grid (0: no SC stream this step)
+        be = (_sc_budget(int(ea[sc_sel].sum()), self._sc_cap)
+              if sc_sel.any() else 0)
+        dc_fn, sc_fn, apply_fn = self._phase_fns(be, dc_mask.any())
+        args, mask, it32 = self._args(), jnp.asarray(dc_mask), jnp.int32(it)
+        state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,
+                                                  it32, args)
+        if be:
+            acc, touched = sc_fn(msgs_p, active, mask, acc, touched, args)
+        state, active = apply_fn(state, keep, acc, touched, it32)
+        return state, active, dc_mask, sc_sel
+
+    def _record_iter(self, it, n_active, ea, counts, dc_mask, sc_sel, wall):
+        """The superstep's :class:`~repro.obs.IterStats`, also recorded
+        through :func:`repro.obs.record_engine_iter`."""
+        b = self.cost.bytes_for(dc_mask, ea, counts > 0)
+        dc_p, sc_p = int(dc_mask.sum()), int(sc_sel.sum())
+        mode_str = "dc" if sc_p == 0 else "sc" if dc_p == 0 else "hybrid"
+        st = obs.IterStats(
+            it=it, n_active=n_active, e_active=int(ea.sum()),
+            dc_parts=dc_p, sc_parts=sc_p,
+            dc_bytes=b["dc_bytes"], sc_bytes=b["sc_bytes"],
+            wall_s=wall, mode=mode_str, program=self.program.name)
+        # dc_e/sc_e split the active-edge count by stream: pure dc/sc
+        # steps give the online Eq. 1 calibration clean single-mode
+        # (size, time) points
+        obs.record_engine_iter("core", st, dc_e=int(ea[dc_mask].sum()),
+                               sc_e=int(ea[sc_sel].sum()))
+        return st
 
     # ------------------------------------------------------------------
     def _batched_step_fn(self, B: int):
@@ -570,14 +586,63 @@ class Engine:
         (packed to the next power-of-two width, so at most log2(B)
         distinct step shapes ever compile).  Results are bit-exact with
         B sequential :meth:`run` calls in mode='dc'.
+
+        That vmapped DC-only step is the ``mode='dc'`` engine's.  A
+        ``hybrid`` or ``sc`` engine instead advances the lanes in
+        lockstep, each through its own Eq. 1 DC/SC split and the same
+        compiled phases as :meth:`run` (one host read of every lane's
+        partition stats per superstep): bit-exact with B sequential
+        :meth:`run` calls in that mode, and a lane whose frontier is
+        small pays for its active edges, not for a whole-edge DC pass.
         """
         active = jnp.asarray(frontiers, jnp.bool_)
         assert active.ndim == 2, "frontiers must be [B, n_pad]"
         states = jax.tree_util.tree_map(jnp.asarray, states)
+        if self.mode != "dc":
+            return self._run_lockstep(states, active, max_iters,
+                                      until_empty, collect_stats)
         return _run_batched_loop(self._batched_step_fn, states, active,
                                  max_iters, until_empty, collect_stats,
                                  engine_name="core",
                                  program=self.program.name)
+
+    def _run_lockstep(self, states, active, max_iters, until_empty,
+                      collect_stats):
+        """:meth:`run_batched` for a hybrid or sc engine: every lane with
+        an active vertex takes one :meth:`_superstep` per iteration."""
+        tmap = jax.tree_util.tree_map
+        B = active.shape[0]
+        lanes = [tmap(lambda a, i=i: a[i], states) for i in range(B)]
+        acts = [active[i] for i in range(B)]
+        stats = []
+        for it in range(max_iters):
+            counts, ea = self._part_stats(jnp.stack(acts))
+            counts, ea = np.asarray(counts), np.asarray(ea)
+            live = np.nonzero(counts.sum(axis=1) > 0)[0]
+            if not len(live):
+                if until_empty:
+                    break
+                continue    # every phase masks on active: a no-op step
+            t0 = time.perf_counter()
+            for i in live:
+                lanes[i], acts[i], _, _ = self._superstep(
+                    lanes[i], acts[i], it, counts[i], ea[i])
+            jax.block_until_ready(acts)
+            wall = time.perf_counter() - t0
+            if collect_stats:
+                n_act = int(counts.sum())
+                stats.append(obs.BatchIterStats(
+                    it=it, lanes_active=len(live), n_active=n_act,
+                    wall_s=wall))
+                if obs.enabled():
+                    obs.event("batch_iter", engine="core",
+                              program=self.program.name, it=it,
+                              lanes_active=len(live), n_active=n_act,
+                              width=len(live), wall_s=wall)
+                    obs.observe("engine.batch_step_wall_s", wall,
+                                engine="core", program=self.program.name)
+        return (tmap(lambda *xs: jnp.stack(xs), *lanes), jnp.stack(acts),
+                stats)
 
     # ------------------------------------------------------------------
     def run_fused(self, state, frontier, iters: int):

@@ -127,11 +127,11 @@ def bucket_work_list(ids_sorted, num_buckets: int, q: int, tile: int):
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "monoid",
                                              "fold_tile", "fold_q",
-                                             "interpret"))
+                                             "interpret", "presorted"))
 def two_level_segment_fold(vals, valid, ids, num_segments: int, *,
                            monoid: str = "add", fold_tile: int = 256,
                            fold_q: int = DEFAULT_FOLD_Q,
-                           interpret: bool = True):
+                           interpret: bool = True, presorted: bool = False):
     """Segmented monoid fold via per-bucket VMEM sub-accumulators.
 
     Same contract as :func:`repro.kernels.fold_block.blocked_segment_fold`
@@ -146,6 +146,11 @@ def two_level_segment_fold(vals, valid, ids, num_segments: int, *,
              size; VMEM use is bounded by ``fold_tile x fold_q``.
       fold_tile: messages per one-hot combine.
       fold_q: segments per coarse bucket (the sub-accumulator width).
+      presorted: ``ids`` are already non-decreasing, whatever ``valid``
+             says (a static stream in segment order, such as the DC
+             stream sorted by destination once at layout time): the sort
+             is skipped, the work list comes from ``ids`` as given, and
+             invalid slots keep their place and match no column.
     Returns:
       acc [num_segments] monoid fold, touched [num_segments] bool.
     """
@@ -169,13 +174,22 @@ def two_level_segment_fold(vals, valid, ids, num_segments: int, *,
     sentinel = nb * q                     # sorts last, lands in no bucket
     ids = ids.astype(jnp.int32)
     ok = (valid > 0) & (ids >= 0) & (ids < num_segments)
-    ids = jnp.pad(jnp.where(ok, ids, sentinel), (0, n_pad - n),
-                  constant_values=sentinel)
     vals = jnp.pad(vals, (0, n_pad - n), constant_values=ident)
-    # unstable: the fold does not care about order within a segment, and
-    # a stable two-operand sort compiles ~5x slower on a TPU (90 s at
-    # 2^25 messages, against 18 s)
-    ids, vals = jax.lax.sort((ids, vals), num_keys=1, is_stable=False)
+    if presorted:
+        # the work list follows the stream's own order; an invalid slot
+        # stays where it is with id -1, below every bucket's base
+        order = jnp.pad(jnp.clip(ids, 0, sentinel), (0, n_pad - n),
+                        constant_values=sentinel)
+        ids = jnp.pad(jnp.where(ok, ids, -1), (0, n_pad - n),
+                      constant_values=-1)
+    else:
+        ids = jnp.pad(jnp.where(ok, ids, sentinel), (0, n_pad - n),
+                      constant_values=sentinel)
+        # unstable: the fold does not care about order within a segment,
+        # and a stable two-operand sort compiles ~5x slower on a TPU (90 s
+        # at 2^25 messages, against 18 s)
+        ids, vals = jax.lax.sort((ids, vals), num_keys=1, is_stable=False)
+        order = ids
 
     tile_spec = pl.BlockSpec((blk,), lambda w, wb, wf, wa, wt: (wt[w],))
     acc_spec = pl.BlockSpec((None, 1, q),
@@ -190,25 +204,26 @@ def two_level_segment_fold(vals, valid, ids, num_segments: int, *,
                    jax.ShapeDtypeStruct((nb, 1, q), jnp.int32)],
         interpret=interpret)
 
-    def fold_chunk(ids, vals):
-        return fold(*bucket_work_list(ids, nb, q, blk), vals, ids)
+    def fold_chunk(order, ids, vals):
+        return fold(*bucket_work_list(order, nb, q, blk), vals, ids)
 
     if nc == 1:
-        acc, touched = fold_chunk(ids, vals)
+        acc, touched = fold_chunk(order, ids, vals)
     else:
         # each chunk of the sorted stream is sorted: fold one after
         # another and combine the partials
         combine = {"add": jnp.add, "min": jnp.minimum,
                    "max": jnp.maximum}[monoid]
 
-        def step(carry, chunk_ids_vals):
-            a, t = fold_chunk(*chunk_ids_vals)
+        def step(carry, chunk):
+            a, t = fold_chunk(*chunk)
             return (combine(carry[0], a), jnp.maximum(carry[1], t)), None
 
         init = (jnp.full((nb, 1, q), ident, vals.dtype),
                 jnp.zeros((nb, 1, q), jnp.int32))
         (acc, touched), _ = jax.lax.scan(
-            step, init, (ids.reshape(nc, -1), vals.reshape(nc, -1)))
+            step, init, (order.reshape(nc, -1), ids.reshape(nc, -1),
+                         vals.reshape(nc, -1)))
     # stage 2: buckets tile the segment space disjointly, so combining the
     # per-bucket partials into the flat output is a relayout, not a fold
     return (from_signed(acc.reshape(-1)[:num_segments], monoid, dtype),

@@ -1,56 +1,28 @@
-"""Fused scatter→fold DC step — the Gather phase without the message stream.
+"""Fused DC step — the Gather phase without the bin buffer.
 
 The composed DC lowering materializes every message twice: the Scatter
 kernel writes the full ``[NM]`` bin buffer (values only, the paper's
 pre-written ``dc_bin``), the slot gather re-reads it into a ``[NE]``
 edge-value stream, and only then does the segmented fold collapse it into
-the per-partition accumulators.  Both intermediates round-trip HBM — the
-single largest remaining memory-traffic cost of the reproduction, and
-exactly the locality argument of the source paper (and of "Making Caches
-Work for Graph Analytics": partition-private accumulators should absorb
-messages while they are still hot).
+the per-partition accumulators.
 
-This kernel fuses the whole chain: per edge tile it *gathers* the source
-value straight out of the vertex-message table, applies the optional edge
-function (``apply_weight``), and folds the result directly into the
-``[fold_q]`` VMEM-resident sub-accumulators of the two-level layout
-(:mod:`repro.kernels.fold_two_level`).  No intermediate message stream
-ever hits HBM; Pallas' automatic input-block pipelining double-buffers
-the edge-tile fetches against the combine.
+This step goes straight from the vertex-message table to the fold: XLA
+gathers each edge's source value and validity out of the ``[n_pad + 1]``
+table (identity sentinel last), the optional edge
+function (``apply_weight``) runs on the gathered values, and the
+two-level Pallas fold (:mod:`repro.kernels.fold_two_level`) folds them
+into the ``[fold_q]`` bucket sub-accumulators.  No ``[NM]`` buffer and
+no XLA scatter: on a TPU the fold is the Mosaic kernel, and the only
+random access left is the gather.
 
-Structure (the two-level fold, with the gather pulled inside):
+Mosaic lowers no vector gather from a table of this size inside a kernel
+("Only 2D gather is supported"), which is why the gather stays in XLA.
+(One gather of ``[M, 2]`` rows in place of the two would pad each row
+to a 128-lane tile on a TPU.)
 
-  * grid ``(nb, nt)``: ``nb = ceil(num_segments / fold_q)`` coarse
-    destination buckets × ``nt = ceil(NE / edge_tile)`` edge tiles;
-  * the message table (``[n_pad + 1]`` vertex values + identity sentinel)
-    rides along as a constant-index-map input block, resident across the
-    whole grid;
-  * bucket ``b``'s ``[1, fold_q]`` sub-accumulator (a row of the
-    ``[nb, 1, fold_q]`` output) is the revisited output block
-    (initialized at ``t == 0``, accumulated across the inner sweep);
-  * per-tile bucket ranges ``[bmin, bmax]`` — computed from the
-    *structurally valid* destinations before the ``pallas_call`` and
-    scalar-prefetched into SMEM — predicate each grid step, so the
-    destination-sorted dc_bin streams do ~``nb + nt`` body runs, not
-    ``nb × nt``;
-  * the combine is the same masked one-hot VPU reduction as the fold
-    kernels (the MXU one-hot matmul stays off the table for the
-    NaN/int-truncation reasons documented in
-    :mod:`repro.kernels.fold_block`).
-
-Validity is resolved *inside* the kernel: an edge contributes iff its
-static slot is real (``edge_valid``) AND its source vertex is live in the
-table (``table_valid`` — the engines pass ``active & dc_mask`` there), so
-the host never materializes a per-edge validity stream either.
-
-The in-kernel table gather (``table[idx]``) is an arbitrary dynamic
-vector gather.  Interpret mode executes it as a plain jnp gather on any
-host; Mosaic refuses it ("Only 2D gather is supported": the TPU lowers
-only in-register gathers), and the resident ``[n_pad + 1]`` table alone
-would be 16 MiB per array at 2^22 vertices.  So ``pallas-native`` does
-not register this kernel, and the TPU default for ``fused_dc`` is the
-``ref`` lowering (one XLA gather + one XLA segmented fold), named in
-:data:`repro.backend.registry.TPU_DEFAULTS`.
+A stream already in destination order (``presorted=True``: the
+layout-bound kernel sorts its static DC stream by destination once) folds
+without the fold's per-call sort.
 
 Env: ``REPRO_FUSED=0`` opts the engines out of fused selection entirely
 (they silently fall back to the composed scatter→fold path, which also
@@ -63,12 +35,9 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .fold_block import combine_tile, default_fold_tile
-from .fold_two_level import default_fold_q
-from .segment_combine import _identity_val
+from .fold_block import default_fold_tile
+from .fold_two_level import default_fold_q, two_level_segment_fold
 
 ENV_FUSED = "REPRO_FUSED"
 
@@ -79,54 +48,22 @@ def fused_enabled() -> bool:
     return os.environ.get(ENV_FUSED, "1") != "0"
 
 
-def _kernel(bmin_ref, bmax_ref,                        # SMEM (per tile)
-            table_ref, tvalid_ref,                     # resident table in
-            idx_ref, evalid_ref, dst_ref, w_ref,       # VMEM in (one tile)
-            acc_ref, touched_ref,                      # VMEM out (bucket)
-            *, monoid: str, q: int, apply_weight):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref,
-                                     _identity_val(monoid, acc_ref.dtype))
-        touched_ref[...] = jnp.zeros_like(touched_ref)
-
-    # bucket-range predication over the structurally valid destinations:
-    # tiles that cannot land a message in bucket b are skipped entirely
-    @pl.when((bmin_ref[t] <= b) & (b <= bmax_ref[t]))
-    def _body():
-        idx = idx_ref[...]                              # [T]
-        # the fused gather: source values pulled straight from the
-        # resident message table — no [NE] edge-value stream in HBM
-        vals = table_ref[...][idx]                      # [T]
-        valid = (tvalid_ref[...][idx] > 0) & (evalid_ref[...] > 0)
-        if apply_weight is not None:
-            vals = apply_weight(vals, w_ref[...]).astype(acc_ref.dtype)
-        # offset inside bucket b; invalid edges carry -1 and match no
-        # column (a bool [T] -> [T, 1] relayout does not lower on Mosaic)
-        off = jnp.where(valid, dst_ref[...], -1) - b * q
-        cols = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], q), 1)
-        combine_tile(acc_ref, touched_ref, vals, off[:, None] == cols,
-                     monoid=monoid)
-
-
 @functools.partial(jax.jit, static_argnames=("num_segments", "monoid",
                                              "edge_tile", "fold_q",
-                                             "interpret", "apply_weight"))
+                                             "interpret", "apply_weight",
+                                             "presorted"))
 def fused_scatter_fold(table, table_valid, idx, edge_valid, dst,
                        num_segments: int, *, monoid: str = "add",
                        edge_tile: int = 256,
                        fold_q: int = None,
                        interpret: bool = True,
-                       apply_weight=None, w=None):
-    """Gather-from-table + edge function + two-level segmented fold, fused.
+                       apply_weight=None, w=None, presorted: bool = False):
+    """Gather-from-table + edge function + two-level segmented fold.
 
     Contract (registry kernel ``fused_dc``):
 
-      table:       [M] source value per table slot (the engines pass the
-                   vertex message array + identity sentinel).
+      table:       [M] 4-byte source value per table slot (the engines
+                   pass the vertex message array + identity sentinel).
       table_valid: [M] bool/int; a slot's messages contribute nothing
                    when its source is invalid (inactive / non-DC).
       idx:         [NE] int32 table slot per edge (clamped into range;
@@ -136,62 +73,27 @@ def fused_scatter_fold(table, table_valid, idx, edge_valid, dst,
                    ``[0, num_segments)`` contribute nothing.
       num_segments: static segment count (engines pass ``n_pad + 1`` /
                    ``nv + 1``; the overflow bin is the last segment).
+      edge_tile:   messages per one-hot combine of the fold.
       apply_weight: optional static edge function ``f(vals, w)`` applied
                    to the gathered values (the composed path applies it
                    to the same inputs elementwise, so parity is exact).
       w:           [NE] edge weights; required iff apply_weight is set.
+      presorted:   ``dst`` is non-decreasing (see
+                   :func:`~repro.kernels.fold_two_level.two_level_segment_fold`).
     Returns:
       acc [num_segments] monoid fold, touched [num_segments] bool —
       an edge contributes iff ``table_valid[idx] & edge_valid``.
     """
-    ns = int(num_segments)
-    q = int(fold_q) if fold_q else default_fold_q()
-    tile = int(edge_tile) if edge_tile else default_fold_tile()
-    ne = idx.shape[0]
-    nt = max(1, -(-ne // tile))
-    ne_pad = nt * tile
-    nb = max(1, -(-ns // q))
-    ident = _identity_val(monoid, table.dtype)
-
     idx = jnp.clip(idx.astype(jnp.int32), 0, table.shape[0] - 1)
-    idx = jnp.pad(idx, (0, ne_pad - ne))
-    evalid = jnp.pad(edge_valid.astype(jnp.int32), (0, ne_pad - ne))
-    dst = jnp.pad(dst.astype(jnp.int32), (0, ne_pad - ne))
+    vals = table[idx]
+    valid = table_valid.astype(bool)[idx] & (edge_valid > 0)
     if apply_weight is not None:
-        w = jnp.pad(w, (0, ne_pad - ne))
-    else:
-        # dummy lane so the in_specs are static; never read by the body
-        w = jnp.zeros((ne_pad,), table.dtype)
-
-    # per-tile coarse-bucket ranges over the structurally valid edges: a
-    # conservative superset (the table-validity side is resolved in the
-    # kernel), exact for the frontier-independent dc_bin structure — an
-    # all-invalid tile gets the empty range [nb, -1] and is never entered
-    vb = evalid > 0
-    bt = jnp.where(vb, dst // q, -1)
-    bmax = jnp.clip(jnp.max(bt.reshape(nt, tile), axis=1), -1, nb - 1)
-    bmin = jnp.clip(
-        jnp.min(jnp.where(vb, dst // q, nb).reshape(nt, tile), axis=1),
-        0, nb)
-
-    m = table.shape[0]
-    tile_spec = pl.BlockSpec((tile,), lambda b, t, lo, hi: (t,))
-    table_spec = pl.BlockSpec((m,), lambda b, t, lo, hi: (0,))
-    acc_spec = pl.BlockSpec((None, 1, q), lambda b, t, lo, hi: (b, 0, 0))
-    acc, touched = pl.pallas_call(
-        functools.partial(_kernel, monoid=monoid, q=q,
-                          apply_weight=apply_weight),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(nb, nt),
-            in_specs=[table_spec, table_spec] + [tile_spec] * 4,
-            out_specs=[acc_spec, acc_spec]),
-        out_shape=[jax.ShapeDtypeStruct((nb, 1, q), table.dtype),
-                   jax.ShapeDtypeStruct((nb, 1, q), jnp.int32)],
-        interpret=interpret,
-    )(bmin.astype(jnp.int32), bmax.astype(jnp.int32),
-      table, table_valid.astype(jnp.int32), idx, evalid, dst, w)
-    # buckets tile the segment space disjointly: stage 2 is a relayout
-    return (acc.reshape(-1)[:ns], touched.reshape(-1)[:ns] > 0)
+        vals = apply_weight(vals, w).astype(table.dtype)
+    return two_level_segment_fold(
+        vals, valid, dst, int(num_segments), monoid=monoid,
+        fold_tile=int(edge_tile) if edge_tile else default_fold_tile(),
+        fold_q=int(fold_q) if fold_q else default_fold_q(),
+        interpret=interpret, presorted=presorted)
 
 
 def ref_fused_scatter_fold(mono, table, table_valid, idx, edge_valid, dst,

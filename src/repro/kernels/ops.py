@@ -367,20 +367,40 @@ def _edge_src_global(layout) -> np.ndarray:
     return np.clip(src, 0, layout.n_pad).astype(np.int32)
 
 
-class FusedDCKernel(LayoutBound):
-    """Fused DC scatter→fold bound to a layout (registry ``fused_dc``).
+def _dst_order(layout) -> np.ndarray:
+    """Permutation putting the gather-order edge stream in destination
+    order (ties in stream order; pad edges, whose destination is the
+    sentinel ``n_pad``, last), computed once per layout.  One sort of
+    packed ``dst << 32 | position`` keys: a stable argsort of the same
+    2^27 int32 keys takes ~8x longer in numpy."""
+    order = layout.__dict__.get("_dst_order")
+    if order is None:
+        key = layout.edge_dst.astype(np.int64) << 32
+        key |= np.arange(len(key), dtype=np.int64)
+        key.sort()
+        order = layout.__dict__["_dst_order"] = key & 0xFFFFFFFF
+    return order
 
-    One Pallas call replaces the composed scatter kernel + slot gather +
-    gather fold of the DC stream: per edge tile the source message is
-    gathered straight from the ``[n_pad + 1]`` vertex table (identity
-    sentinel last) and folded into the two-level ``[fold_q]``
-    sub-accumulators — no ``[NM]`` bin buffer, no ``[NE]`` edge-value
-    stream (see :mod:`repro.kernels.fused_step`).
+
+class FusedDCKernel(LayoutBound):
+    """Fused DC step bound to a layout (registry ``fused_dc``).
+
+    Replaces the composed scatter kernel + slot gather + gather fold of
+    the DC stream: each edge's source message is gathered straight from
+    the ``[n_pad + 1]`` vertex table (identity sentinel last) and folded
+    into the two-level ``[fold_q]`` sub-accumulators — no ``[NM]`` bin
+    buffer (see :mod:`repro.kernels.fused_step`).  The static edge stream
+    is kept in destination order, sorted once here, so the fold runs
+    without a per-call sort.
 
     ``apply_weight`` is engine-configured (the registry does not see the
     program): :class:`repro.core.engine.Engine` sets the attribute once,
     before the step is traced, under the same condition the composed
     path applies it.
+
+    Under ``vmap`` (the batched engine path) the lanes run one after
+    another through the unbatched step, so a batch holds one lane's edge
+    streams at a time.
     """
 
     def __init__(self, layout, monoid_name: str, dtype,
@@ -390,26 +410,47 @@ class FusedDCKernel(LayoutBound):
         self.dtype = jnp.dtype(dtype)
         self.interpret = interpret
         self.n_pad = layout.n_pad
-        self.edge_tile = layout.edge_tile
+        self.fold_tile = layout.fold_tile
         self.fold_q = layout.fold_q
         self.apply_weight = None               # engine-configured
+        call = jax.custom_batching.custom_vmap(self._single)
+        call.def_vmap(self._vmap_rule)
+        self._call = call
 
     def host_arrays(self):
         L = self.L
-        return {"edge_src": _edge_src_global(L),
-                "edge_valid": L.edge_valid.astype(np.int32),
-                "edge_dst": L.edge_dst, "edge_w": L.edge_w}
+        order = _dst_order(L)
+        return {"edge_src": _edge_src_global(L)[order],
+                "edge_valid": L.edge_valid[order].astype(np.int32),
+                "edge_dst": L.edge_dst[order],
+                "edge_w": None if L.edge_w is None else L.edge_w[order]}
 
     def __call__(self, table, table_valid, arrays=None):
-        aw, A = self.apply_weight, self._args(arrays)
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.fused_dc")):
-            return fused_scatter_fold(
-                table, table_valid, A["edge_src"], A["edge_valid"],
-                A["edge_dst"], self.n_pad + 1, monoid=self.monoid,
-                edge_tile=self.edge_tile, fold_q=self.fold_q,
-                interpret=self.interpret, apply_weight=aw,
-                w=A["edge_w"] if aw is not None else None)
+            return self._call(table, table_valid, self._args(arrays))
+
+    def _single(self, table, table_valid, A):
+        aw = self.apply_weight
+        return fused_scatter_fold(
+            table, table_valid, A["edge_src"], A["edge_valid"],
+            A["edge_dst"], self.n_pad + 1, monoid=self.monoid,
+            edge_tile=self.fold_tile, fold_q=self.fold_q,
+            interpret=self.interpret, apply_weight=aw,
+            w=A["edge_w"] if aw is not None else None, presorted=True)
+
+    def _vmap_rule(self, axis_size, in_batched, table, table_valid, A):
+        tb, tvb, a_b = in_batched
+        assert not any(jax.tree_util.tree_leaves(a_b)), \
+            "layout arrays are shared by every lane"
+        if not tb:
+            table = jnp.broadcast_to(table, (axis_size,) + table.shape)
+        if not tvb:
+            table_valid = jnp.broadcast_to(
+                table_valid, (axis_size,) + table_valid.shape)
+        out = jax.lax.map(lambda lane: self._single(*lane, A),
+                          (table, table_valid))
+        return out, (True, True)
 
 
 class RefFusedDC(LayoutBound):

@@ -398,9 +398,13 @@ def test_tpu_defaults_follow_the_table(monkeypatch):
         assert registry.default_backend_name("tpu", kernel) == backend
         assert registry.resolve(kernel, "add", jnp.float32,
                                 platform="tpu").name == backend
-    # Mosaic cannot lower fused_dc: pallas-native never claims it
-    assert "pallas-native" not in registry.supported(
-        "tpu", "fused_dc", "min", jnp.uint32)
+    # every kernel of the table lowers on Mosaic for the BFS and
+    # PageRank monoids, and pallas-native claims nothing off the TPU
+    for kernel in registry.KERNELS:
+        assert "pallas-native" in registry.supported(
+            "tpu", kernel, "min", jnp.uint32) or kernel == "spmv"
+        assert "pallas-native" not in registry.supported(
+            "cpu", kernel, "min", jnp.uint32)
 
 
 def test_tpu_fallback_to_ref_warns_even_for_defaults(monkeypatch):

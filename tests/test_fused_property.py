@@ -62,6 +62,26 @@ def test_fused_matches_ref_oracle(data):
 
 @settings(max_examples=25, deadline=None)
 @given(st.data())
+def test_fused_presorted_matches_ref_oracle(data):
+    """A stream in destination order (the layout-bound kernel's) folds
+    without the sort, invalid edges left in place, to the oracle's
+    answer."""
+    monoid, dtype, mono = draw_monoid(data)
+    ns, q = data.draw(st.sampled_from(NS_Q_PAIRS))
+    tile = data.draw(st.sampled_from(EDGE_TILES))
+    table, tvalid, idx, evalid, dst = draw_fused_case(data, ns, dtype)
+    order = jnp.argsort(dst, stable=True)
+    case = (table, tvalid, idx[order], evalid[order], dst[order])
+    assert_kernel_equiv(
+        lambda *a: fused_scatter_fold(*a, ns, monoid=monoid,
+                                      edge_tile=tile, fold_q=q,
+                                      interpret=True, presorted=True),
+        lambda *a: ref_fused_scatter_fold(mono, *a, ns),
+        case)
+
+
+@settings(max_examples=25, deadline=None)
+@given(st.data())
 def test_fused_matches_composed_gather_fold_overcap(data):
     """fused ≡ the composed lowering it replaces (explicit table gather,
     then the two-level fold kernel), across the over-cap NS_Q_PAIRS —

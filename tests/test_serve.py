@@ -185,6 +185,29 @@ def test_sssp_parents_multi_matches_sequential(glayout):
         assert np.array_equal(res["parent"][i], seq["parent"]), s
 
 
+@pytest.mark.parametrize("mode", ["hybrid", "sc"])
+def test_run_batched_lockstep_matches_sequential_runs(glayout, mode):
+    """A hybrid or sc engine's batched run steps each lane through its
+    own Eq. 1 split: bit-exact with per-source runs in that mode, and
+    small frontiers take SC steps instead of whole-edge DC passes."""
+    from repro.apps.bfs import bfs_program
+    eng = Engine(glayout, bfs_program(), mode=mode, backend="ref")
+    deg = glayout.deg[:glayout.n]
+    srcs = [int(np.argmin(np.where(deg > 0, deg, deg.max() + 1))),
+            int(np.argmax(deg)), 3]
+    res = bfs_multi(glayout, srcs, engine=eng)
+    modes = set()
+    for i, s in enumerate(srcs):
+        seq = bfs(glayout, source=s, mode=mode, backend="ref")
+        assert np.array_equal(res["level"][i], seq["level"])
+        assert np.array_equal(res["parent"][i], seq["parent"])
+        modes |= {st.mode for st in seq["stats"]}
+    assert "sc" in modes
+    assert sum(st.lanes_active for st in res["stats"]) == sum(
+        len(bfs(glayout, source=s, mode=mode, backend="ref")["stats"])
+        for s in srcs)
+
+
 def test_run_batched_freezes_converged_lanes(glayout):
     """A lane whose frontier drains early must keep its final state while
     other lanes continue (per-query done masks + lane compaction)."""
@@ -227,16 +250,28 @@ def test_graph_server_batches_queue_into_one_invocation(glayout, monkeypatch):
             assert np.array_equal(q.result["level"], seq["level"])
 
 
-def test_graph_server_pads_batches_to_pow2(glayout):
+def test_graph_server_pads_batches_to_pow2(glayout, monkeypatch):
     """5 distinct sources -> an 8-lane engine invocation: the per-batch-
-    size jit cache stays logarithmic in the queue depth."""
+    size jit cache stays logarithmic in the queue depth.  The server's
+    hybrid engine steps its lanes through run()'s own phases, so it
+    compiles nothing per batch size at all."""
+    widths = []
+    orig = Engine.run_batched
+
+    def spy(self, states, frontiers, *a, **kw):
+        widths.append(np.asarray(frontiers).shape[0])
+        return orig(self, states, frontiers, *a, **kw)
+
+    monkeypatch.setattr(Engine, "run_batched", spy)
     srv = GraphQueryServer(glayout, backend="ref")
     for i, s in enumerate(_sources(glayout, 5)):
         srv.submit(GraphQuery(i, "bfs", {"source": s}))
     srv.run()
+    assert widths == [8]
     eng = srv._engines["bfs"]
-    assert ("batched", 8) in eng._step_cache
-    assert not any(k == ("batched", 5) for k in eng._step_cache)
+    assert eng.mode == "hybrid"
+    assert not any(k[0] == "batched" for k in eng._step_cache
+                   if isinstance(k, tuple))
 
 
 def test_graph_server_queue_is_deque_and_batch_aware(glayout):

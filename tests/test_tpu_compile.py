@@ -27,7 +27,7 @@ from repro.kernels import ops as kops
 from repro.kernels.dc_gather import dc_gather
 from repro.kernels.fold_block import blocked_segment_fold
 from repro.kernels.fold_two_level import two_level_segment_fold
-from repro.kernels.fused_step import ref_fused_scatter_fold
+from repro.kernels.fused_step import fused_scatter_fold
 from repro.kernels.segment_combine import segment_combine
 from repro.kernels.spmv_block import spmv_block
 
@@ -104,29 +104,38 @@ def test_sc_fold_two_level_compiles(one_chip, budget):
 @pytest.mark.parametrize("monoid", ["min", "add"])
 def test_dc_fused_ref_compiles(one_chip, monoid):
     """The DC stream (BFS min/uint32, PageRank add/f32): the table's
-    ``ref`` lowering of ``fused_dc`` over every edge."""
-    assert registry.TPU_DEFAULTS["fused_dc"][0] == "ref"
+    lowering of ``fused_dc`` over every edge, an XLA gather and the
+    Mosaic two-level fold of the destination-sorted stream."""
+    assert registry.TPU_DEFAULTS["fused_dc"][0] == "pallas-native"
     mono = M.min_(jnp.uint32) if monoid == "min" else M.add(jnp.float32)
     S = _spec(one_chip)
 
     def dc(table, table_valid, idx, edge_valid, dst):
-        return ref_fused_scatter_fold(mono, table, table_valid, idx,
-                                      edge_valid, dst, N_PAD + 1)
+        return fused_scatter_fold(table, table_valid, idx, edge_valid, dst,
+                                  N_PAD + 1, monoid=monoid,
+                                  edge_tile=FOLD_TILE, fold_q=FOLD_Q,
+                                  interpret=False, presorted=True)
 
-    _fits(jax.jit(dc).lower(
+    compiled = jax.jit(dc).lower(
         S((N_PAD + 1,), mono.dtype), S((N_PAD + 1,), jnp.bool_),
-        S((N_EDGES,), jnp.int32), S((N_EDGES,), jnp.bool_),
-        S((N_EDGES,), jnp.int32)).compile())
+        S((N_EDGES,), jnp.int32), S((N_EDGES,), jnp.int32),
+        S((N_EDGES,), jnp.int32)).compile()
+    _fits(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_batched_dc_fused_ref_fits_one_chip(one_chip):
-    """Serving's batched DC step: the ``ref`` fused kernel vmapped over
-    8 BFS lanes, whose lane-chunked fold must fit one chip's HBM."""
+    """Serving's batched DC step: the table's ``fused_dc`` kernel vmapped
+    over 8 BFS lanes, which run one after another and must fit one
+    chip's HBM."""
     mono = M.min_(jnp.uint32)
-    kern = kops.RefFusedDC(types.SimpleNamespace(n_pad=N_PAD), mono)
+    kern = kops.FusedDCKernel(
+        types.SimpleNamespace(n_pad=N_PAD, fold_tile=FOLD_TILE,
+                              fold_q=FOLD_Q), mono.name, mono.dtype,
+        interpret=False)
     S = _spec(one_chip)
     arrays = {"edge_src": S((N_EDGES,), jnp.int32),
-              "edge_valid": S((N_EDGES,), jnp.bool_),
+              "edge_valid": S((N_EDGES,), jnp.int32),
               "edge_dst": S((N_EDGES,), jnp.int32), "edge_w": None}
 
     def step(table, table_valid, A):
@@ -136,9 +145,12 @@ def test_batched_dc_fused_ref_fits_one_chip(one_chip):
     mem = _fits(jax.jit(step).lower(
         S((LANES, N_PAD + 1), jnp.uint32),
         S((LANES, N_PAD + 1), jnp.bool_), arrays).compile())
-    # one lane's streams at a time (~2 GiB): folding all 8 lanes at
-    # once needed 11 GiB of temporaries
-    assert mem.temp_size_in_bytes < 4 * 2**30
+    one = jax.jit(lambda t, tv, A: kern(t, tv, arrays=A)).lower(
+        S((N_PAD + 1,), jnp.uint32), S((N_PAD + 1,), jnp.bool_),
+        arrays).compile().memory_analysis()
+    # one lane's streams at a time: all 8 lanes at once would need 8
+    # times one lane's temporaries, several times one chip's HBM
+    assert mem.temp_size_in_bytes < 1.5 * one.temp_size_in_bytes
 
 
 #: a layout whose partitions a one-hot can span: k = 2048 partitions of
