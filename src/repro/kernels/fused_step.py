@@ -8,12 +8,12 @@ the per-partition accumulators.
 
 This step goes straight from the vertex-message table to the fold: XLA
 gathers each edge's source value and validity out of the ``[n_pad + 1]``
-table (identity sentinel last), the optional edge
-function (``apply_weight``) runs on the gathered values, and the
-two-level Pallas fold (:mod:`repro.kernels.fold_two_level`) folds them
-into the ``[fold_q]`` bucket sub-accumulators.  No ``[NM]`` buffer and
-no XLA scatter: on a TPU the fold is the Mosaic kernel, and the only
-random access left is the gather.
+table (identity sentinel last), the optional edge function
+(``apply_weight``) runs on the gathered values, and the two-level
+Pallas fold (:mod:`repro.kernels.fold_two_level`) folds them into the
+``[fold_q]`` bucket sub-accumulators.  No ``[NM]`` buffer and no XLA
+scatter: on a TPU the fold is the Mosaic kernel, and the only random
+access left is the gather.
 
 Mosaic lowers no vector gather from a table of this size inside a kernel
 ("Only 2D gather is supported"), which is why the gather stays in XLA.
