@@ -314,8 +314,9 @@ def phase_serve(L, sources, bfs_engine, platform: str):
 
 def phase_dist(g, L, roots, want_levels, keys, want_pr, devices,
                platform: str):
-    """``DistEngine`` BFS (hybrid) and 10 PageRank iterations (DC) over
-    ``shard_layout(L, D)`` on a ``D``-device mesh."""
+    """``DistEngine`` BFS (hybrid; every root, then the first again
+    warm) and 10 PageRank iterations (DC) over ``shard_layout(L, D)`` on
+    a ``D``-device mesh."""
     import jax
     import numpy as np
 
@@ -339,8 +340,8 @@ def phase_dist(g, L, roots, want_levels, keys, want_pr, devices,
         names["fused_dc"] = eng.fused_backend_name
         ran["fused_dc"] = eng.fused
     check_lowerings(names, ran, platform, "dist.bfs")
-    for pass_name in ("first", "warm"):
-        for i, r in enumerate(roots):
+    for pass_name, todo in (("first", roots), ("warm", roots[:1])):
+        for i, r in enumerate(todo):
             parent = np.full(N, -1, np.int32)
             parent[r] = r
             level = np.full(N, -1, np.int32)
@@ -357,6 +358,8 @@ def phase_dist(g, L, roots, want_levels, keys, want_pr, devices,
             log(f"dist.bfs.{pass_name}", root=r, seconds=sec,
                 supersteps=len(stats), reached=reached,
                 modes={m: modes.count(m) for m in sorted(set(modes))})
+    log("dist.bfs.memory",
+        peak_bytes_in_use=[peak_bytes(d) for d in devices])
     del eng
     gc.collect()
 

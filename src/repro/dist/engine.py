@@ -74,21 +74,24 @@ def _pack_bits(flags):
     Validity flags cross the wire 8x smaller than bool lanes (XLA sends
     one byte per bool).  The pack/unpack pair is shifts and masked sums,
     which the algebraic simplifier cannot cancel through the collective,
-    so the wire really carries the packed bytes."""
+    so the wire really carries the packed bytes.  Byte ``j`` holds flags
+    ``j, P + j, ..., 7P + j``: the bit axis is a major one, so the
+    minor axis stays ``P`` long (a minor axis of 8 takes the TPU
+    compiler tens of seconds to lay out at scale)."""
     S = flags.shape[-1]
-    Sp = -(-S // 8) * 8
-    if Sp != S:
-        pad = jnp.zeros(flags.shape[:-1] + (Sp - S,), jnp.bool_)
+    P = -(-S // 8)
+    if 8 * P != S:
+        pad = jnp.zeros(flags.shape[:-1] + (8 * P - S,), jnp.bool_)
         flags = jnp.concatenate([flags, pad], axis=-1)
-    bits = flags.reshape(flags.shape[:-1] + (Sp // 8, 8)).astype(jnp.uint8)
-    weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))
-    return jnp.sum(bits * weights, axis=-1, dtype=jnp.uint8)
+    bits = flags.reshape(flags.shape[:-1] + (8, P)).astype(jnp.uint8)
+    weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))[:, None]
+    return jnp.sum(bits * weights, axis=-2, dtype=jnp.uint8)
 
 
 def _unpack_bits(packed, S):
     """Inverse of :func:`_pack_bits`: ``[..., P]`` u8 -> ``[..., S]`` bool."""
-    bits = (packed[..., None] >> jnp.arange(8, dtype=jnp.uint8)) \
-        & jnp.uint8(1)
+    bits = (packed[..., None, :]
+            >> jnp.arange(8, dtype=jnp.uint8)[:, None]) & jnp.uint8(1)
     return bits.reshape(bits.shape[:-2] + (-1,))[..., :S] != 0
 
 
@@ -277,7 +280,9 @@ def build_dc_step(program: VertexProgram, meta: dict,
             # the received bin table itself — no [NEd] edge-value stream.
             # The table is pre-cast off the wire dtype (the elementwise
             # cast commutes with the gather, so parity with the composed
-            # ``rv[slot].astype`` is bit-exact)
+            # ``rv[slot].astype`` is bit-exact); shard_layout keeps each
+            # device's edges in destination order, so the fold skips
+            # its sort
             table = rv.astype(mono.dtype)
             aw = (program.apply_weight
                   if program.apply_weight is not None and weighted
@@ -291,13 +296,15 @@ def build_dc_step(program: VertexProgram, meta: dict,
                 accs, touch = [], []
                 for i in range(table.shape[0]):
                     a, t = fused(table[i], rf[i], slot, evalid_s, dst_s,
-                                 nv + 1, w=w, apply_weight=aw)
+                                 nv + 1, w=w, apply_weight=aw,
+                                 presorted=True)
                     accs.append(a)
                     touch.append(t)
                 acc, touched = jnp.stack(accs), jnp.stack(touch)
             else:
                 acc, touched = fused(table, rf, slot, evalid_s, dst_s,
-                                     nv + 1, w=w, apply_weight=aw)
+                                     nv + 1, w=w, apply_weight=aw,
+                                     presorted=True)
         else:
             slot = A["in_msg_slot"]
             ev = rv[..., slot].astype(mono.dtype)             # [.., NEd]

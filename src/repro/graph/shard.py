@@ -45,7 +45,7 @@ class ShardedLayout:
 
     # ---- DC gather side (per destination device) ----
     in_msg_slot: np.ndarray     # int32[D, NEd] -> index into recv[D*S] (sentinel D*S)
-    in_dst_local: np.ndarray    # int32[D, NEd] (sentinel nv)
+    in_dst_local: np.ndarray    # int32[D, NEd] (sentinel nv), sorted per device
     in_valid: np.ndarray        # bool [D, NEd]
     in_w: Optional[np.ndarray]  # f32  [D, NEd]
 
@@ -130,19 +130,26 @@ def shard_layout(L: Layout, D: int) -> ShardedLayout:
     for d in range(D):
         lo, hi = int(dev_edge_lo[d]), int(dev_edge_hi[d])
         c = hi - lo
-        ms = L.msg_slot[lo:hi]
-        ok = ms < nm_pad
-        slot_mapped = np.full(c, D * S, dtype=np.int32)
-        slot_mapped[ok] = slot_recv[ms[ok]].astype(np.int32)
-        in_msg_slot[d, :c] = slot_mapped
         dst = L.edge_dst[lo:hi].astype(np.int64)
         dok = dst < n_pad
         dl = np.full(c, nv, dtype=np.int32)
         dl[dok] = (dst[dok] - d * nv).astype(np.int32)
-        in_dst_local[d, :c] = dl
-        in_valid[d, :c] = L.edge_valid[lo:hi]
+        # each device's edges in destination order (the sentinel nv,
+        # and the padding after them, last): the fused gather folds a
+        # presorted stream (one sort of packed dst << 32 | position keys)
+        key = dl.astype(np.int64) << 32
+        key |= np.arange(c, dtype=np.int64)
+        key.sort()
+        o = lo + (key & 0xFFFFFFFF)
+        ms = L.msg_slot[o]
+        ok = ms < nm_pad
+        slot_mapped = np.full(c, D * S, dtype=np.int32)
+        slot_mapped[ok] = slot_recv[ms[ok]].astype(np.int32)
+        in_msg_slot[d, :c] = slot_mapped
+        in_dst_local[d, :c] = (key >> 32).astype(np.int32)
+        in_valid[d, :c] = L.edge_valid[o]
         if L.weighted:
-            in_w[d, :c] = L.edge_w[lo:hi]
+            in_w[d, :c] = L.edge_w[o]
 
     # ---------- SC side: out-edges grouped by (src device, dst device) ------
     deg_np = L.deg

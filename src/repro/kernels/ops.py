@@ -536,7 +536,8 @@ class FusedStreamKernel:
         self.q = q
 
     def __call__(self, table, table_valid, idx, edge_valid, dst,
-                 num_segments, w=None, apply_weight=None):
+                 num_segments, w=None, apply_weight=None,
+                 presorted: bool = False):
         tile = int(self.tile) if self.tile else default_fold_tile()
         q = int(self.q) if self.q else default_fold_q()
         with obs_tracing.kernel_scope(
@@ -545,7 +546,7 @@ class FusedStreamKernel:
                 table, table_valid, idx, edge_valid, dst,
                 int(num_segments), monoid=self.monoid, edge_tile=tile,
                 fold_q=q, interpret=self.interpret,
-                apply_weight=apply_weight, w=w)
+                apply_weight=apply_weight, w=w, presorted=presorted)
 
 
 class RefFusedStream:
@@ -555,7 +556,8 @@ class RefFusedStream:
         self.monoid = monoid
 
     def __call__(self, table, table_valid, idx, edge_valid, dst,
-                 num_segments, w=None, apply_weight=None):
+                 num_segments, w=None, apply_weight=None,
+                 presorted: bool = False):
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.fused_dc.ref")):
             return ref_fused_scatter_fold(

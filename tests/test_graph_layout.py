@@ -79,3 +79,19 @@ def test_cost_model_mode_choice():
     assert all_dc[L.part_edges > 0].all()
     b = cm.bytes_for(all_dc, L.part_edges, L.part_edges > 0)
     assert b["sc_bytes"] == 0 and b["dc_bytes"] > 0
+
+
+def test_shard_gather_side_in_destination_order():
+    """DistEngine's fused DC step folds each device's gather-side stream
+    without sorting it: shard_layout keeps it in destination order (the
+    sentinel ``nv`` and the padding last) and drops no edge."""
+    from repro.graph.shard import shard_layout
+    g = rmat(9, 8, seed=3)
+    L = build_layout(g, k=8, edge_tile=64, msg_tile=32)
+    SL = shard_layout(L, 4)
+    dl, ok = SL.in_dst_local, SL.in_valid
+    assert np.all(np.diff(dl, axis=1) >= 0)
+    assert np.all(dl[ok] < SL.nv) and np.all(dl[~ok] == SL.nv)
+    glob = dl + (np.arange(SL.D) * SL.nv)[:, None]
+    assert np.array_equal(np.sort(glob[ok]),
+                          np.sort(L.edge_dst[L.edge_valid]))
