@@ -523,8 +523,10 @@ class FusedStreamKernel:
     What :class:`FoldKernel` is to the fold, this is to the fused step:
     the distributed engine's gather side has no tile/partition structure
     on the receive table (``rv[slot]``), so the kernel takes the table,
-    the slot indices and the static validity per call and fuses the slot
-    gather + edge function + two-level fold in one Pallas launch.
+    the slot indices and the static validity per call: an XLA slot
+    gather, the edge function, then the two-level Pallas fold, which
+    skips its sort when the caller passes ``presorted=True`` (a stream
+    in destination order, as ``shard_layout`` keeps it).
     """
 
     def __init__(self, monoid_name: str, dtype, interpret: bool = True,
