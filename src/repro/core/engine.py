@@ -166,9 +166,6 @@ def _run_batched_loop(step_for_width, states, active, max_iters: int,
                           n_active=n_act, width=W, wall_s=wall, **extra)
                 obs.observe("engine.batch_step_wall_s", wall,
                             engine=engine_name, program=program or "?")
-                obs.cost_sample("dc", n_act, wall, it=it, batched=True,
-                                width=W, engine=engine_name,
-                                program=program)
     return states, active, stats
 
 
@@ -469,65 +466,88 @@ class Engine:
                              "resume_from=+touched=)")
         active = jnp.asarray(frontier, jnp.bool_)
         stats = []
-        for it in range(max_iters):
-            counts, ea = self._part_stats(active)
-            counts = np.asarray(counts)
-            ea = np.asarray(ea)
-            n_active = int(counts.sum())
-            if until_empty and n_active == 0:
-                break
-            t0 = time.perf_counter()
-            state, active, dc_mask, sc_sel = self._superstep(
-                state, active, it, counts, ea)
-            jax.block_until_ready(active)
-            if collect_stats:
-                stats.append(self._record_iter(it, n_active, ea, counts,
-                                               dc_mask, sc_sel,
-                                               time.perf_counter() - t0))
+        # host spans (repro.obs.annotation): one engine.superstep per
+        # pass, the last pass's (the empty frontier's) included; inside
+        # it engine.part_stats, engine.split, engine.dispatch,
+        # engine.sync and engine.record, in that order
+        with obs.annotation("engine.run", program=self.program.name,
+                            mode=self.mode):
+            for it in range(max_iters):
+                with obs.annotation("engine.superstep", it=it) as span:
+                    with obs.annotation("engine.part_stats"):
+                        counts, ea = self._part_stats(active)
+                        counts, ea = np.asarray(counts), np.asarray(ea)
+                    n_active = int(counts.sum())
+                    if until_empty and n_active == 0:
+                        break
+                    t0 = time.perf_counter()
+                    state, active, dc_mask, split = self._superstep(
+                        state, active, it, counts, ea)
+                    with obs.annotation("engine.sync"):
+                        jax.block_until_ready(active)
+                        wall = time.perf_counter() - t0
+                    span.set_metadata(**split)
+                    if collect_stats:
+                        with obs.annotation("engine.record"):
+                            stats.append(self._record_iter(
+                                it, n_active, ea, counts, dc_mask, split,
+                                wall))
         return state, active, stats
 
     def _superstep(self, state, active, it: int, counts, ea):
         """One superstep from the host-side partition stats ``counts`` /
         ``ea`` (active vertices / active edges per partition): the Eq. 1
-        split, then the DC, SC and apply phases.  Returns ``(state,
-        active, dc_mask, sc_sel)``."""
-        has_active = counts > 0
-        if self.mode == "dc":
-            dc_mask = has_active
-        elif self.mode == "sc":
-            dc_mask = np.zeros(self.k, bool)
-        else:
-            dc_mask = self.cost.choose_dc(ea, has_active)
-        sc_sel = (~dc_mask) & has_active
-        # SC edge budget: the active edges of SC partitions, rounded up to
-        # the budget grid (0: no SC stream this step)
-        be = (_sc_budget(int(ea[sc_sel].sum()), self._sc_cap)
-              if sc_sel.any() else 0)
-        dc_fn, sc_fn, apply_fn = self._phase_fns(be, dc_mask.any())
-        args, mask, it32 = self._args(), jnp.asarray(dc_mask), jnp.int32(it)
-        state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,
-                                                  it32, args)
-        if be:
-            acc, touched = sc_fn(msgs_p, active, mask, acc, touched, args)
-        state, active = apply_fn(state, keep, acc, touched, it32)
-        return state, active, dc_mask, sc_sel
+        split (span ``engine.split``), then the DC, SC and apply phases
+        (span ``engine.dispatch``).  Returns ``(state, active, dc_mask,
+        split)``, ``split`` the superstep's partitions and active edges
+        by stream, its SC budget (0: no SC stream) and the phase programs
+        built for it (``new_programs``: their first call compiles)."""
+        with obs.annotation("engine.split"):
+            has_active = counts > 0
+            if self.mode == "dc":
+                dc_mask = has_active
+            elif self.mode == "sc":
+                dc_mask = np.zeros(self.k, bool)
+            else:
+                dc_mask = self.cost.choose_dc(ea, has_active)
+            sc_sel = (~dc_mask) & has_active
+            sc_e = int(ea[sc_sel].sum())
+            # SC edge budget: the active edges of SC partitions, rounded
+            # up to the budget grid (0: no SC stream this step)
+            be = _sc_budget(sc_e, self._sc_cap) if sc_sel.any() else 0
+            built = len(self._step_cache)
+            dc_fn, sc_fn, apply_fn = self._phase_fns(be, dc_mask.any())
+            split = dict(dc_parts=int(dc_mask.sum()),
+                         sc_parts=int(sc_sel.sum()),
+                         dc_e=int(ea[dc_mask].sum()), sc_e=sc_e,
+                         sc_budget=be,
+                         new_programs=len(self._step_cache) - built)
+        with obs.annotation("engine.dispatch"):
+            args, mask = self._args(), jnp.asarray(dc_mask)
+            it32 = jnp.int32(it)
+            state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,
+                                                      it32, args)
+            if be:
+                acc, touched = sc_fn(msgs_p, active, mask, acc, touched,
+                                     args)
+            state, active = apply_fn(state, keep, acc, touched, it32)
+        return state, active, dc_mask, split
 
-    def _record_iter(self, it, n_active, ea, counts, dc_mask, sc_sel, wall):
+    def _record_iter(self, it, n_active, ea, counts, dc_mask, split, wall):
         """The superstep's :class:`~repro.obs.IterStats`, also recorded
-        through :func:`repro.obs.record_engine_iter`."""
+        through :func:`repro.obs.record_engine_iter` with the active
+        edges of each stream (``dc_e`` / ``sc_e``)."""
         b = self.cost.bytes_for(dc_mask, ea, counts > 0)
-        dc_p, sc_p = int(dc_mask.sum()), int(sc_sel.sum())
+        dc_p, sc_p = split["dc_parts"], split["sc_parts"]
         mode_str = "dc" if sc_p == 0 else "sc" if dc_p == 0 else "hybrid"
         st = obs.IterStats(
             it=it, n_active=n_active, e_active=int(ea.sum()),
             dc_parts=dc_p, sc_parts=sc_p,
             dc_bytes=b["dc_bytes"], sc_bytes=b["sc_bytes"],
-            wall_s=wall, mode=mode_str, program=self.program.name)
-        # dc_e/sc_e split the active-edge count by stream: pure dc/sc
-        # steps give the online Eq. 1 calibration clean single-mode
-        # (size, time) points
-        obs.record_engine_iter("core", st, dc_e=int(ea[dc_mask].sum()),
-                               sc_e=int(ea[sc_sel].sum()))
+            wall_s=wall, mode=mode_str, program=self.program.name,
+            sc_budget=split["sc_budget"])
+        obs.record_engine_iter("core", st, dc_e=split["dc_e"],
+                               sc_e=split["sc_e"])
         return st
 
     # ------------------------------------------------------------------
@@ -615,34 +635,52 @@ class Engine:
         lanes = [tmap(lambda a, i=i: a[i], states) for i in range(B)]
         acts = [active[i] for i in range(B)]
         stats = []
-        for it in range(max_iters):
-            counts, ea = self._part_stats(jnp.stack(acts))
-            counts, ea = np.asarray(counts), np.asarray(ea)
-            live = np.nonzero(counts.sum(axis=1) > 0)[0]
-            if not len(live):
-                if until_empty:
-                    break
-                continue    # every phase masks on active: a no-op step
-            t0 = time.perf_counter()
-            for i in live:
-                lanes[i], acts[i], _, _ = self._superstep(
-                    lanes[i], acts[i], it, counts[i], ea[i])
-            jax.block_until_ready(acts)
-            wall = time.perf_counter() - t0
-            if collect_stats:
-                n_act = int(counts.sum())
-                stats.append(obs.BatchIterStats(
-                    it=it, lanes_active=len(live), n_active=n_act,
-                    wall_s=wall))
-                if obs.enabled():
-                    obs.event("batch_iter", engine="core",
-                              program=self.program.name, it=it,
-                              lanes_active=len(live), n_active=n_act,
-                              width=len(live), wall_s=wall)
-                    obs.observe("engine.batch_step_wall_s", wall,
-                                engine="core", program=self.program.name)
+        # the spans of run(); engine.split and engine.dispatch repeat
+        # once for each live lane, and engine.superstep's fields sum the
+        # lanes' splits (sc_budget: the largest)
+        with obs.annotation("engine.run", program=self.program.name,
+                            mode=self.mode):
+            for it in range(max_iters):
+                with obs.annotation("engine.superstep", it=it) as span:
+                    with obs.annotation("engine.part_stats"):
+                        counts, ea = self._part_stats(jnp.stack(acts))
+                        counts, ea = np.asarray(counts), np.asarray(ea)
+                    live = np.nonzero(counts.sum(axis=1) > 0)[0]
+                    if not len(live):
+                        if until_empty:
+                            break
+                        continue    # every phase masks on active: no-op
+                    t0 = time.perf_counter()
+                    splits = []
+                    for i in live:
+                        lanes[i], acts[i], _, split = self._superstep(
+                            lanes[i], acts[i], it, counts[i], ea[i])
+                        splits.append(split)
+                    with obs.annotation("engine.sync"):
+                        jax.block_until_ready(acts)
+                        wall = time.perf_counter() - t0
+                    span.set_metadata(lanes=len(live), **{
+                        k: (max if k == "sc_budget" else sum)(
+                            s[k] for s in splits) for k in splits[0]})
+                    if collect_stats:
+                        with obs.annotation("engine.record"):
+                            stats.append(self._record_batch_iter(
+                                it, len(live), int(counts.sum()), wall))
         return (tmap(lambda *xs: jnp.stack(xs), *lanes), jnp.stack(acts),
                 stats)
+
+    def _record_batch_iter(self, it, lanes, n_act, wall):
+        """The lockstep superstep's :class:`~repro.obs.BatchIterStats`,
+        also recorded as a ``batch_iter`` event and step-wall
+        histogram."""
+        if obs.enabled():
+            obs.event("batch_iter", engine="core", program=self.program.name,
+                      it=it, lanes_active=lanes, n_active=n_act,
+                      width=lanes, wall_s=wall)
+            obs.observe("engine.batch_step_wall_s", wall, engine="core",
+                        program=self.program.name)
+        return obs.BatchIterStats(it=it, lanes_active=lanes, n_active=n_act,
+                                  wall_s=wall)
 
     # ------------------------------------------------------------------
     def run_fused(self, state, frontier, iters: int):

@@ -747,17 +747,14 @@ class DistEngine:
 
     def _record_iter(self, s: dict):
         """Telemetry for one distributed step (no-op when obs is off):
-        engine_iter event with the analytic wire bytes, step-wall
-        histogram keyed by mode, and an Eq. 1 cost sample."""
+        engine_iter event with the analytic wire bytes and step-wall
+        histogram keyed by mode."""
         if not obs.enabled():
             return
         prog = self.program.name
         obs.event("engine_iter", engine="dist", program=prog, **s)
         obs.observe("engine.step_wall_s", s["wall_s"], engine="dist",
                     program=prog or "?", mode=s["mode"])
-        obs.cost_sample(s["mode"], s["e_active"], s["wall_s"], it=s["it"],
-                        engine="dist", program=prog,
-                        wire_bytes=s["wire_bytes"])
 
     # ------------------------------------------------------------------
     def wire_bytes_per_step(self, batch: int = 1) -> int:

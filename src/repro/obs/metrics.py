@@ -13,13 +13,10 @@ dict regardless of sample count and any quantile estimate is within one
 bucket's relative width (``GROWTH``) of the true order statistic —
 tight enough for latency percentiles, unbeatable for the price.
 
-The registry also carries two streams the plain metrics cannot express:
-
-* **events** — schema'd dicts (:mod:`repro.obs.schema`) appended to a
-  bounded in-memory buffer and, when ``REPRO_OBS_SINK`` names a path,
-  streamed to it as JSON lines;
-* **cost samples** — ``(mode, size, wall_s)`` tuples recorded per engine
-  step, the raw table an online Eq. 1 cost-model calibration fits.
+The registry also carries a stream the plain metrics cannot express:
+**events**, schema'd dicts (:mod:`repro.obs.schema`) appended to a
+bounded in-memory buffer and, when ``REPRO_OBS_SINK`` names a path,
+streamed to it as JSON lines.
 """
 from __future__ import annotations
 
@@ -220,7 +217,7 @@ _KINDS = {"counter": Counter, "gauge": Gauge, "histogram": Histogram}
 
 
 class Registry:
-    """One process-wide home for metrics, events, and cost samples.
+    """One process-wide home for metrics and events.
 
     ``enabled`` resolves from ``REPRO_OBS`` (anything but
     0/false/off/no enables; the default is ON).  When disabled, every
@@ -232,7 +229,6 @@ class Registry:
         self.enabled = _env_enabled() if enabled is None else bool(enabled)
         self._metrics = {}            # (kind, name, labelkey) -> metric
         self._events = deque(maxlen=max_events)
-        self._cost = []               # (mode, size, wall_s, extra) tuples
         self._lock = threading.Lock()
         self._sink_path = _env_sink() if sink is None else sink
         self._sink_file = None
@@ -283,28 +279,7 @@ class Registry:
         self._events.append(rec)
         self._sink_write(rec)
 
-    def cost_sample(self, mode: str, size, wall_s, **extra):
-        """One (partition mode, work size, wall seconds) step timing —
-        the raw material for online Eq. 1 cost-model calibration."""
-        if not self.enabled:
-            return
-        with self._lock:
-            self._cost.append((str(mode), int(size), float(wall_s), extra))
-
     # -- reads ---------------------------------------------------------
-    def cost_samples(self, mode=None):
-        """``(mode, size, wall_s)`` tuples recorded so far, optionally
-        filtered to one partition mode."""
-        with self._lock:
-            rows = list(self._cost)
-        return [(m, s, w) for m, s, w, _ in rows
-                if mode is None or m == mode]
-
-    def cost_samples_full(self, mode=None):
-        with self._lock:
-            rows = list(self._cost)
-        return [r for r in rows if mode is None or r[0] == mode]
-
     def events(self, event=None):
         out = list(self._events)
         if event is not None:
@@ -331,10 +306,9 @@ class Registry:
 
     # -- lifecycle -----------------------------------------------------
     def reset(self):
-        """Drop every metric, event, and cost sample (enabled/sink kept)."""
+        """Drop every metric and event (enabled/sink kept)."""
         with self._lock:
             self._metrics.clear()
-            self._cost.clear()
         self._events.clear()
 
     def reset_metric(self, name: str, **labels):
@@ -438,14 +412,6 @@ def observe(name: str, v, **labels):
 
 def event(event_name: str, **fields):
     _default.event(event_name, **fields)
-
-
-def cost_sample(mode: str, size, wall_s, **extra):
-    _default.cost_sample(mode, size, wall_s, **extra)
-
-
-def cost_samples(mode=None):
-    return _default.cost_samples(mode)
 
 
 def events(event_name=None):

@@ -10,7 +10,8 @@ turns one into the dict the JSONL sink ships.
 ``EVENT_SCHEMA`` is the machine-checkable contract for every event type
 the repo emits: per event, the required fields and their types.  Extra
 fields are always allowed (events are forward-extensible); missing or
-mistyped required fields are a schema violation.
+mistyped required fields are a schema violation, and so are optional
+fields that are present with another type.
 ``tools/obs_schema.json`` is the checked-in serialization of this dict
 (``tools/check_obs_schema.py`` validates exported JSONL against it
 without importing the repo; a test asserts the two never diverge).
@@ -36,6 +37,9 @@ class IterStats:
     mode: str = ""
     #: vertex-program name, for grouping a multi-app run's telemetry
     program: str = ""
+    #: the superstep's SC edge budget, the class of its compiled SC
+    #: program (0: no SC stream)
+    sc_budget: int = 0
 
 
 @dataclasses.dataclass
@@ -60,11 +64,13 @@ EVENT_SCHEMA = {
     "version": 1,
     "events": {
         # one engine iteration (single-device or distributed); dist steps
-        # add wire_bytes (analytic all_to_all payload)
+        # add wire_bytes (analytic all_to_all payload), single-device ones
+        # the active edges of each stream and the SC budget class
         "engine_iter": {
             "required": {"engine": "str", "program": "str", "it": "int",
                          "mode": "str", "n_active": "int",
                          "e_active": "int", "wall_s": "float"},
+            "optional": {"dc_e": "int", "sc_e": "int", "sc_budget": "int"},
         },
         # one batched (multi-source) engine step
         "batch_iter": {
@@ -150,8 +156,8 @@ TYPE_TAGS = {
 
 def validate_event(rec: dict, schema: dict = None):
     """Return a list of violation strings for one event dict (empty when
-    valid).  Unknown event types and missing/mistyped required fields are
-    violations; extra fields are not."""
+    valid).  Unknown event types, missing/mistyped required fields and
+    mistyped optional ones are violations; extra fields are not."""
     schema = EVENT_SCHEMA if schema is None else schema
     errs = []
     ev = rec.get("event")
@@ -162,9 +168,11 @@ def validate_event(rec: dict, schema: dict = None):
         return [f"unknown event type {ev!r}"]
     if not isinstance(rec.get("ts"), (int, float)):
         errs.append(f"{ev}: missing/invalid 'ts'")
-    for field, tag in spec["required"].items():
+    optional = spec.get("optional", {})
+    for field, tag in [*spec["required"].items(), *optional.items()]:
         if field not in rec:
-            errs.append(f"{ev}: missing required field {field!r}")
+            if field not in optional:
+                errs.append(f"{ev}: missing required field {field!r}")
             continue
         ok_types = TYPE_TAGS[tag]
         v = rec[field]

@@ -8,9 +8,11 @@ as named regions instead of anonymous fusions.  ``jax.named_scope`` adds
 trace-time metadata only — no ops, no retraces, zero runtime cost — and
 is skipped entirely when telemetry is disabled.
 
-``annotation`` is the host-side counterpart (``TraceAnnotation``): wrap a
-host region (a scheduler tick, a drain) so it is attributable in the
-same profile.
+``annotation`` is the host-side counterpart (``TraceAnnotation``): a
+span around a host region (the engine loop's partition-stats copy, its
+dispatches, its sync) on the profiler's own clock, so a capture puts it
+beside the device's ops.  Its fields reach ``perfetto_trace.json.gz`` as
+the event's ``args``.  With telemetry disabled it is a no-op.
 """
 from __future__ import annotations
 
@@ -31,14 +33,31 @@ def kernel_scope(name: str):
     return jax.named_scope(name)
 
 
-def annotation(name: str):
-    """Host-side profiler annotation (TraceAnnotation) when enabled."""
+class _NullSpan:
+    """What :func:`annotation` returns with telemetry disabled."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **meta):
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def annotation(name: str, **meta):
+    """Host span ``name`` with fields ``meta``: a
+    ``jax.profiler.TraceAnnotation`` when telemetry is enabled, else a
+    no-op.  Either one is a context manager whose ``set_metadata(**meta)``
+    adds fields after entry (they are written when the span ends)."""
     if not metrics.enabled():
-        return _NULL
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:                         # profiler unavailable
-        return _NULL
+        return _NULL_SPAN
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
 @contextlib.contextmanager

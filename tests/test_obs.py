@@ -4,9 +4,10 @@ Covers the metrics registry (histogram percentile math against
 numpy.percentile, label-subset resets), the exporters (JSONL round-trip,
 Prometheus text format, the checked-in schema JSON staying in sync with
 ``EVENT_SCHEMA``), the engine and serve-tier wiring (events validate,
-cost samples accumulate, server counters match the obs series, layout
-swaps segment the hit-rate series), and the disabled-mode no-op
-guarantee (no events, no metrics, no extra jit retraces).
+server counters match the obs series, layout swaps segment the hit-rate
+series), the engine loop's host spans in a profiler capture, and the
+disabled-mode no-op guarantee (no events, no metrics, no spans, no extra
+jit retraces).
 """
 import importlib.util
 import json
@@ -144,23 +145,14 @@ class TestRegistry:
         assert r.counter("hits", layout="a", app="sssp").value == 0
         assert r.counter("hits", layout="b", app="bfs").value == 7
 
-    def test_cost_sample_filter(self):
-        r = Registry(enabled=True)
-        r.cost_sample("dc", 100, 0.5, it=0)
-        r.cost_sample("sc", 10, 0.1)
-        assert r.cost_samples() == [("dc", 100, 0.5), ("sc", 10, 0.1)]
-        assert r.cost_samples(mode="sc") == [("sc", 10, 0.1)]
-
     def test_disabled_records_nothing(self):
         r = Registry(enabled=False)
         r.inc("hits")
         r.set_gauge("depth", 4)
         r.observe("lat", 0.1)
         r.event("engine_iter", engine="core")
-        r.cost_sample("dc", 1, 0.1)
         assert r.metrics() == {}
         assert r.events() == []
-        assert r.cost_samples() == []
 
 
 # ----------------------------------------------------------------------
@@ -231,6 +223,21 @@ class TestSchema:
                "e_active": 8, "wall_s": 0.01}
         assert any("got bool" in m for m in obs_schema.validate_event(rec))
 
+    def test_optional_fields_are_typed_where_present(self, tmp_path):
+        checker = _load_tool("check_obs_schema")
+        rec = {"event": "engine_iter", "ts": 1.0, "engine": "core",
+               "program": "bfs", "it": 0, "mode": "sc", "n_active": 1,
+               "e_active": 8, "wall_s": 0.01}
+        schema = json.loads(
+            (REPO_ROOT / "tools" / "obs_schema.json").read_text())
+        for extra, bad in [({}, False), ({"sc_budget": 4096}, False),
+                           ({"sc_budget": "4096"}, True),
+                           ({"dc_e": 0, "sc_e": 1.5}, True)]:
+            r = dict(rec, **extra)
+            errs = obs_schema.validate_event(r)
+            assert bool(errs) == bad, (extra, errs)
+            assert checker.validate_record(r, schema) == errs
+
     def test_schema_json_in_sync(self):
         on_disk = json.loads(
             (REPO_ROOT / "tools" / "obs_schema.json").read_text())
@@ -266,19 +273,20 @@ def _bfs_inputs(layout, source=0):
 
 
 class TestEngineTelemetry:
-    def test_run_records_events_and_cost_samples(self, obs_on, layout):
+    def test_run_records_events(self, obs_on, layout):
         from repro.apps import bfs
         res = bfs(layout, source=0)
         iters = obs.events("engine_iter")
         assert len(iters) == len(res["stats"]) > 0
-        for e in iters:
+        for e, st_ in zip(iters, res["stats"]):
             assert obs_schema.validate_event(e) == []
             assert e["engine"] == "core" and e["program"] == "bfs"
             assert e["mode"] in ("dc", "sc", "hybrid")
-        samples = obs.cost_samples()
-        assert len(samples) == len(iters)
-        mode, size, wall = samples[0]
-        assert isinstance(size, int) and wall >= 0
+            # the active edges split by stream, and the SC budget class
+            assert e["dc_e"] + e["sc_e"] == e["e_active"]
+            assert e["sc_budget"] == st_.sc_budget
+            assert (e["sc_budget"] >= e["sc_e"]) if e["sc_parts"] \
+                else e["sc_budget"] == 0
 
     def test_batched_run_records_batch_iters(self, obs_on, layout):
         from repro.apps.bfs import bfs_multi
@@ -298,7 +306,6 @@ class TestEngineTelemetry:
         state, frontier = _bfs_inputs(layout)
         eng.run(state, frontier, collect_stats=False)
         assert obs.events("engine_iter") == []
-        assert obs.cost_samples() == []
 
     def test_disabled_mode_no_events_no_retrace(self, layout):
         from repro.apps.bfs import bfs_program
@@ -346,6 +353,116 @@ class TestEngineTelemetry:
         st_ = obs_schema.IterStats(0, 1, 2, 3, 4, 5.0, 6.0, 0.1)
         assert (st_.mode, st_.program) == ("", "")
         assert obs_schema.as_event(st_)["dc_bytes"] == 5.0
+
+
+# ----------------------------------------------------------------------
+# engine loop spans in a profiler capture
+# ----------------------------------------------------------------------
+
+LOOP = ["engine.part_stats", "engine.split", "engine.dispatch",
+        "engine.sync", "engine.record"]
+
+
+def _captured_spans(fn):
+    """Run ``fn`` under ``jax.profiler.trace``; returns its result and the
+    ``engine.*`` complete events of ``perfetto_trace.json.gz``, outer
+    before inner."""
+    import gzip
+    import tempfile
+
+    import jax
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d, create_perfetto_trace=True):
+            out = fn()
+        (f,) = Path(d).glob("plugins/profile/*/perfetto_trace.json.gz")
+        with gzip.open(f, "rt") as fh:
+            events = json.load(fh)["traceEvents"]
+    spans = [e for e in events if e.get("ph") == "X"
+             and e["name"].startswith("engine.")]
+    return out, sorted(spans, key=lambda e: (e["ts"], -e["dur"]))
+
+
+def _span_run(layout, path):
+    """``(spans, stats)`` of one BFS through ``Engine.run`` or the
+    lockstep ``run_batched`` of a hybrid engine (three lanes)."""
+    from repro.apps.bfs import bfs_multi, bfs_program
+    from repro.core.engine import Engine
+    eng = Engine(layout, bfs_program(), mode="hybrid")
+    state, frontier = _bfs_inputs(layout)
+
+    def go():
+        if path == "run":
+            return eng.run(state, frontier)[2]
+        return bfs_multi(layout, [0, 1, 2], engine=eng)["stats"]
+    go()                                # compile outside the capture
+    stats, spans = _captured_spans(go)
+    return spans, stats
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+class TestEngineSpans:
+    @pytest.mark.parametrize("path", ["run", "lockstep"])
+    def test_span_tree(self, obs_on, layout, path):
+        spans, stats = _span_run(layout, path)
+        eps = 0.01                                  # us of rounding
+        assert len({(e["pid"], e["tid"]) for e in spans}) == 1
+        (run,) = [e for e in spans if e["name"] == "engine.run"]
+        assert run["args"] == {"program": "bfs", "mode": "hybrid"}
+        steps = [e for e in spans if e["name"] == "engine.superstep"]
+        # one per loop pass: each recorded superstep, then the pass that
+        # finds the frontier empty
+        assert len(steps) == len(stats) + 1
+        inner = [e for e in spans if e["name"] in LOOP]
+        for i, step in enumerate(steps):
+            assert run["ts"] - eps <= step["ts"] <= _end(step) \
+                <= _end(run) + eps
+            kids = [e for e in inner if step["ts"] - eps <= e["ts"]
+                    and _end(e) <= _end(step) + eps]
+            if i == len(stats):
+                want = ["engine.part_stats"]
+            elif path == "run":
+                want = LOOP
+            else:
+                lanes = stats[i].lanes_active
+                want = (LOOP[:1] + LOOP[1:3] * lanes + LOOP[3:])
+            assert [e["name"] for e in kids] == want
+            for a, b in zip(kids, kids[1:]):
+                assert _end(a) <= b["ts"] + eps      # siblings, in order
+            assert step["args"]["it"] == str(i)
+            if i < len(stats):
+                assert "sc_budget" in step["args"]
+                assert int(step["args"]["new_programs"]) == 0
+                if path == "run":
+                    assert int(step["args"]["sc_budget"]) \
+                        == stats[i].sc_budget
+                    assert int(step["args"]["sc_parts"]) \
+                        == stats[i].sc_parts
+                else:
+                    assert int(step["args"]["lanes"]) \
+                        == stats[i].lanes_active
+        # every inner span lies in exactly one superstep
+        for e in inner:
+            assert sum(s["ts"] - eps <= e["ts"] and _end(e) <= _end(s) + eps
+                       for s in steps) == 1
+
+    @pytest.mark.parametrize("path", ["run", "lockstep"])
+    def test_disabled_mode_opens_no_span(self, layout, path):
+        with obs.override_enabled(False):
+            spans, stats = _span_run(layout, path)
+        assert stats and spans == []
+
+    def test_annotation_is_a_profiler_span_or_a_no_op(self):
+        import jax
+        with obs.override_enabled(True):
+            assert isinstance(obs.annotation("x", a=1),
+                              jax.profiler.TraceAnnotation)
+        with obs.override_enabled(False):
+            with obs.annotation("x", a=1) as span:
+                span.set_metadata(b=2)
+            assert not isinstance(span, jax.profiler.TraceAnnotation)
 
 
 # ----------------------------------------------------------------------

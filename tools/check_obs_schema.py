@@ -13,7 +13,8 @@ the two never diverge).  The validation rules mirror
   * every record needs a known ``"event"`` type and a numeric ``"ts"``;
   * every field the schema marks required must be present with the
     declared type (``float`` accepts ints; ``bool`` is rejected where an
-    int/float is asked — bool is an int subclass in Python);
+    int/float is asked — bool is an int subclass in Python), and every
+    field it marks optional has that type where present;
   * extra fields are always allowed (events are forward-extensible).
 
 ``--require`` additionally fails the run when the file contains no
@@ -53,9 +54,11 @@ def validate_record(rec, schema):
     if not isinstance(rec.get("ts"), (int, float)) \
             or isinstance(rec.get("ts"), bool):
         errs.append(f"{ev}: missing/invalid 'ts'")
-    for field, tag in spec["required"].items():
+    optional = spec.get("optional", {})
+    for field, tag in [*spec["required"].items(), *optional.items()]:
         if field not in rec:
-            errs.append(f"{ev}: missing required field {field!r}")
+            if field not in optional:
+                errs.append(f"{ev}: missing required field {field!r}")
             continue
         v = rec[field]
         if isinstance(v, bool) and tag in ("int", "float"):

@@ -140,8 +140,7 @@ def demo():
     # a repeat of an answered query: exercises the LRU hit path
     srv.submit(GraphQuery(qid=99, app="bfs", params={"source": 0}))
     srv.run()
-    print(f"demo: {len(obs.events())} events, "
-          f"{len(obs.cost_samples())} cost samples "
+    print(f"demo: {len(obs.events())} events "
           f"(cache hits={srv.cache_hits} misses={srv.cache_misses})",
           file=sys.stderr)
     return obs.events()
