@@ -234,9 +234,7 @@ class Engine:
         # in the program as constants, gigabytes at chip scale.
         arrays = {
             "csr_indptr": L.csr_indptr, "csr_indices": L.csr_indices,
-            "csr_w": L.csr_w, "deg": L.deg.astype(np.int32),
-            "vert_part": (np.arange(L.n_pad, dtype=np.int64)
-                          // L.q).astype(np.int32)}
+            "csr_w": L.csr_w, "deg": L.deg.astype(np.int32)}
         if self._fused is None:
             arrays.update(
                 png_src=L.png_src,
@@ -258,6 +256,7 @@ class Engine:
         self._part_stats = lambda active: _part_stats(active,
                                                       self.arrays["deg"])
         self._step_cache = {}            # SC budget / phase -> jitted fn
+        self._dc_one_gather = None       # the last DC phase's path
         self._sc_cap = _next_pow2(max(int(L.deg.sum()), 1))
 
     def _args(self):
@@ -280,7 +279,7 @@ class Engine:
         fn = self._step_cache.get("step")
         if fn is None:
             def step(state, active, dc_mask, it, args):
-                state, keep, _, acc, touched = self._dc_phase(
+                state, keep, acc, touched, _ = self._dc_phase(
                     state, active, dc_mask, it, args)
                 return self._apply_phase(state, keep, acc, touched, it)
             fn = self._step_cache["step"] = jax.jit(step)
@@ -309,15 +308,18 @@ class Engine:
                   stream: bool = True):
         """Scatter, initFrontier and the DC stream (paper Alg. 2:
         values-only messages over the pre-written dc_bin adjacency).
-        Returns ``(state, keep, msgs_p, acc, touched)`` over ``n_pad + 1``
-        slots, the last the identity sentinel.  ``stream=False`` skips
-        the DC stream, whose result is the identity when ``dc_mask`` is
-        all false."""
+        Returns ``(state, keep, acc, touched, one_gather)``, the
+        accumulators over ``n_pad + 1`` slots, the last the identity
+        sentinel; ``one_gather`` is the fused kernel's report of its path
+        (None without a fused kernel).  ``stream=False`` skips the DC
+        stream, whose result is the identity when ``dc_mask`` is all
+        false.  The messages are not an output: the SC phase scatters
+        its own, and on a TPU XLA keeps the fused kernel's masked table
+        in on-chip memory only while the unmasked one is no output."""
         prog, mono, n_pad = self.program, self.program.monoid, self.n_pad
         A = args["engine"]
-        vert_part = A["vert_part"]
+        dc_v = self._vertex_mask(dc_mask)
         msgs = prog.scatter_fn(state).astype(mono.dtype)      # [n_pad]
-        msgs_p = jnp.concatenate([msgs, mono.identity_array((1,))])
 
         # ---- initFrontier (selective continuity) ----
         if prog.init_fn is not None:
@@ -328,20 +330,24 @@ class Engine:
             keep = jnp.zeros((n_pad,), jnp.bool_)
 
         if not stream:
-            return (state, keep, msgs_p, mono.identity_array((n_pad + 1,)),
-                    jnp.zeros((n_pad + 1,), jnp.bool_))
+            return (state, keep, mono.identity_array((n_pad + 1,)),
+                    jnp.zeros((n_pad + 1,), jnp.bool_), None)
         if self._fused is not None:
             # fused lowering: the kernel gathers each edge's source value
             # from msgs_p itself and folds it straight into the
             # accumulators — the [NM] bin buffer and the [NE] edge-value
-            # stream never materialize
+            # stream never materialize.  A vertex without out-edges feeds
+            # no edge: marking it invalid keeps its message (PageRank's
+            # 0.0, the add identity) from forcing the two-gather path
+            msgs_p = jnp.concatenate([msgs, mono.identity_array((1,))])
             table_valid = jnp.concatenate(
-                [active & dc_mask[vert_part], jnp.zeros((1,), jnp.bool_)])
-            acc, touched = self._fused(msgs_p, table_valid,
-                                       arrays=args["fused"])
-            return state, keep, msgs_p, acc, touched
+                [active & dc_v & (A["deg"] > 0),
+                 jnp.zeros((1,), jnp.bool_)])
+            acc, touched, one_gather = self._fused(msgs_p, table_valid,
+                                                   arrays=args["fused"])
+            return state, keep, acc, touched, one_gather
         active_p = jnp.concatenate([active, jnp.zeros((1,), jnp.bool_)])
-        msg_data = self._scatter_kernel(msgs, active & dc_mask[vert_part],
+        msg_data = self._scatter_kernel(msgs, active & dc_v,
                                         arrays=args["scatter"])
         dc_valid = active_p[A["png_src"]] & dc_mask[A["png_part"]]  # [NM]
         msg_data_p = jnp.concatenate([msg_data, mono.identity_array((1,))])
@@ -356,15 +362,26 @@ class Engine:
             arrays=args["gather"])
         acc = jnp.concatenate([acc, mono.identity_array((1,))])
         touched = jnp.concatenate([touched, jnp.zeros((1,), jnp.bool_)])
-        return state, keep, msgs_p, acc, touched
+        return state, keep, acc, touched, None
 
-    def _sc_phase(self, be, msgs_p, active, dc_mask, acc, touched, args):
+    def _vertex_mask(self, dc_mask):
+        """``dc_mask`` [k] per vertex: partition p holds vertices
+        ``p * q`` to ``(p + 1) * q - 1``.  (A lookup by a per-vertex
+        partition id lowers to a chain of k selects, after which XLA
+        puts the fused kernel's masked table in HBM.)"""
+        return jnp.repeat(dc_mask, self.q)
+
+    def _sc_phase(self, be, state, active, dc_mask, acc, touched, args):
         """The SC stream under a static edge budget ``be``: the CSR rows
         of the active vertices of SC partitions, expanded into ``be``
-        (value, dst) slots and folded into ``acc`` / ``touched``."""
+        (value, dst) slots and folded into ``acc`` / ``touched``.
+        ``state`` is the superstep's state before initFrontier, which
+        the DC phase scattered from too."""
         prog, mono, n_pad = self.program, self.program.monoid, self.n_pad
         A = args["engine"]
-        sc_active = active & ~dc_mask[A["vert_part"]]
+        msgs_p = jnp.concatenate([prog.scatter_fn(state).astype(mono.dtype),
+                                  mono.identity_array((1,))])
+        sc_active = active & ~self._vertex_mask(dc_mask)
         degs = jnp.where(sc_active, A["deg"], 0)               # [n_pad]
         start = jnp.cumsum(degs) - degs       # first slot of each row
         total = start[-1] + degs[-1]
@@ -501,7 +518,9 @@ class Engine:
         (span ``engine.dispatch``).  Returns ``(state, active, dc_mask,
         split)``, ``split`` the superstep's partitions and active edges
         by stream, its SC budget (0: no SC stream) and the phase programs
-        built for it (``new_programs``: their first call compiles)."""
+        built for it (``new_programs``: their first call compiles).  The
+        fused DC kernel's report of its path, a device scalar (None
+        without one), is kept as ``_dc_one_gather`` for the record."""
         with obs.annotation("engine.split"):
             has_active = counts > 0
             if self.mode == "dc":
@@ -525,10 +544,11 @@ class Engine:
         with obs.annotation("engine.dispatch"):
             args, mask = self._args(), jnp.asarray(dc_mask)
             it32 = jnp.int32(it)
-            state, keep, msgs_p, acc, touched = dc_fn(state, active, mask,
-                                                      it32, args)
+            state0 = state
+            state, keep, acc, touched, self._dc_one_gather = dc_fn(
+                state, active, mask, it32, args)
             if be:
-                acc, touched = sc_fn(msgs_p, active, mask, acc, touched,
+                acc, touched = sc_fn(state0, active, mask, acc, touched,
                                      args)
             state, active = apply_fn(state, keep, acc, touched, it32)
         return state, active, dc_mask, split
@@ -536,7 +556,10 @@ class Engine:
     def _record_iter(self, it, n_active, ea, counts, dc_mask, split, wall):
         """The superstep's :class:`~repro.obs.IterStats`, also recorded
         through :func:`repro.obs.record_engine_iter` with the active
-        edges of each stream (``dc_e`` / ``sc_e``)."""
+        edges of each stream (``dc_e`` / ``sc_e``) and, where the fused
+        DC kernel ran, whether it gathered each edge once
+        (``dc_one_gather``; read after the sync, so it waits on
+        nothing)."""
         b = self.cost.bytes_for(dc_mask, ea, counts > 0)
         dc_p, sc_p = split["dc_parts"], split["sc_parts"]
         mode_str = "dc" if sc_p == 0 else "sc" if dc_p == 0 else "hybrid"
@@ -546,8 +569,11 @@ class Engine:
             dc_bytes=b["dc_bytes"], sc_bytes=b["sc_bytes"],
             wall_s=wall, mode=mode_str, program=self.program.name,
             sc_budget=split["sc_budget"])
+        extra = {}
+        if self._dc_one_gather is not None and obs.enabled():
+            extra["dc_one_gather"] = bool(self._dc_one_gather)
         obs.record_engine_iter("core", st, dc_e=split["dc_e"],
-                               sc_e=split["sc_e"])
+                               sc_e=split["sc_e"], **extra)
         return st
 
     # ------------------------------------------------------------------

@@ -295,16 +295,16 @@ def build_dc_step(program: VertexProgram, meta: dict,
                 # static slot/validity/dst streams are shared)
                 accs, touch = [], []
                 for i in range(table.shape[0]):
-                    a, t = fused(table[i], rf[i], slot, evalid_s, dst_s,
-                                 nv + 1, w=w, apply_weight=aw,
-                                 presorted=True)
+                    a, t, _ = fused(table[i], rf[i], slot, evalid_s,
+                                    dst_s, nv + 1, w=w, apply_weight=aw,
+                                    presorted=True)
                     accs.append(a)
                     touch.append(t)
                 acc, touched = jnp.stack(accs), jnp.stack(touch)
             else:
-                acc, touched = fused(table, rf, slot, evalid_s, dst_s,
-                                     nv + 1, w=w, apply_weight=aw,
-                                     presorted=True)
+                acc, touched, _ = fused(table, rf, slot, evalid_s, dst_s,
+                                        nv + 1, w=w, apply_weight=aw,
+                                        presorted=True)
         else:
             slot = A["in_msg_slot"]
             ev = rv[..., slot].astype(mono.dtype)             # [.., NEd]

@@ -391,7 +391,9 @@ class FusedDCKernel(LayoutBound):
     into the two-level ``[fold_q]`` sub-accumulators — no ``[NM]`` bin
     buffer (see :mod:`repro.kernels.fused_step`).  The static edge stream
     is kept in destination order, sorted once here, so the fold runs
-    without a per-call sort.
+    without a per-call sort.  A call returns ``(acc, touched,
+    one_gather)``, the last whether the step gathered each edge once (see
+    :func:`~repro.kernels.fused_step.fused_scatter_fold`).
 
     ``apply_weight`` is engine-configured (the registry does not see the
     program): :class:`repro.core.engine.Engine` sets the attribute once,
@@ -450,12 +452,14 @@ class FusedDCKernel(LayoutBound):
                 table_valid, (axis_size,) + table_valid.shape)
         out = jax.lax.map(lambda lane: self._single(*lane, A),
                           (table, table_valid))
-        return out, (True, True)
+        return out, (True, True, True)
 
 
 class RefFusedDC(LayoutBound):
     """Pure-jnp fused DC step with FusedDCKernel's exact call contract —
     the composed oracle collapsed to one gather + one segmented fold.
+    It gathers value and validity apart on every call, so its
+    ``one_gather`` is always false.
 
     Carries the same ``custom_vmap`` rule as :class:`RefGather` (the
     batched multi-source engine path): the table gather batches fine,
@@ -485,10 +489,11 @@ class RefFusedDC(LayoutBound):
 
     def _single(self, table, table_valid, A):
         aw = self.apply_weight
-        return ref_fused_scatter_fold(
+        acc, touched = ref_fused_scatter_fold(
             self.monoid, table, table_valid, A["edge_src"],
             A["edge_valid"], A["edge_dst"], self.n_pad + 1,
             apply_weight=aw, w=A["edge_w"] if aw is not None else None)
+        return acc, touched, jnp.bool_(False)
 
     def _vmap_rule(self, axis_size, in_batched, table, table_valid, A):
         tb, tvb, a_b = in_batched
@@ -513,8 +518,10 @@ class RefFusedDC(LayoutBound):
             return vals, valid, jnp.where(valid, A["edge_dst"][None, :],
                                           ns - 1)
 
-        return (_fold_lanes_flat(mono, lanes, (table, table_valid), ns,
-                                 src.shape[0]), (True, True))
+        acc, touched = _fold_lanes_flat(mono, lanes, (table, table_valid),
+                                        ns, src.shape[0])
+        return ((acc, touched, jnp.zeros((axis_size,), jnp.bool_)),
+                (True, True, True))
 
 
 class FusedStreamKernel:
@@ -526,7 +533,8 @@ class FusedStreamKernel:
     the slot indices and the static validity per call: an XLA slot
     gather, the edge function, then the two-level Pallas fold, which
     skips its sort when the caller passes ``presorted=True`` (a stream
-    in destination order, as ``shard_layout`` keeps it).
+    in destination order, as ``shard_layout`` keeps it).  Returns
+    ``(acc, touched, one_gather)``, as :class:`FusedDCKernel` does.
     """
 
     def __init__(self, monoid_name: str, dtype, interpret: bool = True,
@@ -552,7 +560,8 @@ class FusedStreamKernel:
 
 
 class RefFusedStream:
-    """Pure-jnp stream fused step with FusedStreamKernel's call contract."""
+    """Pure-jnp stream fused step with FusedStreamKernel's call contract
+    (``one_gather`` always false)."""
 
     def __init__(self, monoid):
         self.monoid = monoid
@@ -562,9 +571,10 @@ class RefFusedStream:
                  presorted: bool = False):
         with obs_tracing.kernel_scope(
                 getattr(self, "_obs_scope", "ppm.fused_dc.ref")):
-            return ref_fused_scatter_fold(
+            acc, touched = ref_fused_scatter_fold(
                 self.monoid, table, table_valid, idx, edge_valid, dst,
                 int(num_segments), apply_weight=apply_weight, w=w)
+        return acc, touched, jnp.bool_(False)
 
 
 class RefScatter(LayoutBound):

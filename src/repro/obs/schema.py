@@ -65,12 +65,14 @@ EVENT_SCHEMA = {
     "events": {
         # one engine iteration (single-device or distributed); dist steps
         # add wire_bytes (analytic all_to_all payload), single-device ones
-        # the active edges of each stream and the SC budget class
+        # the active edges of each stream, the SC budget class and, where
+        # the fused DC kernel ran, whether it gathered each edge once
         "engine_iter": {
             "required": {"engine": "str", "program": "str", "it": "int",
                          "mode": "str", "n_active": "int",
                          "e_active": "int", "wall_s": "float"},
-            "optional": {"dc_e": "int", "sc_e": "int", "sc_budget": "int"},
+            "optional": {"dc_e": "int", "sc_e": "int", "sc_budget": "int",
+                         "dc_one_gather": "bool"},
         },
         # one batched (multi-source) engine step
         "batch_iter": {

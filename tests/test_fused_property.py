@@ -55,7 +55,7 @@ def test_fused_matches_ref_oracle(data):
     assert_kernel_equiv(
         lambda *a: fused_scatter_fold(*a, ns, monoid=monoid,
                                       edge_tile=tile, fold_q=q,
-                                      interpret=True),
+                                      interpret=True)[:2],
         lambda *a: ref_fused_scatter_fold(mono, *a, ns),
         (table, tvalid, idx, evalid, dst))
 
@@ -75,7 +75,7 @@ def test_fused_presorted_matches_ref_oracle(data):
     assert_kernel_equiv(
         lambda *a: fused_scatter_fold(*a, ns, monoid=monoid,
                                       edge_tile=tile, fold_q=q,
-                                      interpret=True, presorted=True),
+                                      interpret=True, presorted=True)[:2],
         lambda *a: ref_fused_scatter_fold(mono, *a, ns),
         case)
 
@@ -104,7 +104,7 @@ def test_fused_matches_composed_gather_fold_overcap(data):
     assert_kernel_equiv(
         lambda *a: fused_scatter_fold(*a, ns, monoid=monoid,
                                       edge_tile=tile, fold_q=q,
-                                      interpret=True),
+                                      interpret=True)[:2],
         composed,
         (table, tvalid, idx, evalid, dst))
 
@@ -115,7 +115,8 @@ def test_fused_registry_backends_agree(data):
     """The registry triple: the ``pallas-interpret`` stream kernel and the
     ``ref`` stream kernel implement the same ``fused_dc`` contract,
     apply_weight included (the sssp-style relax keeps integer payloads
-    integer, so the check stays bit-exact)."""
+    integer, so the check stays bit-exact).  Their reports of the path
+    taken differ by design (``ref`` always gathers twice)."""
     monoid, dtype, mono = draw_monoid(data)
     ns = data.draw(st.sampled_from(NUM_SEGMENTS))
     q = data.draw(st.sampled_from(FOLD_QS))
@@ -129,7 +130,7 @@ def test_fused_registry_backends_agree(data):
                                                             q=q)
     rk = registry.BACKENDS["ref"].fused_stream(mono)
     args = (table, tvalid, idx, evalid, dst, ns, w, _relax)
-    assert_kernel_equiv(pk, rk, args)
+    assert_kernel_equiv(lambda *a: pk(*a)[:2], lambda *a: rk(*a)[:2], args)
 
 
 def test_fused_empty_and_all_invalid():
@@ -149,9 +150,9 @@ def test_fused_empty_and_all_invalid():
          jnp.zeros(9, bool), jnp.zeros(9, jnp.int32)),    # all-pad edges
     ]
     for args in cases:
-        acc, touched = fused_scatter_fold(*args, ns, monoid="min",
-                                          edge_tile=8, fold_q=4,
-                                          interpret=True)
+        acc, touched, _ = fused_scatter_fold(*args, ns, monoid="min",
+                                             edge_tile=8, fold_q=4,
+                                             interpret=True)
         assert np.array_equal(np.asarray(acc),
                               np.full(ns, mono.identity, np.uint32))
         assert not np.asarray(touched).any()
@@ -167,9 +168,9 @@ def test_fused_out_of_range_dst_contributes_nothing():
     idx = jnp.zeros((8,), jnp.int32)
     ev = jnp.ones((8,), bool)
     dst = jnp.asarray(np.array([0, 5, 9, 10, 11, 50, -3, -1], np.int32))
-    acc, touched = fused_scatter_fold(table, tv, idx, ev, dst, ns,
-                                      monoid="add", edge_tile=4, fold_q=3,
-                                      interpret=True)
+    acc, touched, _ = fused_scatter_fold(table, tv, idx, ev, dst, ns,
+                                         monoid="add", edge_tile=4,
+                                         fold_q=3, interpret=True)
     want = np.zeros(ns, np.float32)
     want[[0, 5, 9]] = 1.0
     assert np.array_equal(np.asarray(acc), want)
@@ -289,3 +290,220 @@ def test_dist_cc_fused_parity_shard_map(monkeypatch):
                        timeout=600)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "dist fused parity ok" in r.stdout
+
+
+# ----------------------------------------------------------------------
+# one gather per edge: each source's validity carried in its value
+# ----------------------------------------------------------------------
+
+ONE_GATHER_MONOIDS = [("add", "float32"), ("min", "uint32"),
+                      ("max", "int32")]
+
+
+def _one_gather_case(mono, dtype, seed, ns=13, m=40, ne=150):
+    """A fused case in which no valid slot holds the identity's bits and
+    every invalid slot holds junk: the identity, NaN/inf for floats, or
+    a random payload."""
+    rng = np.random.default_rng(seed)
+    table = np.asarray(payload(rng, m, dtype))
+    ident = np.asarray(mono.identity, dtype)
+    table = np.where(table.view(f"u{table.itemsize}")
+                     == ident.view(f"u{table.itemsize}"),
+                     np.asarray(1, dtype), table)
+    tvalid = rng.random(m) < 0.6
+    junk = [ident] + ([np.asarray(np.nan, dtype), np.asarray(np.inf, dtype)]
+                      if np.dtype(dtype).kind == "f" else [])
+    pick = rng.integers(0, len(junk) + 1, m)
+    for j, v in enumerate(junk):
+        table = np.where(~tvalid & (pick == j), v, table)
+    idx = rng.integers(0, m, ne).astype(np.int32)
+    evalid = rng.random(ne) < 0.8
+    dst = rng.integers(0, ns, ne).astype(np.int32)
+    w = np.asarray(payload(rng, ne, dtype))
+    if np.dtype(dtype).kind != "u":
+        w = np.abs(w)
+    return (jnp.asarray(table), jnp.asarray(tvalid), jnp.asarray(idx),
+            jnp.asarray(evalid), jnp.asarray(dst), jnp.asarray(w))
+
+
+@pytest.mark.parametrize("presorted", [False, True])
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("monoid,dtype", ONE_GATHER_MONOIDS)
+def test_fused_one_gather_matches_ref(monoid, dtype, weighted, presorted):
+    """With no valid slot on the identity the step gathers once, reads
+    each edge's validity from the masked table's value (before the edge
+    function), and folds to the two-gather oracle's answer bit for bit,
+    junk in the invalid slots included."""
+    from kernel_harness import MONOIDS
+    mono = MONOIDS[(monoid, dtype)]()
+    ns = 13
+    aw = _relax if weighted else None
+    for seed in range(3):
+        table, tv, idx, ev, dst, w = _one_gather_case(mono, dtype, seed, ns)
+        if presorted:
+            order = jnp.argsort(dst, stable=True)
+            idx, ev, dst, w = idx[order], ev[order], dst[order], w[order]
+        acc, touched, one = fused_scatter_fold(
+            table, tv, idx, ev, dst, ns, monoid=monoid, edge_tile=8,
+            fold_q=4, interpret=True, apply_weight=aw,
+            w=w if weighted else None, presorted=presorted)
+        assert bool(one)
+        assert_kernel_equiv(
+            lambda *a: (acc, touched),
+            lambda *a: ref_fused_scatter_fold(
+                mono, *a, ns, apply_weight=aw, w=w if weighted else None),
+            (table, tv, idx, ev, dst))
+
+
+@pytest.mark.parametrize("slot_valid", [True, False])
+@pytest.mark.parametrize("monoid,dtype,value,on_identity", [
+    ("add", "float32", 0.0, True),
+    ("add", "float32", -0.0, False),      # other bits than 0.0's
+    ("min", "uint32", 0xFFFFFFFF, True),
+])
+def test_fused_falls_back_exactly_when_a_valid_slot_holds_identity(
+        monoid, dtype, value, on_identity, slot_valid):
+    """A value bit-equal to the identity in a slot that is valid, and
+    read by valid edges, sends the step down the two-gather path; in an
+    invalid slot, or with other bits (-0.0), it does not.  Either way
+    the answer is the oracle's."""
+    from kernel_harness import MONOIDS
+    mono = MONOIDS[(monoid, dtype)]()
+    ns = 13
+    table, tv, idx, ev, dst, _ = _one_gather_case(mono, dtype, 7, ns)
+    table = table.at[5].set(np.asarray(value, dtype))
+    tv = tv.at[5].set(slot_valid)
+    idx = idx.at[:4].set(5)
+    ev = ev.at[:4].set(True)
+    acc, touched, one = fused_scatter_fold(
+        table, tv, idx, ev, dst, ns, monoid=monoid, edge_tile=8, fold_q=4,
+        interpret=True)
+    assert bool(one) == (not (slot_valid and on_identity))
+    assert_kernel_equiv(lambda *a: (acc, touched),
+                        lambda *a: ref_fused_scatter_fold(mono, *a, ns),
+                        (table, tv, idx, ev, dst))
+
+
+@pytest.mark.parametrize("monoid,dtype", ONE_GATHER_MONOIDS
+                         + [("max", "float32")])
+def test_fused_invalid_slots_never_count(monoid, dtype):
+    """Every edge reads an invalid slot that holds junk (the identity,
+    NaN, inf, a payload): nothing is folded and nothing is touched, on
+    the one-gather path."""
+    from kernel_harness import MONOIDS
+    mono = MONOIDS[(monoid, dtype)]()
+    ns = 13
+    table, tv, idx, ev, dst, _ = _one_gather_case(mono, dtype, 11, ns)
+    tv = tv & (jnp.arange(tv.shape[0]) % 2 == 0)
+    idx = 2 * (idx // 2) + 1                       # odd slots: invalid
+    acc, touched, one = fused_scatter_fold(
+        table, tv, idx, ev, dst, ns, monoid=monoid, edge_tile=8, fold_q=4,
+        interpret=True)
+    assert bool(one)
+    assert np.array_equal(np.asarray(acc),
+                          np.full(ns, mono.identity, np.dtype(dtype)))
+    assert not np.asarray(touched).any()
+
+
+def _iter_events():
+    """This run's engine_iter events, each checked against the event
+    schema and the checked-in JSON schema."""
+    import importlib.util
+    import json
+    from pathlib import Path
+    from repro import obs
+    tools = Path(__file__).resolve().parents[1] / "tools"
+    spec = importlib.util.spec_from_file_location(
+        "check_obs_schema", tools / "check_obs_schema.py")
+    checker = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(checker)
+    schema = json.loads((tools / "obs_schema.json").read_text())
+    events = obs.events("engine_iter")
+    for e in events:
+        assert obs.validate_event(e) == []
+        assert checker.validate_record(e, schema) == []
+    return events
+
+
+def _dc_paths(events):
+    """``dc_one_gather`` of each superstep that ran the DC stream; the
+    field is absent from every other one."""
+    for e in events:
+        assert ("dc_one_gather" in e) == (e["dc_parts"] > 0), e
+    return [e["dc_one_gather"] for e in events if e["dc_parts"] > 0]
+
+
+@pytest.fixture(scope="module")
+def one_gather_layout():
+    from repro.graph import build_layout, rmat
+    L = build_layout(rmat(7, 8, seed=3), k=4, edge_tile=32, msg_tile=16)
+    # vertices without out-edges, which PageRank sends 0.0 from
+    assert (L.deg[:L.n] == 0).any()
+    return L
+
+
+@pytest.mark.parametrize("app,mode", [("bfs", "dc"), ("bfs", "hybrid"),
+                                      ("pagerank", "dc")])
+def test_engine_reports_one_gather_on_every_dc_superstep(
+        one_gather_layout, monkeypatch, app, mode):
+    """``Engine.run`` with the fused kernel records ``dc_one_gather``
+    true on every DC superstep of BFS and of PageRank, answers as the
+    ``ref`` backend does, and the ``ref`` backend records false."""
+    from repro import obs
+    from repro.apps.bfs import bfs
+    from repro.apps.pagerank import pagerank
+    monkeypatch.setenv(ENV_FUSED, "1")
+    L = one_gather_layout
+    out, paths = {}, {}
+    for backend in ("pallas-interpret", "ref"):
+        obs.reset()
+        if app == "bfs":
+            res = bfs(L, 0, mode=mode, backend=backend)
+            out[backend] = (res["level"], res["parent"])
+        else:
+            res = pagerank(L, iters=3, mode=mode, fused=False,
+                           backend=backend)
+            out[backend] = (res["pr"],)
+        paths[backend] = _dc_paths(_iter_events())
+    assert paths["pallas-interpret"] and all(paths["pallas-interpret"])
+    assert paths["ref"] and not any(paths["ref"])
+    for got, want in zip(out["pallas-interpret"], out["ref"]):
+        if app == "bfs":
+            assert np.array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+
+
+def test_engine_identity_message_takes_two_gathers(one_gather_layout,
+                                                   monkeypatch):
+    """An add program whose active vertices with out-edges send 0.0 (the
+    identity) records ``dc_one_gather`` false and answers as the ``ref``
+    backend does."""
+    from repro import obs
+    from repro.core import monoid as M
+    from repro.core.engine import Engine
+    from repro.core.program import VertexProgram
+
+    def apply_fn(state, acc, touched, it):
+        return dict(state, x=jnp.where(touched, state["x"] + acc,
+                                       state["x"])), touched
+
+    prog = VertexProgram(name="sumprop", monoid=M.add(jnp.float32),
+                         scatter_fn=lambda s: s["x"], apply_fn=apply_fn)
+    monkeypatch.setenv(ENV_FUSED, "1")
+    L = one_gather_layout
+    rng = np.random.default_rng(0)
+    x0 = rng.integers(1, 8, L.n_pad).astype(np.float32)
+    x0[np.nonzero(L.deg > 0)[0][::3]] = 0.0
+    frontier = np.zeros(L.n_pad, bool)
+    frontier[:L.n] = True
+    out, paths = {}, {}
+    for backend in ("pallas-interpret", "ref"):
+        obs.reset()
+        eng = Engine(L, prog, mode="dc", backend=backend)
+        state, _, _ = eng.run({"x": jnp.asarray(x0)}, frontier, max_iters=1)
+        out[backend] = np.asarray(state["x"])
+        paths[backend] = _dc_paths(_iter_events())
+    assert paths["pallas-interpret"] == [False]
+    assert paths["ref"] == [False]
+    assert np.array_equal(out["pallas-interpret"], out["ref"])

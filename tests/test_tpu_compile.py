@@ -14,6 +14,7 @@ test worker imports this file.
 """
 import functools
 import os
+import re
 import types
 
 import jax
@@ -21,8 +22,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.apps.bfs import bfs_program
 from repro.backend import registry
 from repro.core import monoid as M
+from repro.core.engine import Engine
 from repro.kernels import ops as kops
 from repro.kernels.dc_gather import dc_gather
 from repro.kernels.fold_block import blocked_segment_fold
@@ -70,6 +73,33 @@ def _fits(compiled):
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 2**30:.1f} GiB > one chip's HBM"
     return mem
+
+
+def _gather_sites(hlo: str, n: int):
+    """Where the compiled module gathers ``n`` elements: one entry per
+    gather, the computation that runs it (a fusion's caller), ``branch``
+    for a branch of a conditional."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(", line)
+        if head:
+            cur = "ENTRY" if head.group(1) else head.group(2)
+            comps[cur] = []
+        elif cur is not None:
+            comps[cur].append(line)
+    branches = {b for body in comps.values() for line in body
+                for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                        line)
+                for b in re.findall(r"%([\w.\-]+)", group)}
+    callers = {}
+    for comp, body in comps.items():
+        for line in body:
+            for callee in re.findall(r"calls=%([\w.\-]+)", line):
+                callers.setdefault(callee, []).append(comp)
+    sites = [site for comp, body in comps.items() for line in body
+             if re.search(rf"= \w+\[{n}\]\S* gather\(", line)
+             for site in callers.get(comp, [comp])]
+    return sorted("branch" if s in branches else s for s in sites)
 
 
 def test_tpu_table_declares_every_kernel():
@@ -122,6 +152,43 @@ def test_dc_fused_ref_compiles(one_chip, monoid):
         S((N_EDGES,), jnp.int32)).compile()
     _fits(compiled)
     assert "tpu_custom_call" in compiled.as_text()
+    # one gather of the edge stream outside the cond (the values), the
+    # validity's only inside its fallback branch: a gather common to
+    # both branches would be hoisted and the fast path gather twice
+    assert _gather_sites(compiled.as_text(), N_EDGES) == ["ENTRY", "branch"]
+
+
+def test_dc_phase_gathers_from_on_chip_memory(one_chip):
+    """BFS's DC program (``Engine._dc_phase``, fused): the one gather
+    reads the masked message table from on-chip memory (``S(1)``).  XLA
+    leaves it in HBM when the program also returns the unmasked
+    messages, or masks by a per-vertex partition id (chip_smoke.py's
+    partition count, k = 32)."""
+    k = 32
+    eng = Engine.__new__(Engine)        # the program's shapes, no layout
+    eng.program, eng.n_pad, eng.q = bfs_program(), N_PAD, N_PAD // k
+    eng._fused = kops.FusedDCKernel(
+        types.SimpleNamespace(n_pad=N_PAD, fold_tile=FOLD_TILE,
+                              fold_q=FOLD_Q), "min", jnp.uint32,
+        interpret=False)
+    eng._fused.apply_weight = None
+    S = _spec(one_chip)
+    vec = lambda dtype: S((N_PAD,), dtype)            # noqa: E731
+    state = {"parent": vec(jnp.int32), "level": vec(jnp.int32),
+             "vid": vec(jnp.uint32)}
+    args = {"engine": {"deg": vec(jnp.int32)},
+            "fused": {"edge_src": S((N_EDGES,), jnp.int32),
+                      "edge_valid": S((N_EDGES,), jnp.int32),
+                      "edge_dst": S((N_EDGES,), jnp.int32), "edge_w": None}}
+    compiled = jax.jit(eng._dc_phase).lower(
+        state, vec(jnp.bool_), S((k,), jnp.bool_), S((), jnp.int32),
+        args).compile()
+    _fits(compiled)
+    entry = compiled.as_text().split("\nENTRY ")[1]
+    table = re.search(rf"= u32\[{N_EDGES}\]\S* fusion\(%([\w.\-]+)",
+                      entry).group(1)
+    assert re.search(rf"%{re.escape(table)} = u32\[{N_PAD + 1}\]"
+                     r"\{[^}]*S\(1\)\}", entry), table
 
 
 def test_batched_dc_fused_ref_fits_one_chip(one_chip):
