@@ -171,8 +171,10 @@ def check_bfs_tree(g, keys, root: int, level, parent, want_level):
 
 
 def ref_pagerank(g, iters: int, damping: float = 0.85):
-    """Float64 power iteration with the app's semantics: every vertex
-    sends rank/out-degree along its out-edges; rank' = (1-d)/n + d*sum."""
+    """Float64 power iteration with the app's semantics (LDBC
+    Graphalytics): every vertex sends rank/out-degree along its
+    out-edges, and the rank of vertices without out-edges is spread over
+    every vertex; rank' = (1-d)/n + d*(sum + dangling/n)."""
     import numpy as np
 
     from repro.graph import to_scipy
@@ -181,7 +183,8 @@ def ref_pagerank(g, iters: int, damping: float = 0.85):
     pr = np.full(g.n, 1.0 / g.n)
     for _ in range(iters):
         share = np.divide(pr, deg, out=np.zeros_like(pr), where=deg > 0)
-        pr = (1.0 - damping) / g.n + damping * (AT @ share)
+        dangling = pr[deg == 0].sum()
+        pr = (1.0 - damping) / g.n + damping * (AT @ share + dangling / g.n)
     return pr
 
 

@@ -1,9 +1,15 @@
-"""PageRank (paper Alg. 6).
+"""PageRank (paper Alg. 6), as LDBC Graphalytics defines it.
 
-scatterFunc -> rank/deg;  initFunc -> zero the rank, stay active;
-gatherFunc -> accumulate;  filterFunc -> damping.  All vertices stay active
-every iteration, so the engine runs the fully-fused DC path (paper §6.2.2:
-"PageRank always uses DC mode").
+    PR_0(v) = 1/n
+    PR_i(v) = (1-d)/n + d * sum_{u->v} PR_{i-1}(u)/|out(u)|
+                      + (d/n) * sum_{w in D} PR_{i-1}(w)
+
+``D`` is the set of dangling vertices (no out-edge): their rank is spread
+over every vertex, so the total stays 1.  scatterFunc -> rank/deg;
+initFunc -> zero the rank, stay active; gatherFunc -> accumulate;
+filterFunc -> damping plus the dangling term, whose sum is the program's
+aggregate.  All vertices stay active every iteration, so the engine runs
+the fully-fused DC path (paper §6.2.2: "PageRank always uses DC mode").
 """
 from __future__ import annotations
 
@@ -28,13 +34,18 @@ def pagerank_program(n: int, damping: float = 0.85) -> VertexProgram:
     def apply_fn(state, acc, touched, it):
         return dict(state, pr=state["pr"] + acc), jnp.ones_like(touched)
 
-    def filter_fn(state, it):
-        return dict(state, pr=base + damping * state["pr"]), \
+    def dangling(state):
+        return jnp.where(state["deg"] > 0, 0.0, state["pr"])
+
+    def filter_fn(state, it, dangling_mass):
+        teleport = base + damping * dangling_mass / n
+        return dict(state, pr=teleport + damping * state["pr"]), \
             jnp.ones(state["pr"].shape, jnp.bool_)
 
     return VertexProgram(name="pagerank", monoid=M.add(jnp.float32),
                          scatter_fn=scatter_fn, apply_fn=apply_fn,
-                         init_fn=init_fn, filter_fn=filter_fn)
+                         init_fn=init_fn, filter_fn=filter_fn,
+                         aggregate=dangling)
 
 
 def pagerank(layout, iters: int = 10, damping: float = 0.85,

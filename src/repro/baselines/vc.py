@@ -84,6 +84,8 @@ def bfs_ec(g, source):
 # ---------------------------------------------------------------------------
 
 def pagerank_spmv(g: Graph, iters: int = 10, damping: float = 0.85):
+    """LDBC Graphalytics PageRank (:mod:`repro.apps.pagerank`): the rank
+    of vertices without out-edges is spread over every vertex."""
     E = _prep(g)
     n = E["n"]
 
@@ -91,9 +93,10 @@ def pagerank_spmv(g: Graph, iters: int = 10, damping: float = 0.85):
     def run(pr):
         def body(_, pr):
             contrib = jnp.where(E["deg"] > 0, pr / E["deg"], 0.0)
+            dangling = jnp.sum(jnp.where(E["deg"] > 0, 0.0, pr))
             acc = jax.ops.segment_sum(contrib[E["src"]], E["dst"],
                                       num_segments=n)
-            return (1 - damping) / n + damping * acc
+            return (1 - damping + damping * dangling) / n + damping * acc
         return jax.lax.fori_loop(0, iters, body, pr)
 
     pr = run(jnp.full((n,), 1.0 / n, jnp.float32))

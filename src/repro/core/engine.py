@@ -279,11 +279,22 @@ class Engine:
         fn = self._step_cache.get("step")
         if fn is None:
             def step(state, active, dc_mask, it, args):
+                agg = self._aggregate(state)
                 state, keep, acc, touched, _ = self._dc_phase(
                     state, active, dc_mask, it, args)
-                return self._apply_phase(state, keep, acc, touched, it)
+                return self._apply_phase(state, keep, acc, touched, it, agg)
             fn = self._step_cache["step"] = jax.jit(step)
         return fn
+
+    def _aggregate(self, state):
+        """The program's aggregate (:class:`VertexProgram` ``aggregate``)
+        summed over the real vertices, ids below ``n``; None for a
+        program without one."""
+        if self.program.aggregate is None:
+            return None
+        vals = self.program.aggregate(state)
+        real = jnp.arange(vals.shape[-1]) < self.layout.n
+        return jnp.sum(jnp.where(real, vals, 0), axis=-1)
 
     def _phase_fns(self, be: int, dc: bool = True):
         """``(dc, sc, apply)`` jitted phases of one superstep.  The DC
@@ -291,12 +302,16 @@ class Engine:
         stream compiles per budget, so a deep frontier's many budgets do
         not each recompile the DC stream's whole-edge-set fold.  With
         ``dc=False`` (no DC partition this step) the first phase skips
-        the DC stream and returns identity accumulators."""
+        the DC stream and returns identity accumulators.  A program with
+        an aggregate gets one more program, ``aggregate``, which
+        :meth:`_superstep` runs first."""
         if "dc" not in self._step_cache:
             self._step_cache["dc"] = jax.jit(self._dc_phase)
             self._step_cache["no_dc"] = jax.jit(
                 functools.partial(self._dc_phase, stream=False))
             self._step_cache["apply"] = jax.jit(self._apply_phase)
+            if self.program.aggregate is not None:
+                self._step_cache["aggregate"] = jax.jit(self._aggregate)
         key = ("sc", be)
         if be and key not in self._step_cache:
             self._step_cache[key] = jax.jit(
@@ -405,15 +420,17 @@ class Engine:
         acc2, touched2 = self._fold(vals, valid, dst, n_pad + 1)
         return mono.combine(acc, acc2), touched | touched2
 
-    def _apply_phase(self, state, keep, acc, touched, it):
-        """Gather apply and filterFrontier on the union frontier."""
+    def _apply_phase(self, state, keep, acc, touched, it, agg=None):
+        """Gather apply and filterFrontier on the union frontier; ``agg``
+        is the superstep's :meth:`_aggregate`, which ``filter_fn`` takes
+        where the program has one."""
         prog, n_pad = self.program, self.n_pad
         acc, touched = acc[:n_pad], touched[:n_pad]
         st3, activated = prog.apply_fn(state, acc, touched, it)
         state = _tree_where(touched, st3, state)
         new_active = keep | (activated & touched)
         if prog.filter_fn is not None:
-            st4, fkeep = prog.filter_fn(state, it)
+            st4, fkeep = prog.filter(state, it, agg)
             state = _tree_where(new_active, st4, state)
             new_active = new_active & fkeep
         return state, new_active
@@ -545,12 +562,14 @@ class Engine:
             args, mask = self._args(), jnp.asarray(dc_mask)
             it32 = jnp.int32(it)
             state0 = state
+            agg_fn = self._step_cache.get("aggregate")
+            agg = None if agg_fn is None else agg_fn(state0)
             state, keep, acc, touched, self._dc_one_gather = dc_fn(
                 state, active, mask, it32, args)
             if be:
                 acc, touched = sc_fn(state0, active, mask, acc, touched,
                                      args)
-            state, active = apply_fn(state, keep, acc, touched, it32)
+            state, active = apply_fn(state, keep, acc, touched, it32, agg)
         return state, active, dc_mask, split
 
     def _record_iter(self, it, n_active, ea, counts, dc_mask, split, wall):
@@ -709,28 +728,50 @@ class Engine:
                                   wall_s=wall)
 
     # ------------------------------------------------------------------
+    def _fused_loop(self, iters: int):
+        """Jitted ``loop(state, active, args)``: ``iters`` DC steps
+        (:meth:`_step_fn`, every partition on the DC stream) in one
+        ``fori_loop``; cached per ``iters``, so a repeated call compiles
+        nothing."""
+        key = ("fused", iters)
+        fn = self._step_cache.get(key)
+        if fn is None:
+            step, k = self._step_fn(), self.k
+
+            def loop(state, active, args):
+                dc_mask = jnp.ones((k,), jnp.bool_)
+
+                def body(it, carry):
+                    st, act = carry
+                    return step(st, act, dc_mask, it, args)
+                return jax.lax.fori_loop(0, iters, body, (state, active))
+            fn = self._step_cache[key] = jax.jit(loop)
+        return fn
+
     def run_fused(self, state, frontier, iters: int):
         """Fully-jitted fixed-iteration loop (DC mode, no host round trips).
 
         This is the PageRank-style path: all partitions scatter DC every
-        iteration (paper §6.2.2: "PageRank always uses DC mode").
+        iteration (paper §6.2.2: "PageRank always uses DC mode").  With
+        telemetry on, the call is one host span ``engine.fused``
+        (``iters``, ``dc_e``) around its ``engine.dispatch`` and
+        ``engine.sync`` and one ``fused_run`` event; ``dc_e`` is the DC
+        stream's active edges per iteration, counted from ``frontier`` as
+        :meth:`run` counts a superstep's.
         """
-        step = self._step_fn()
-        dc_mask = jnp.ones((self.k,), jnp.bool_)
-
-        @jax.jit
-        def loop(state, active, args):
-            def body(it, carry):
-                st, act = carry
-                return step(st, act, dc_mask, it, args)
-            return jax.lax.fori_loop(0, iters, body, (state, active))
-
+        loop = self._fused_loop(iters)
+        active = jnp.asarray(frontier, jnp.bool_)
         if not obs.enabled():
-            return loop(state, jnp.asarray(frontier, jnp.bool_),
-                        self._args())
-        t0 = time.perf_counter()
-        out = loop(state, jnp.asarray(frontier, jnp.bool_), self._args())
-        jax.block_until_ready(out)
+            return loop(state, active, self._args())
+        _, ea = self._part_stats(active)
+        dc_e = int(np.asarray(ea).sum())
+        with obs.annotation("engine.fused", iters=iters, dc_e=dc_e):
+            t0 = time.perf_counter()
+            with obs.annotation("engine.dispatch"):
+                out = loop(state, active, self._args())
+            with obs.annotation("engine.sync"):
+                jax.block_until_ready(out)
+            wall = time.perf_counter() - t0
         obs.event("fused_run", engine="core", program=self.program.name,
-                  iters=iters, wall_s=time.perf_counter() - t0)
+                  iters=iters, dc_e=dc_e, wall_s=wall)
         return out

@@ -137,6 +137,28 @@ def _fold_lanes(fold, vals, valid, ids, ns):
     return jnp.stack(accs), jnp.stack(touch)
 
 
+def _aggregate(program: VertexProgram, state, meta: dict, axes,
+               batched: bool = False):
+    """The program's aggregate over the real vertices of every shard (a
+    global id ``device * nv + local`` below ``n``): the local sum, then a
+    ``psum`` over the vertex axes; per lane when ``batched``.  None for a
+    program without one."""
+    if program.aggregate is None:
+        return None
+    agg = jax.vmap(program.aggregate) if batched else program.aggregate
+    vals = agg(state)                                         # [.., nv]
+    nv = meta["nv"]
+    gid = jax.lax.axis_index(axes) * nv + jnp.arange(nv)
+    local = jnp.sum(jnp.where(gid < meta["n"], vals, 0), axis=-1)
+    return jax.lax.psum(local, axes)
+
+
+def _filter(program: VertexProgram, state, it, agg, vm=None):
+    """:meth:`VertexProgram.filter`; ``vm`` maps it over a lane axis."""
+    fn = program.filter if vm is None else vm(program.filter, (0, None, 0))
+    return fn(state, it, agg)
+
+
 def _resolve_fold(program: VertexProgram, backend=None, tile=None, q=None):
     """Shard-local segmented fold through the backend registry.
 
@@ -233,6 +255,7 @@ def build_dc_step(program: VertexProgram, meta: dict,
         # per-device slices (leading 1)
         A = _squeeze0(arrays)
         lead = active.shape[:-1]                              # () or (B,)
+        agg = _aggregate(program, state, meta, axes, batched)
         msgs = vm(program.scatter_fn, 0)(state).astype(wdt)
         ident = jnp.asarray(mono.identity, wdt)
 
@@ -324,7 +347,7 @@ def build_dc_step(program: VertexProgram, meta: dict,
         state = _tree_where(touched, st3, state)
         new_active = keep | (activated & touched)
         if program.filter_fn is not None:
-            st4, fkeep = vm(program.filter_fn, (0, None))(state, it)
+            st4, fkeep = _filter(program, state, it, agg, vm)
             state = _tree_where(new_active, st4, state)
             new_active = new_active & fkeep
         return state, new_active
@@ -355,6 +378,7 @@ def build_sc_step(program: VertexProgram, meta: dict,
 
     def step(state, active, arrays, it):
         A = _squeeze0(arrays)
+        agg = _aggregate(program, state, meta, axes)
         msgs = program.scatter_fn(state).astype(mono.dtype)
         ident = mono.identity
         ne_s = A["oe_src_local"].shape[0]
@@ -432,7 +456,7 @@ def build_sc_step(program: VertexProgram, meta: dict,
         state = _tree_where(touched, st3, state)
         new_active = keep | (activated & touched)
         if program.filter_fn is not None:
-            st4, fkeep = program.filter_fn(state, it)
+            st4, fkeep = _filter(program, state, it, agg)
             state = _tree_where(new_active, st4, state)
             new_active = new_active & fkeep
         return state, new_active
@@ -461,6 +485,7 @@ def build_hybrid_step(program: VertexProgram, meta: dict,
     def step(state, active, arrays, it, dc_mask):
         A = _squeeze0(arrays)
         dcm = dc_mask[0] if dc_mask.ndim == 2 else dc_mask     # [kpd]
+        agg = _aggregate(program, state, meta, axes)
         msgs = program.scatter_fn(state).astype(mono.dtype)
         ident = mono.identity
 
@@ -531,7 +556,7 @@ def build_hybrid_step(program: VertexProgram, meta: dict,
         state = _tree_where(touched, st3, state)
         new_active = keep | (activated & touched)
         if program.filter_fn is not None:
-            st4, fkeep = program.filter_fn(state, it)
+            st4, fkeep = _filter(program, state, it, agg)
             state = _tree_where(new_active, st4, state)
             new_active = new_active & fkeep
         return state, new_active
@@ -570,7 +595,7 @@ class DistEngine:
             program, backend, tile=getattr(sharded, "fold_tile", None),
             q=getattr(sharded, "fold_q", None))
         self.fold, self.fused = fold, fused
-        meta = dict(nv=sharded.nv, S=sharded.S, D=sharded.D,
+        meta = dict(n=sharded.n, nv=sharded.nv, S=sharded.S, D=sharded.D,
                     cap_in=sharded.cap_in, cap_pair=sharded.cap_pair,
                     kpd=sharded.kpd, weighted=sharded.weighted)
         self.meta = meta

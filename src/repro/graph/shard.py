@@ -234,6 +234,6 @@ def sharded_spec(n: int, m: int, D: int, k_per_dev: int = 4,
         arrs["in_w"] = f32((D, ne_d), np.float32)
         arrs["oe_w"] = f32((D, ne_s), np.float32)
     cap_pair = _pad_to(int(m / (D * D) * edge_slack) + 8, 8)
-    meta = dict(D=D, kpd=k_per_dev, q=q, nv=nv, S=S, cap_in=ne_s,
+    meta = dict(n=n, D=D, kpd=k_per_dev, q=q, nv=nv, S=S, cap_in=ne_s,
                 cap_pair=cap_pair, weighted=weighted)
     return arrs, meta

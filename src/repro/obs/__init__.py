@@ -42,10 +42,10 @@ What gets recorded
   the active edges of each stream and the SC budget class, modeled or
   analytic wire bytes, step wall time), step-wall histograms keyed by
   mode, and lane-compaction events on the batched paths.
-* **Engine loop spans** — ``Engine.run`` and the lockstep
-  ``run_batched`` of a hybrid or sc engine open host spans
-  (:func:`annotation`, a ``jax.profiler.TraceAnnotation``), so a
-  capture shows what the host did while the device idled.
+* **Engine loop spans** — ``Engine.run``, the lockstep
+  ``run_batched`` of a hybrid or sc engine and ``Engine.run_fused`` open
+  host spans (:func:`annotation`, a ``jax.profiler.TraceAnnotation``),
+  so a capture shows what the host did while the device idled.
 * **Kernels** — every registry-constructed scatter/gather/fold/spmv
   call runs under a ``jax.named_scope`` tagged with the kernel and
   backend name, so a ``jax.profiler.trace()`` capture (see
@@ -90,6 +90,11 @@ Inside ``engine.superstep`` these five are siblings in that order and do
 not overlap; a lockstep pass repeats ``engine.split`` and
 ``engine.dispatch`` once for each live lane.  ``IterStats.wall_s`` runs
 from the end of ``engine.part_stats`` to the end of the sync.
+
+``engine.fused`` (``iters``, ``dc_e``)
+    one ``run_fused`` call's device loop: its ``engine.dispatch`` and
+    ``engine.sync``; ``dc_e`` is the DC stream's active edges per
+    iteration.  The ``fused_run`` event's ``wall_s`` is its wall time.
 
 Quick use::
 

@@ -86,10 +86,11 @@ EVENT_SCHEMA = {
                          "lanes_active": "int", "width": "int",
                          "batch": "int"},
         },
-        # a fully-jitted fixed-iteration loop (Engine.run_fused)
+        # a fully-jitted fixed-iteration loop (Engine.run_fused); dc_e is
+        # the DC stream's active edges per iteration
         "fused_run": {
             "required": {"engine": "str", "program": "str", "iters": "int",
-                         "wall_s": "float"},
+                         "dc_e": "int", "wall_s": "float"},
         },
         # one fused serve-tier batch answered by run_batched
         "serve_batch": {

@@ -59,12 +59,14 @@ def test_sssp(g_weighted, mode, pallas):
 
 
 def _pr_ref(g, iters, d=0.85):
+    """LDBC Graphalytics PageRank: the rank of vertices without
+    out-edges is spread over every vertex."""
     x = np.full(g.n, 1.0 / g.n)
     P = to_scipy(g)
     outdeg = g.out_degrees()
     for _ in range(iters):
         c = np.where(outdeg > 0, x / np.maximum(outdeg, 1), 0.0)
-        x = (1 - d) / g.n + d * (P.T @ c)
+        x = (1 - d) / g.n + d * (P.T @ c + x[outdeg == 0].sum() / g.n)
     return x
 
 

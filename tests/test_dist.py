@@ -86,7 +86,8 @@ state, _, _ = eng.run({"pr": pr0, "deg": deg}, frontier, max_iters=5,
 pr = np.asarray(state["pr"])[:g.n]
 x = np.full(g.n, 1.0/g.n); outdeg = g.out_degrees(); P = to_scipy(g)
 for _ in range(5):
-    x = 0.15/g.n + 0.85*(P.T@np.where(outdeg>0, x/np.maximum(outdeg,1), 0.0))
+    x = 0.15/g.n + 0.85*(P.T@np.where(outdeg>0, x/np.maximum(outdeg,1), 0.0)
+                         + x[outdeg == 0].sum()/g.n)
 assert np.abs(pr-x).max() < 1e-5, "dist pagerank mismatch"
 
 gw = rmat(9, 8, seed=2, weighted=True)
@@ -103,6 +104,64 @@ d2 = csg.shortest_path(to_scipy(gw), method="D", indices=s2)
 fin = ~np.isinf(d2)
 assert np.allclose(ours[fin], d2[fin], atol=1e-5)
 assert np.array_equal(np.isinf(ours), ~fin)
+print("OK")
+""")
+    assert "OK" in out
+
+
+GRAPHALYTICS = """
+from repro.apps.pagerank import pagerank_program
+from repro.graph.csr import from_edges, symmetrize
+# a symmetrized Kronecker graph over all 2^10 ids: self loops kept once,
+# unreached ids isolated (dangling)
+g0 = rmat(10, 8, seed=7, dedup=False)
+g = symmetrize(from_edges(np.repeat(np.arange(g0.n), g0.out_degrees()),
+                          g0.indices, g0.n))
+deg = g.out_degrees()
+assert (deg == 0).any() and (np.repeat(np.arange(g.n), deg) == g.indices).any()
+L = build_layout(g, k=16, edge_tile=64, msg_tile=32)
+SL = shard_layout(L, D)
+N = D * SL.nv
+src = np.repeat(np.arange(g.n), deg)
+def ref(x, iters):
+    for _ in range(iters):
+        share = np.divide(x, deg, out=np.zeros(g.n), where=deg > 0)
+        acc = np.bincount(g.indices, weights=share[src], minlength=g.n)
+        x = 0.15 / g.n + 0.85 * (acc + x[deg == 0].sum() / g.n)
+    return x
+def check(got, want):
+    got = np.asarray(got, np.float64)[:g.n]
+    assert np.all(np.abs(got - want) <= np.maximum(1e-4 * want, 1e-4 / g.n))
+    assert abs(got.sum() - 1.0) <= 1e-4
+x0 = np.full(g.n, 1.0 / g.n)
+pr0 = np.zeros(N, np.float32); pr0[:g.n] = x0
+degf = np.zeros(N, np.float32); degf[:L.n_pad] = L.deg
+frontier = np.zeros(N, bool); frontier[:g.n] = True
+"""
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("mode", ["dc", "sc", "hybrid_pp", "batched"])
+def test_dist_pagerank_graphalytics(mode):
+    """LDBC Graphalytics PageRank (dangling rank spread over every
+    vertex) through every DistEngine step: the aggregate is a psum of
+    the shards' sums over their real vertices."""
+    out = _run(COMMON + GRAPHALYTICS + f"""
+mode = {mode!r}
+eng = DistEngine(SL, pagerank_program(g.n), mesh,
+                 mode="dc" if mode == "batched" else mode)
+if mode == "batched":
+    warm = np.random.default_rng(0).random(g.n); warm /= warm.sum()
+    pr = np.stack([pr0, pr0]); pr[1, :g.n] = warm
+    st, _, _ = eng.run_batched({{"pr": pr, "deg": np.stack([degf, degf])}},
+                               np.stack([frontier, frontier]), max_iters=6,
+                               until_empty=False)
+    check(np.asarray(st["pr"])[0], ref(x0, 6))
+    check(np.asarray(st["pr"])[1], ref(warm, 6))
+else:
+    st, _, _ = eng.run({{"pr": pr0, "deg": degf}}, frontier, max_iters=6,
+                       until_empty=False)
+    check(st["pr"], ref(x0, 6))
 print("OK")
 """)
     assert "OK" in out

@@ -191,6 +191,34 @@ def test_dc_phase_gathers_from_on_chip_memory(one_chip):
                      r"\{[^}]*S\(1\)\}", entry), table
 
 
+def test_pagerank_fused_loop_compiles(one_chip):
+    """PageRank's device loop (``Engine.run_fused``, 10 iterations): the
+    fused ``add``/f32 DC step over every edge and the dangling rank's
+    sum over the real vertices, in one program that fits one chip."""
+    from repro.apps.pagerank import pagerank_program
+    k = 32
+    eng = Engine.__new__(Engine)        # the program's shapes, no layout
+    eng.program = pagerank_program(N_PAD)
+    eng.layout = types.SimpleNamespace(n=N_PAD)
+    eng.n_pad, eng.k, eng.q, eng._step_cache = N_PAD, k, N_PAD // k, {}
+    eng._fused = kops.FusedDCKernel(
+        types.SimpleNamespace(n_pad=N_PAD, fold_tile=FOLD_TILE,
+                              fold_q=FOLD_Q), "add", jnp.float32,
+        interpret=False)
+    eng._fused.apply_weight = None
+    S = _spec(one_chip)
+    vec = lambda dtype: S((N_PAD,), dtype)            # noqa: E731
+    args = {"engine": {"deg": vec(jnp.int32)},
+            "fused": {"edge_src": S((N_EDGES,), jnp.int32),
+                      "edge_valid": S((N_EDGES,), jnp.int32),
+                      "edge_dst": S((N_EDGES,), jnp.int32), "edge_w": None}}
+    compiled = eng._fused_loop(10).lower(
+        {"pr": vec(jnp.float32), "deg": vec(jnp.float32)},
+        vec(jnp.bool_), args).compile()
+    _fits(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_batched_dc_fused_ref_fits_one_chip(one_chip):
     """Serving's batched DC step: the table's ``fused_dc`` kernel vmapped
     over 8 BFS lanes, which run one after another and must fit one
