@@ -105,18 +105,25 @@ def fused_scatter_fold(table, table_valid, idx, edge_valid, dst,
       as well.
     """
     idx = jnp.clip(idx.astype(jnp.int32), 0, table.shape[0] - 1)
+    # build the table only once the clamped index stream exists: XLA
+    # keeps the masked table in on-chip memory (S(1)) only while no
+    # edge-sized op runs between its producer and the gather.  The
+    # gathers take ``idx`` as clamped (mode "clip", no negative-index
+    # wrap), so XLA's own clamp of their indices fuses with this one
+    _, table, table_valid = jax.lax.optimization_barrier(
+        (idx, table, table_valid))
     table_valid = table_valid.astype(bool)
     ident = jnp.asarray(_identity_val(monoid, table.dtype))
     one_gather = ~jnp.any(table_valid & (_bits(table) == _bits(ident)))
 
     # an invalid slot's value is the identity, which the fold ignores
-    vals = jnp.where(table_valid, table, ident)[idx]
+    vals = jnp.take(jnp.where(table_valid, table, ident), idx, mode="clip")
     # the value gather stays outside the cond: XLA hoists a gather that
     # ends both branches out of a cond, and the one-gather branch would
     # then gather the values a second time for their validity
     valid = jax.lax.cond(one_gather,
                          lambda: _bits(vals) != _bits(ident),
-                         lambda: table_valid[idx])
+                         lambda: jnp.take(table_valid, idx, mode="clip"))
     valid = valid & (edge_valid > 0)
     if apply_weight is not None:
         vals = apply_weight(vals, w).astype(table.dtype)

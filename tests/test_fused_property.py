@@ -507,3 +507,93 @@ def test_engine_identity_message_takes_two_gathers(one_gather_layout,
     assert paths["pallas-interpret"] == [False]
     assert paths["ref"] == [False]
     assert np.array_equal(out["pallas-interpret"], out["ref"])
+
+
+# ----------------------------------------------------------------------
+# the table built after the clamped index stream: the same step
+# ----------------------------------------------------------------------
+
+
+def _unordered_fused(table, table_valid, idx, edge_valid, dst, ns, monoid,
+                     fold_tile, fold_q):
+    """``fused_scatter_fold`` of a presorted stream with its masked table
+    free to be built before the index stream is clamped, and the gathers'
+    indices wrapped for negative values."""
+    import jax
+    from repro.kernels.fused_step import _bits
+    from repro.kernels.segment_combine import _identity_val
+    idx = jnp.clip(idx.astype(jnp.int32), 0, table.shape[0] - 1)
+    table_valid = table_valid.astype(bool)
+    ident = jnp.asarray(_identity_val(monoid, table.dtype))
+    one_gather = ~jnp.any(table_valid & (_bits(table) == _bits(ident)))
+    vals = jnp.where(table_valid, table, ident)[idx]
+    valid = jax.lax.cond(one_gather, lambda: _bits(vals) != _bits(ident),
+                         lambda: table_valid[idx])
+    acc, touched = two_level_segment_fold(
+        vals, valid & (edge_valid > 0), dst, ns, monoid=monoid,
+        fold_tile=fold_tile, fold_q=fold_q, interpret=True, presorted=True)
+    return acc, touched, one_gather
+
+
+@pytest.mark.parametrize("app,fallback", [("pagerank", False),
+                                          ("pagerank", True),
+                                          ("bfs", False), ("bfs", True)])
+def test_dc_phase_matches_unordered_table_construction(
+        one_gather_layout, monkeypatch, app, fallback):
+    """Engine level, on a graph with vertices that have no out-edges:
+    ``Engine._dc_phase`` (what ``run_fused``'s loop steps through)
+    returns the ``acc``, ``touched`` and ``one_gather`` that the
+    construction without the ordering gives from the same table, bit for
+    bit.  PageRank keeps the one-gather path: its vertices without
+    out-edges, which send 0.0, are invalid slots.  With a valid slot on
+    the identity's bits (rank 0.0, vertex id 0xFFFFFFFF) both take the
+    fallback."""
+    import jax
+    from repro.apps.bfs import bfs_program
+    from repro.apps.pagerank import pagerank_program
+    from repro.core.engine import Engine
+    monkeypatch.setenv(ENV_FUSED, "1")
+    L = one_gather_layout
+    rng = np.random.default_rng(1)
+    sources = np.nonzero(L.deg[:L.n] > 0)[0][::5]
+    if app == "pagerank":
+        prog = pagerank_program(L.n)
+        pr = rng.random(L.n_pad).astype(np.float32)
+        if fallback:
+            pr[sources] = 0.0
+        state = {"pr": jnp.asarray(pr),
+                 "deg": jnp.asarray(L.deg.astype(np.float32))}
+        active = np.arange(L.n_pad) < L.n
+        dc_mask = np.ones(L.k, bool)
+    else:
+        prog = bfs_program()
+        vid = np.arange(L.n_pad, dtype=np.uint32)
+        if fallback:
+            vid[sources] = 0xFFFFFFFF
+        state = {"parent": jnp.full((L.n_pad,), -1, jnp.int32),
+                 "level": jnp.full((L.n_pad,), -1, jnp.int32),
+                 "vid": jnp.asarray(vid)}
+        active = rng.random(L.n_pad) < 0.5
+        active[sources] = True
+        dc_mask = np.arange(L.k) != 1
+    eng = Engine(L, prog, mode="dc", backend="pallas-interpret")
+    assert eng._fused is not None
+    active, dc_mask = jnp.asarray(active), jnp.asarray(dc_mask)
+    got = jax.jit(eng._dc_phase)(state, active, dc_mask, 0,
+                                 eng._args())[2:]
+
+    mono = prog.monoid
+    msgs_p = jnp.concatenate([prog.scatter_fn(state).astype(mono.dtype),
+                              mono.identity_array((1,))])
+    table_valid = jnp.concatenate(
+        [active & jnp.repeat(dc_mask, L.q) & jnp.asarray(L.deg > 0),
+         jnp.zeros((1,), jnp.bool_)])
+    A = eng._fused.arrays
+    want = _unordered_fused(msgs_p, table_valid, A["edge_src"],
+                            A["edge_valid"], A["edge_dst"], L.n_pad + 1,
+                            mono.name, L.fold_tile, L.fold_q)
+    assert bool(got[2]) == (not fallback)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert np.array_equal(g.view(np.uint8), w.view(np.uint8))

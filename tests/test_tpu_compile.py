@@ -158,65 +158,97 @@ def test_dc_fused_ref_compiles(one_chip, monoid):
     assert _gather_sites(compiled.as_text(), N_EDGES) == ["ENTRY", "branch"]
 
 
+def _assert_gathers_on_chip(hlo: str, dtype: str):
+    """Every edge-sized ``dtype`` gather of the compiled module, in any
+    computation (a loop's body too), reads its table from on-chip memory
+    (``S(1)``)."""
+    tables = re.findall(rf"= {dtype}\[{N_EDGES}\]\S* fusion\(%([\w.\-]+), "
+                        r"[^\n]*kind=kCustom", hlo)
+    layouts = [re.search(rf"%{re.escape(t)} = {dtype}\[{N_PAD + 1}\](\S*)",
+                         hlo).group(1) for t in tables]
+    assert layouts and all("S(1)" in t for t in layouts), layouts
+
+
+def _engine(program, monoid, dtype, k=32):
+    """An engine with ``program``'s shapes at scale 22 and no layout."""
+    eng = Engine.__new__(Engine)
+    eng.program, eng.layout = program, types.SimpleNamespace(n=N_PAD)
+    eng.n_pad, eng.k, eng.q, eng._step_cache = N_PAD, k, N_PAD // k, {}
+    eng._fused = kops.FusedDCKernel(
+        types.SimpleNamespace(n_pad=N_PAD, fold_tile=FOLD_TILE,
+                              fold_q=FOLD_Q), monoid, dtype,
+        interpret=False)
+    eng._fused.apply_weight = None
+    return eng
+
+
+def _dc_args(S):
+    vec = S((N_PAD,), jnp.int32)
+    edges = S((N_EDGES,), jnp.int32)
+    return {"engine": {"deg": vec},
+            "fused": {"edge_src": edges, "edge_valid": edges,
+                      "edge_dst": edges, "edge_w": None}}
+
+
 def test_dc_phase_gathers_from_on_chip_memory(one_chip):
     """BFS's DC program (``Engine._dc_phase``, fused): the one gather
     reads the masked message table from on-chip memory (``S(1)``).  XLA
-    leaves it in HBM when the program also returns the unmasked
-    messages, or masks by a per-vertex partition id (chip_smoke.py's
-    partition count, k = 32)."""
+    keeps it there only while no edge-sized op runs between the table's
+    producer and the gather, which is why ``fused_scatter_fold`` builds
+    the table after the clamped index stream.  XLA also leaves it in HBM
+    when the program returns the unmasked messages, or masks by a
+    per-vertex partition id (chip_smoke.py's partition count, k = 32)."""
     k = 32
-    eng = Engine.__new__(Engine)        # the program's shapes, no layout
-    eng.program, eng.n_pad, eng.q = bfs_program(), N_PAD, N_PAD // k
-    eng._fused = kops.FusedDCKernel(
-        types.SimpleNamespace(n_pad=N_PAD, fold_tile=FOLD_TILE,
-                              fold_q=FOLD_Q), "min", jnp.uint32,
-        interpret=False)
-    eng._fused.apply_weight = None
+    eng = _engine(bfs_program(), "min", jnp.uint32, k)
     S = _spec(one_chip)
     vec = lambda dtype: S((N_PAD,), dtype)            # noqa: E731
     state = {"parent": vec(jnp.int32), "level": vec(jnp.int32),
              "vid": vec(jnp.uint32)}
-    args = {"engine": {"deg": vec(jnp.int32)},
-            "fused": {"edge_src": S((N_EDGES,), jnp.int32),
-                      "edge_valid": S((N_EDGES,), jnp.int32),
-                      "edge_dst": S((N_EDGES,), jnp.int32), "edge_w": None}}
     compiled = jax.jit(eng._dc_phase).lower(
         state, vec(jnp.bool_), S((k,), jnp.bool_), S((), jnp.int32),
-        args).compile()
+        _dc_args(S)).compile()
     _fits(compiled)
-    entry = compiled.as_text().split("\nENTRY ")[1]
-    table = re.search(rf"= u32\[{N_EDGES}\]\S* fusion\(%([\w.\-]+)",
-                      entry).group(1)
-    assert re.search(rf"%{re.escape(table)} = u32\[{N_PAD + 1}\]"
-                     r"\{[^}]*S\(1\)\}", entry), table
+    _assert_gathers_on_chip(compiled.as_text(), "u32")
 
 
 def test_pagerank_fused_loop_compiles(one_chip):
     """PageRank's device loop (``Engine.run_fused``, 10 iterations): the
     fused ``add``/f32 DC step over every edge and the dangling rank's
-    sum over the real vertices, in one program that fits one chip."""
+    sum over the real vertices, in one program that fits one chip, whose
+    gather reads the masked table from on-chip memory (``S(1)``).  XLA
+    moves the table to HBM where it schedules the edge-sized clamp of the
+    gather's indices between the table's producer and the gather, as it
+    does, unless ``fused_scatter_fold`` orders them, for messages the
+    step computes (PageRank's rank over degree) rather than reads from
+    the state (BFS's vertex ids)."""
     from repro.apps.pagerank import pagerank_program
-    k = 32
-    eng = Engine.__new__(Engine)        # the program's shapes, no layout
-    eng.program = pagerank_program(N_PAD)
-    eng.layout = types.SimpleNamespace(n=N_PAD)
-    eng.n_pad, eng.k, eng.q, eng._step_cache = N_PAD, k, N_PAD // k, {}
-    eng._fused = kops.FusedDCKernel(
-        types.SimpleNamespace(n_pad=N_PAD, fold_tile=FOLD_TILE,
-                              fold_q=FOLD_Q), "add", jnp.float32,
-        interpret=False)
-    eng._fused.apply_weight = None
+    eng = _engine(pagerank_program(N_PAD), "add", jnp.float32)
     S = _spec(one_chip)
     vec = lambda dtype: S((N_PAD,), dtype)            # noqa: E731
-    args = {"engine": {"deg": vec(jnp.int32)},
-            "fused": {"edge_src": S((N_EDGES,), jnp.int32),
-                      "edge_valid": S((N_EDGES,), jnp.int32),
-                      "edge_dst": S((N_EDGES,), jnp.int32), "edge_w": None}}
     compiled = eng._fused_loop(10).lower(
         {"pr": vec(jnp.float32), "deg": vec(jnp.float32)},
-        vec(jnp.bool_), args).compile()
+        vec(jnp.bool_), _dc_args(S)).compile()
     _fits(compiled)
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    _assert_gathers_on_chip(hlo, "f32")
+
+
+def test_batched_dc_step_gathers_from_on_chip_memory(one_chip):
+    """Serving's batched DC step (``Engine._step_fn`` under ``vmap``, 8
+    BFS lanes, one after another): each lane's gather reads its masked
+    table from on-chip memory (``S(1)``)."""
+    k = 32
+    eng = _engine(bfs_program(), "min", jnp.uint32, k)
+    S = _spec(one_chip)
+    lanes = lambda dtype: S((LANES, N_PAD), dtype)    # noqa: E731
+    state = {"parent": lanes(jnp.int32), "level": lanes(jnp.int32),
+             "vid": lanes(jnp.uint32)}
+    step = jax.jit(jax.vmap(eng._step_fn(), in_axes=(0, 0, 0, None, None)))
+    compiled = step.lower(state, lanes(jnp.bool_), S((LANES, k), jnp.bool_),
+                          S((), jnp.int32), _dc_args(S)).compile()
+    _fits(compiled)
+    _assert_gathers_on_chip(compiled.as_text(), "u32")
 
 
 def test_batched_dc_fused_ref_fits_one_chip(one_chip):
