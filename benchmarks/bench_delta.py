@@ -151,7 +151,7 @@ def run(scales, fracs, reps: int, k: int, out_path: Path):
         # undirected view); smallest dirty fraction = the serving case
         gs = symmetrize(g)
         lays = _delta_layout(gs, k)
-        backend = registry.default_backend_name(kernel="gather")
+        backend = registry.default_backend_name(kernel="fused_dc")
         res_s, cold_s = bench_cc_resume(lays, min(fracs), reps, rng)
         for variant, wall in (("resume", res_s), ("cold", cold_s)):
             results.append({

@@ -4,20 +4,17 @@
       [--scales 8,10,12] [--backends ref,pallas-interpret]
       [--out BENCH_kernels.json]
 
-Times one compiled call of each of ``gather`` (segment_combine), ``scatter``
-(dc_gather), ``spmv`` (spmv_block), ``fold`` (fold_block — the blocked
-segmented fold behind the distributed gather), ``fold2`` (fold_two_level
-— the same fold on an over-cap segment count, where the two-level bucketed
-kernel runs) and ``fused`` (fused_step — the fused DC step, a table
-gather and the two-level fold in place of scatter→gather→fold) for
-every backend the registry can
-lower on this platform,
-across rmat graph scales, and writes the results to ``BENCH_kernels.json``
-at the repo root — the perf-trajectory artifact every hot-path PR
-regenerates.  ``--smoke`` (used by CI) runs two small
-scales at best-of-2 so the emission path can never silently rot; CI
-compares the smoke rows against the committed baseline with
-``tools/check_bench_regression.py``.
+Times one compiled call of each of ``fold`` (fold_block — the blocked
+segmented fold behind the SC stream and the distributed gather),
+``fold2`` (fold_two_level — the same fold on an over-cap segment count,
+where the two-level bucketed kernel runs) and ``fused`` (fused_step — the
+DC step, a table gather and the two-level fold) for every backend the
+registry can lower on this platform, across rmat graph scales, and
+writes the results to ``BENCH_kernels.json`` at the repo root — the
+perf-trajectory artifact every hot-path PR regenerates.  ``--smoke``
+(used by CI) runs two small scales at best-of-2 so the emission path
+can never silently rot; CI compares the smoke rows against the
+committed baseline with ``tools/check_bench_regression.py``.
 """
 from __future__ import annotations
 
@@ -35,7 +32,7 @@ from repro.graph import build_layout, rmat
 from .common import write_telemetry
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
-KERNELS = ("gather", "scatter", "spmv", "fold", "fold2", "fused")
+KERNELS = ("fold", "fold2", "fused")
 
 
 def bench_backend(layout, backend_name: str, platform: str, reps: int):
@@ -47,8 +44,7 @@ def bench_backend(layout, backend_name: str, platform: str, reps: int):
         # fold2 is the registry 'fold' kernel timed in the over-cap
         # (two-level) regime, not a separate registry entry; 'fused'
         # is registry kernel 'fused_dc'
-        reg_kernel = ("fused_dc" if kernel == "fused"
-                      else "fold" if kernel.startswith("fold") else kernel)
+        reg_kernel = "fused_dc" if kernel == "fused" else "fold"
         resolved = registry.resolve(reg_kernel, monoid,
                                     platform=platform, choice=backend_name)
         if resolved.name != backend_name:
@@ -56,8 +52,6 @@ def bench_backend(layout, backend_name: str, platform: str, reps: int):
         t = tuning.time_layout(layout, backend_name, platform,
                                kernels=(kernel,), reps=reps,
                                monoid=monoid)
-        if kernel not in t:
-            continue
         rows.append({"kernel": kernel, "monoid": monoid,
                      "backend": backend_name, "wall_s": t[kernel]})
     return rows
@@ -127,7 +121,7 @@ def main() -> None:
     else:
         platform = jax.default_backend()
         backends = [n for n in registry.available_backends()
-                    if registry.BACKENDS[n].supports(platform, "gather",
+                    if registry.BACKENDS[n].supports(platform, "fused_dc",
                                                      "add", "float32")]
     run(scales, backends, reps, args.k, Path(args.out))
 

@@ -152,7 +152,7 @@ def run(scales, backends, batches, reps: int, k: int, out_path: Path):
         order = np.argsort(g.out_degrees())[::-1]
         pool = order[:max(64, max(batches))]
         for backend_name in backends:
-            if registry.resolve("gather", "min", platform=platform,
+            if registry.resolve("fused_dc", "min", platform=platform,
                                 choice=backend_name).name != backend_name:
                 continue               # would silently time the fallback
             for app in APPS:
@@ -191,7 +191,7 @@ def run(scales, backends, batches, reps: int, k: int, out_path: Path):
                         "kernel": f"serve_{app}_{variant}_b{B}",
                         "monoid": "min",
                         "backend": registry.default_backend_name(
-                            kernel="gather"),
+                            kernel="fused_dc"),
                         "scale": scale, "n": int(gs.n), "m": int(gs.m),
                         "batch": B, "wall_s": wall,
                         "qps": Q / max(wall, 1e-9),
@@ -227,7 +227,7 @@ def main() -> None:
                     help="comma-separated batch sizes (default 1,2,4,8,16)")
     ap.add_argument("--backends", default=None,
                     help="comma-separated backend names (default: platform "
-                         "default for the gather kernel)")
+                         "default for the DC step)")
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--k", type=int, default=16)
     ap.add_argument("--out", default=str(REPO_ROOT / "BENCH_serve.json"))
@@ -244,7 +244,7 @@ def main() -> None:
     if args.backends:
         backends = args.backends.split(",")
     else:
-        backends = [registry.default_backend_name(kernel="gather")]
+        backends = [registry.default_backend_name(kernel="fused_dc")]
     run(scales, backends, batches, reps, args.k, Path(args.out))
 
 

@@ -231,12 +231,10 @@ def check_lowerings(names: dict, kernels: dict, platform: str, tag: str):
 
 def engine_kernels(eng, dc_only: bool = False) -> dict:
     """The kernels an :class:`~repro.core.engine.Engine` runs: the DC
-    stream's (fused or composed) and, unless ``dc_only``, the SC fold."""
-    ks = eng.kernels
-    ran = ({"fused_dc": eng._fused} if eng._fused is not None
-           else {"scatter": ks.scatter, "gather": ks.gather})
+    stream's and, unless ``dc_only``, the SC fold."""
+    ran = {"fused_dc": eng._fused}
     if not dc_only:
-        ran["fold"] = ks.fold
+        ran["fold"] = eng.kernels.fold
     return ran
 
 
@@ -337,11 +335,8 @@ def phase_dist(g, L, roots, want_levels, keys, want_pr, devices,
     require(N == L.n_pad, f"dist: {D} x {SL.nv} vertices != {L.n_pad}")
 
     eng = DistEngine(SL, bfs_program(), mesh, mode="hybrid")
-    names = {"fold": eng.backend_name}
-    ran = {"fold": eng.fold}
-    if eng.fused is not None:
-        names["fused_dc"] = eng.fused_backend_name
-        ran["fused_dc"] = eng.fused
+    names = {"fold": eng.backend_name, "fused_dc": eng.fused_backend_name}
+    ran = {"fold": eng.fold, "fused_dc": eng.fused}
     check_lowerings(names, ran, platform, "dist.bfs")
     for pass_name, todo in (("first", roots), ("warm", roots[:1])):
         for i, r in enumerate(todo):

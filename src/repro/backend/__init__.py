@@ -28,10 +28,9 @@ Backends register per ``(platform, kernel, monoid, dtype)`` support;
 ``jax.default_backend()``, honours the ``REPRO_KERNEL_BACKEND`` override,
 and falls back to ``ref`` per call when a lowering is unsupported (with a
 warning on a TPU, where :data:`~repro.backend.registry.TPU_DEFAULTS`
-declares each kernel's lowering: Mosaic for ``fold``, and ``ref``, with
-the reason, for every kernel whose Mosaic form cannot serve a scale-22
-graph, and :data:`~repro.backend.registry.TPU_REF_MONOIDS` sends the
-packed uint64 monoid to ``ref`` for every kernel).  Tile
+declares each kernel's lowering — Mosaic for both ``fold`` and
+``fused_dc`` — and :data:`~repro.backend.registry.TPU_REF_MONOIDS` sends
+the packed uint64 monoid to ``ref`` for every kernel).  Tile
 geometry (``edge_tile``/``msg_tile``/``fold_tile`` — the §3.1
 partition-sizing rule) is swept empirically by
 :mod:`repro.backend.tuning` and cached on disk.
@@ -56,7 +55,7 @@ It is the Gather phase as a *stream* kernel: no layout binding, no
 ``jax.ops.segment_*``, no scatter in the lowering, so it traces inside
 ``shard_map`` bodies and is what ``DistEngine`` folds each device's
 received bin column with (and the single-device engine its compacted SC
-stream).  Unlike every other kernel it defaults to Pallas on all
+stream).  Unlike ``fused_dc`` it defaults to Pallas on all
 platforms (``pallas-native`` on TPU, ``pallas-interpret`` elsewhere);
 ``REPRO_KERNEL_BACKEND=ref`` opts out.
 
@@ -84,46 +83,29 @@ to ``ref`` at any segment count — ``REPRO_KERNEL_BACKEND=ref`` is the
 only way to get the ``jax.ops`` fold.  ``autotune()`` sweeps ``fold_q``
 jointly with ``fold_tile`` via the over-cap ``fold2`` timing row.
 
-The DC step: composed vs fused dataflow
----------------------------------------
+Kernel ``fused_dc`` — the DC step
+---------------------------------
 
-The engines' DC stream has two lowerings.  The *composed* path is the
-paper's literal pipeline: the ``scatter`` kernel writes the dense
-``[NM]`` bin buffer (values only, the pre-written dc_bin), a slot
-gather re-reads it into an ``[NE]`` per-edge value stream, and the
-gather-side fold collapses that into the per-partition accumulators —
-two HBM round-trips per superstep for data that is only ever consumed
-once.  The *fused* path is registry kernel ``fused_dc``
-(:mod:`repro.kernels.fused_step`): XLA gathers each edge's source value
-straight from the message table, the optional edge function runs on
-it, and the two-level Pallas fold folds the stream into the
-``[fold_q]`` sub-accumulators — no bin buffer, no XLA scatter.  The
-layout-bound kernel keeps its static edge stream in destination order,
-so its fold needs no per-call sort; Mosaic lowers it, and it is the
-TPU default (:data:`~repro.backend.registry.TPU_DEFAULTS`).  Its stream
-contract mirrors the fold's::
+The engines' one DC lowering (:mod:`repro.kernels.fused_step`): XLA
+gathers each edge's source value straight from the message table, the
+optional edge function runs on it, and the two-level Pallas fold folds
+the stream into the ``[fold_q]`` sub-accumulators — no ``[NM]`` bin
+buffer, no XLA scatter.  The layout-bound kernel keeps its static edge
+stream in destination order, so its fold needs no per-call sort;
+Mosaic lowers it, and it is the TPU default
+(:data:`~repro.backend.registry.TPU_DEFAULTS`).  Its stream contract
+mirrors the fold's::
 
-    acc, touched = fused(table, table_valid, idx, edge_valid, dst,
-                         num_segments[, w=, apply_weight=])
+    acc, touched, one_gather = fused(table, table_valid, idx, edge_valid,
+                                     dst, num_segments[, w=,
+                                     apply_weight=])
 
-with an edge contributing iff ``table_valid[idx] & edge_valid`` — the
-same elementwise condition the composed path computes via the scatter
-flags, so the two lowerings are bit-exact against each other (enforced
-by ``tests/test_fused_property.py`` through the shared differential
-harness).
-
-Selection rule: the engines take the fused kernel from
-:func:`make_kernels` / ``fused_dc`` resolution when (a) ``REPRO_FUSED``
-is not ``0`` and (b) the *selected* backend itself lowers the
-``(monoid, dtype)`` combination — {add,min,max} × 4-byte f/i/u for the
-Pallas backends, everything for ``ref``.  Unlike the other kernels
-there is deliberately NO per-call ``ref`` fallback: a missing fused
-lowering silently keeps the engine on its composed path (same backend),
-which also remains the lowering for the SC and hybrid-SC streams, for
-``pallas-native`` requests off-TPU, and for monoids outside the Pallas
-set (``pallas-native`` never registers it).  ``autotune()`` observes
-the fused grid's ``edge_tile × fold_q`` cross-product through the
-``fused`` timing row, and the winners ride
+with an edge contributing iff ``table_valid[idx] & edge_valid``.  It
+resolves like ``fold``: the Pallas backends lower {add,min,max} × 4-byte
+f/i/u, ``ref`` every monoid, and any other combination falls back to
+``ref`` per call (the platform default off a TPU is ``ref``).
+``autotune()`` observes the fused grid's ``edge_tile × fold_q``
+cross-product through the ``fused`` timing row, and the winners ride
 the same cached :class:`~repro.backend.tuning.TileGeometry` the
 layouts are built from.
 

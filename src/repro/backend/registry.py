@@ -1,18 +1,16 @@
 """Kernel-backend registry keyed on ``(platform, kernel, monoid, dtype)``.
 
 Every kernel call in the repo is constructed through :func:`make_kernels` /
-:func:`resolve`: the engine asks for a kernel by name (``gather`` /
-``scatter`` / ``spmv`` / ``fold`` / ``fused_dc``) together with its monoid
-and dtype, and
-the registry hands back the implementation that is actually lowerable on
-the current platform — ``ref`` (pure jnp), ``pallas-interpret`` (Pallas
-bodies under the interpreter, any host), or ``pallas-native`` (Mosaic,
-TPU only).  Selection order:
+:func:`resolve`: the engine asks for a kernel by name (``fold`` or
+``fused_dc``) together with its monoid and dtype, and the registry hands
+back the implementation that is actually lowerable on the current
+platform — ``ref`` (pure jnp), ``pallas-interpret`` (Pallas bodies under
+the interpreter, any host), or ``pallas-native`` (Mosaic, TPU only).
+Selection order:
 
   1. an explicit ``backend=`` argument (``Engine(..., backend=...)``),
   2. the ``REPRO_KERNEL_BACKEND`` environment variable,
-  3. the platform default: :data:`TPU_DEFAULTS` on TPU (each kernel's
-     lowering named with the reason wherever it is not Mosaic, and
+  3. the platform default: :data:`TPU_DEFAULTS` on TPU (and
      :data:`TPU_REF_MONOIDS` for monoids no Mosaic kernel folds), ``ref``
      elsewhere.
 
@@ -34,13 +32,10 @@ distributed gather runs the paper's blocked VMEM fold at every segment
 count — never ``jax.ops`` scatter-adds — unless
 ``REPRO_KERNEL_BACKEND=ref`` explicitly opts out.
 
-Kernel ``fused_dc`` (the fused scatter→fold DC step,
-:mod:`repro.kernels.fused_step`) is selection-special the other way:
-:func:`make_kernels` constructs it only when the *selected* backend
-itself lowers the ``(monoid, dtype)`` combination — no per-call ``ref``
-fallback — because the engines' fallback for a missing fused kernel is
-their own composed scatter→fold path, not a different backend.
-``REPRO_FUSED=0`` opts the engines out of selecting it at all.
+Kernel ``fused_dc`` (the DC step, :mod:`repro.kernels.fused_step`) is
+the engines' one DC lowering, resolved like ``fold``: its ``ref``
+implementation folds every monoid, so the per-call fallback always has
+somewhere to go.
 """
 from __future__ import annotations
 
@@ -55,24 +50,14 @@ import jax.numpy as jnp
 from ..kernels import ops as kops
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
-KERNELS = ("gather", "scatter", "spmv", "fold", "fused_dc")
+KERNELS = ("fold", "fused_dc")
 PALLAS_MONOIDS = ("add", "min", "max")
-#: kernels whose Pallas bodies Mosaic compiles (``fused_dc`` gathers in
-#: XLA and folds with the two-level kernel)
-MOSAIC_KERNELS = ("gather", "scatter", "spmv", "fold", "fused_dc")
 
 #: The TPU table: each kernel's default lowering on a TPU, and why, where
-#: it is not the Mosaic kernel.  ``chip_smoke.py`` prints it and fails
-#: when a kernel ran on anything else.  The composed DC kernels build a
-#: ``[tile, q]`` one-hot per edge tile, which at scale 22 with k = 32
-#: (q = 131072) is out of reach; they stay selectable by name.
+#: it is not the Mosaic kernel (``fused_dc`` gathers in XLA and folds
+#: with the Mosaic two-level kernel).  ``chip_smoke.py`` prints it and
+#: fails when a kernel ran on anything else.
 TPU_DEFAULTS = {
-    "gather": ("ref", "its [tile, q] one-hot needs 131.75 MiB of the 128 "
-               "MiB VMEM at q = 131072"),
-    "scatter": ("ref", "its one-hot select gather makes q = 131072 compares "
-                "per message and takes ~170 s to compile there"),
-    "spmv": ("ref", "its one-hot select gather makes q = 131072 compares "
-             "per edge and takes ~140 s to compile there"),
     "fold": ("pallas-native", None),
     "fused_dc": ("pallas-native", None),
 }
@@ -103,12 +88,6 @@ class KernelBackend(Protocol):
     def supports(self, platform: str, kernel: str, monoid: str,
                  dtype) -> bool: ...
 
-    def gather(self, layout, monoid) -> Any: ...
-
-    def scatter(self, layout, monoid) -> Any: ...
-
-    def spmv(self, layout, weighted=None) -> Any: ...
-
     def segment_fold(self, monoid, tile=None, q=None) -> Any: ...
 
     def fused_dc(self, layout, monoid) -> Any: ...
@@ -122,19 +101,7 @@ class RefBackend:
     name = "ref"
 
     def supports(self, platform, kernel, monoid, dtype):
-        if kernel == "spmv":
-            return monoid == "add" and jnp.issubdtype(jnp.dtype(dtype),
-                                                      jnp.floating)
         return kernel in KERNELS
-
-    def gather(self, layout, monoid):
-        return kops.RefGather(layout, _monoid_obj(monoid))
-
-    def scatter(self, layout, monoid):
-        return kops.RefScatter(layout, _monoid_obj(monoid))
-
-    def spmv(self, layout, weighted=None):
-        return kops.RefSpmv(layout, weighted=weighted)
 
     def segment_fold(self, monoid, tile=None, q=None):
         return kops.RefFold(_monoid_obj(monoid))
@@ -154,30 +121,12 @@ class PallasBackend:
         self.interpret = interpret
 
     def supports(self, platform, kernel, monoid, dtype):
-        if not self.interpret and (platform != "tpu"
-                                   or kernel not in MOSAIC_KERNELS):
+        if kernel not in KERNELS or (not self.interpret
+                                     and platform != "tpu"):
             return False                     # Mosaic lowering is TPU-only
         dt = jnp.dtype(dtype)
-        if kernel == "spmv":
-            return monoid == "add" and dt == jnp.float32
-        if kernel not in ("gather", "scatter", "fold", "fused_dc"):
-            return False
         return monoid in PALLAS_MONOIDS and dt.kind in "fiu" \
             and dt.itemsize == 4
-
-    def gather(self, layout, monoid):
-        mono = _monoid_obj(monoid)
-        return kops.GatherKernel(layout, mono.name, mono.dtype,
-                                 interpret=self.interpret)
-
-    def scatter(self, layout, monoid):
-        mono = _monoid_obj(monoid)
-        return kops.ScatterKernel(layout, mono.name, mono.dtype,
-                                  interpret=self.interpret)
-
-    def spmv(self, layout, weighted=None):
-        return kops.SpmvKernel(layout, interpret=self.interpret,
-                               weighted=weighted)
 
     def segment_fold(self, monoid, tile=None, q=None):
         mono = _monoid_obj(monoid)
@@ -297,58 +246,27 @@ def _tag_scope(kernel, kname: str, backend_name: str):
 class KernelSet:
     """Layout-bound kernels for one engine, resolved per call."""
 
-    gather: Any
-    scatter: Any
     fold: Any
-    spmv: Any
+    fused: Any                   # the DC step (registry kernel fused_dc)
     names: dict                  # kernel -> backend name actually used
-    fused: Any = None            # fused DC step, None -> composed path
 
     @property
     def any_pallas(self) -> bool:
         # the fold defaults to Pallas on every platform, so it says nothing
         # about whether the engine *chose* a Pallas backend
-        return any(n.startswith("pallas") for k, n in self.names.items()
-                   if k != "fold")
+        return self.names["fused_dc"].startswith("pallas")
 
 
-def make_kernels(layout, monoid, backend=None, platform=None,
-                 with_spmv: bool = False) -> KernelSet:
-    """Resolve and construct the gather/scatter/fold (and optionally spmv)
-    kernels for a layout; each call may fall back to ``ref`` on its own."""
+def make_kernels(layout, monoid, backend=None, platform=None) -> KernelSet:
+    """Resolve and construct the fold and the DC step for a layout; each
+    may fall back to ``ref`` on its own."""
     mono = _monoid_obj(monoid)
-    gb = resolve("gather", mono, platform=platform, choice=backend)
-    sb = resolve("scatter", mono, platform=platform, choice=backend)
     fb = resolve("fold", mono, platform=platform, choice=backend)
-    names = {"gather": gb.name, "scatter": sb.name, "fold": fb.name}
-    spmv = None
-    if with_spmv:
-        vb = resolve("spmv", "add", dtype=jnp.float32, platform=platform,
-                     choice=backend)
-        spmv = _tag_scope(vb.spmv(layout), "spmv", vb.name)
-        names["spmv"] = vb.name
+    xb = resolve("fused_dc", mono, platform=platform, choice=backend)
     fold = fb.segment_fold(mono,
                            tile=getattr(layout, "fold_tile", None),
                            q=getattr(layout, "fold_q", None))
-    # fused DC step: constructed only when the *selected* backend itself
-    # lowers it — deliberately no per-call ref fallback here, because the
-    # engines' fallback for a missing fused kernel is the composed
-    # scatter→fold path (same backend), not a different backend
-    fused = None
-    platform_r = platform or jax.default_backend()
-    if backend is None:
-        xb = BACKENDS[default_backend_name(platform_r, "fused_dc",
-                                           mono.name)]
-    elif isinstance(backend, str):
-        xb = BACKENDS[backend]
-    else:
-        xb = backend
-    if xb.supports(platform_r, "fused_dc", mono.name, mono.dtype):
-        fused = _tag_scope(xb.fused_dc(layout, mono), "fused_dc", xb.name)
-        names["fused_dc"] = xb.name
-    return KernelSet(gather=_tag_scope(gb.gather(layout, mono),
-                                       "gather", gb.name),
-                     scatter=_tag_scope(sb.scatter(layout, mono),
-                                        "scatter", sb.name),
-                     fold=_tag_scope(fold, "fold", fb.name),
-                     spmv=spmv, names=names, fused=fused)
+    return KernelSet(fold=_tag_scope(fold, "fold", fb.name),
+                     fused=_tag_scope(xb.fused_dc(layout, mono), "fused_dc",
+                                      xb.name),
+                     names={"fold": fb.name, "fused_dc": xb.name})

@@ -149,50 +149,22 @@ def _timed(fn, reps: int) -> float:
 
 
 def time_layout(layout, backend_name: str, platform: str,
-                kernels=("gather", "scatter", "spmv", "fold", "fold2",
-                         "fused"),
-                reps: int = 3,
-                monoid: str = "add", fold_backend=None) -> dict:
+                kernels=("fold", "fold2", "fused"), reps: int = 3,
+                monoid: str = "add") -> dict:
     """Time one compiled call of each kernel on a built layout.
 
-    ``fold_backend`` overrides the backend for the fold *and fused* rows
-    only: the autotuner passes the *per-kernel* platform default there,
-    because the fold's default backend (Pallas everywhere) differs from
-    the other kernels' and ``RefFold``/``RefFusedDC`` ignore the tile
-    knobs — sweeping them through ref would select the winner by timing
-    jitter.  The ``fused`` row times the fused DC step on the layout's
-    real edge stream, so the sweep observes the ``fold_tile × fold_q``
+    ``fold`` and ``fold2`` time registry kernel ``fold`` at the layout's
+    segment count and past the flat fold's cap; ``fused`` times the DC
+    step (registry kernel ``fused_dc``) on the layout's real edge
+    stream, so the sweep observes the ``fold_tile × fold_q``
     cross-product its fold is built from."""
     rng = np.random.default_rng(0)
     out = {}
     dtype = jnp.float32
-    # jit the layout-bound callables so the ref backend is timed as one
-    # compiled program, exactly as the engines run it
-    if "gather" in kernels:
-        b = registry.resolve("gather", monoid, dtype=dtype,
-                             platform=platform, choice=backend_name)
-        gk = jax.jit(b.gather(layout, monoid).__call__)
-        ev = jnp.asarray(
-            rng.integers(0, 64, layout.num_edges).astype(np.float32))
-        valid = jnp.asarray(layout.edge_valid)
-        pa = jnp.ones((layout.k,), jnp.int32)
-        out["gather"] = _timed(lambda: gk(ev, valid, pa), reps)
-    if "scatter" in kernels:
-        b = registry.resolve("scatter", monoid, dtype=dtype,
-                             platform=platform, choice=backend_name)
-        sk = jax.jit(b.scatter(layout, monoid).__call__)
-        x = jnp.asarray(rng.integers(0, 64, layout.n_pad).astype(np.float32))
-        act = jnp.ones((layout.n_pad,), jnp.int32)
-        out["scatter"] = _timed(lambda: sk(x, act), reps)
-    if "spmv" in kernels:
-        b = registry.resolve("spmv", "add", dtype=dtype, platform=platform,
-                             choice=backend_name)
-        vk = jax.jit(b.spmv(layout).__call__)
-        x = jnp.asarray(rng.integers(0, 64, layout.n_pad).astype(np.float32))
-        out["spmv"] = _timed(lambda: vk(x), reps)
+
     def _time_fold(key: str, ns: int, ids_np):
         b = registry.resolve("fold", monoid, dtype=dtype, platform=platform,
-                             choice=fold_backend or backend_name)
+                             choice=backend_name)
         fold = b.segment_fold(monoid, tile=getattr(layout, "fold_tile",
                                                    None),
                               q=getattr(layout, "fold_q", None))
@@ -221,10 +193,10 @@ def time_layout(layout, backend_name: str, platform: str,
     if "fused" in kernels:
         # the fused DC step over the layout's real edge stream: its fold
         # walks the destination-sorted stream in [fold_tile, fold_q]
-        # one-hots, the fold geometry on real data
+        # one-hots, the fold geometry on real data; jitted, so the ref
+        # backend is timed as one compiled program, as the engines run it
         b = registry.resolve("fused_dc", monoid, dtype=dtype,
-                             platform=platform,
-                             choice=fold_backend or backend_name)
+                             platform=platform, choice=backend_name)
         fk = jax.jit(b.fused_dc(layout, monoid).__call__)
         table = jnp.asarray(
             rng.integers(0, 64, layout.n_pad + 1).astype(np.float32))
@@ -236,9 +208,7 @@ def time_layout(layout, backend_name: str, platform: str,
 
 def autotune(g, k: Optional[int] = None, backend=None,
              platform: Optional[str] = None,
-             kernels=("gather", "scatter", "spmv", "fold", "fold2",
-                      "fused"),
-             reps: int = 3,
+             kernels=("fold", "fold2", "fused"), reps: int = 3,
              cache_dir=None, force: bool = False) -> TileGeometry:
     """Sweep candidate tile geometries for graph ``g``; cache the winner.
 
@@ -258,8 +228,10 @@ def autotune(g, k: Optional[int] = None, backend=None,
                           cache_dir)
         if hit is not None:
             return hit
-    # sweep the fold through the backend engines really resolve for it
-    # (Pallas by default) unless the caller pinned one explicitly
+    # time the kernels through the backend engines resolve the fold to
+    # (Pallas by default) unless the caller pinned one explicitly:
+    # RefFold and RefFusedDC ignore the tile knobs, so sweeping them
+    # would pick the winner by timing jitter
     fold_bname = (bname if backend is not None
                   else registry.default_backend_name(platform, "fold"))
     sweeps = []
@@ -268,8 +240,8 @@ def autotune(g, k: Optional[int] = None, backend=None,
                          msg_tile=geom.msg_tile,
                          fold_tile=geom.fold_tile,
                          fold_q=geom.fold_q)
-        times = time_layout(L, bname, platform, kernels=kernels, reps=reps,
-                            fold_backend=fold_bname)
+        times = time_layout(L, fold_bname, platform, kernels=kernels,
+                            reps=reps)
         sweeps.append({"edge_tile": geom.edge_tile,
                        "msg_tile": geom.msg_tile,
                        "fold_tile": geom.fold_tile,

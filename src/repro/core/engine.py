@@ -5,11 +5,12 @@ Each iteration follows paper Alg. 3/4 exactly:
 
   1. *Scatter*: active vertices produce messages.  Per-partition mode choice
      (Eq. 1 cost model):
-       - **DC stream**: all PNG message slots of DC-mode partitions that have
-         at least one active vertex are materialized (values only — the
-         adjacency side ``msg_slot``/``edge_dst`` is static, the paper's
-         pre-written ``dc_bin``).  Slots whose source vertex is inactive carry
-         the monoid identity, which makes them exact no-ops in the fold — the
+       - **DC stream**: registry kernel ``fused_dc`` gathers each edge's
+         source message straight from the vertex table and folds it
+         (the adjacency side is static, the paper's pre-written
+         ``dc_bin``; no bin buffer is materialized).  Sources that are
+         inactive or in SC-mode partitions carry the monoid identity,
+         which makes their edges exact no-ops in the fold — the
          array-semantics equivalent of the paper's "scatter the whole
          partition" correctness contract.
        - **SC stream**: the CSR adjacency of the active vertices of SC-mode
@@ -25,8 +26,8 @@ Each iteration follows paper Alg. 3/4 exactly:
   4. *filterFrontier*: ``filter_fn`` on the union frontier.
 
 The 2-level active list appears as: per-partition active counts drive the mode
-decision and exclude empty partitions entirely (gPartList); tile-level
-predication inside the Pallas kernels skips edge tiles of inactive partitions
+decision and exclude empty partitions entirely (gPartList); inside the DC
+stream the partition mask marks the sources of non-DC partitions invalid
 (binPartList).
 """
 from __future__ import annotations
@@ -173,7 +174,7 @@ class Engine:
     """Single-device PPM engine.
 
     mode: 'hybrid' (paper's GPOP), 'dc' (GPOP_DC), 'sc' (GPOP_SC).
-    backend: kernel backend for the DC scatter/gather — a name from
+    backend: kernel backend for the DC step and the SC fold — a name from
     :mod:`repro.backend.registry` ('ref', 'pallas-interpret',
     'pallas-native'), a KernelBackend instance, or None to auto-select
     from the platform / REPRO_KERNEL_BACKEND.
@@ -200,47 +201,36 @@ class Engine:
         L = layout
         self.k, self.q, self.n_pad = L.k, L.q, L.n_pad
 
-        # kernel construction goes through the backend registry; each of
-        # gather/scatter may fall back to 'ref' on its own when the chosen
+        # kernel construction goes through the backend registry; each
+        # kernel may fall back to 'ref' on its own when the chosen
         # backend has no lowering for this (monoid, dtype, platform)
         kset = kregistry.make_kernels(layout, program.monoid,
                                       backend=backend)
         self.kernels = kset
         self.backend_names = kset.names
         self.use_pallas = kset.any_pallas          # introspection compat
-        self._gather_kernel = kset.gather
-        self._scatter_kernel = kset.scatter
         # SC-stream monoid fold + touched flags through registry kernel
         # 'fold' (the blocked Pallas fold by default — flat below
         # REPRO_FOLD_MAX_SEGMENTS, two-level above, both carrying the
         # layout's tuned fold_tile/fold_q; budgets are static per
         # compiled step, so the stream shape is known at trace time)
         self._fold = kset.fold
-        # fused DC step (registry kernel 'fused_dc'): one call replacing
-        # scatter -> slot gather -> gather fold, selected when the
-        # backend provides it and REPRO_FUSED != 0; otherwise the
-        # composed path below runs
-        from ..kernels.fused_step import fused_enabled
-        self._fused = kset.fused if fused_enabled() else None
-        if self._fused is not None:
-            self._fused.apply_weight = (
-                program.apply_weight
-                if (program.apply_weight is not None
-                    and L.edge_w is not None) else None)
+        # the DC step (registry kernel 'fused_dc'): one call that
+        # gathers each edge's source message and folds it
+        self._fused = kset.fused
+        self._fused.apply_weight = (
+            program.apply_weight
+            if (program.apply_weight is not None
+                and L.edge_w is not None) else None)
 
-        # device-resident static structure: only what the selected DC
-        # lowering and the SC stream read.  Every jitted step takes these
-        # as arguments (see _args) — jit would embed closed-over arrays
-        # in the program as constants, gigabytes at chip scale.
+        # device-resident static structure the SC stream reads (the DC
+        # step keeps its own in self._fused.arrays).  Every jitted step
+        # takes these as arguments (see _args) — jit would embed
+        # closed-over arrays in the program as constants, gigabytes at
+        # chip scale.
         arrays = {
             "csr_indptr": L.csr_indptr, "csr_indices": L.csr_indices,
             "csr_w": L.csr_w, "deg": L.deg.astype(np.int32)}
-        if self._fused is None:
-            arrays.update(
-                png_src=L.png_src,
-                png_part=(L.png_src.astype(np.int64) // L.q)
-                .clip(0, L.k - 1).astype(np.int32),
-                msg_slot=L.msg_slot, edge_w=L.edge_w)
         self.arrays = {k: None if v is None else jnp.asarray(v)
                        for k, v in arrays.items()}
 
@@ -261,12 +251,8 @@ class Engine:
 
     def _args(self):
         """Every device array a jitted step reads, passed as an argument:
-        the engine's own, then each kernel's on the selected DC path."""
-        if self._fused is not None:
-            return {"engine": self.arrays, "fused": self._fused.arrays}
-        return {"engine": self.arrays,
-                "gather": self._gather_kernel.arrays,
-                "scatter": self._scatter_kernel.arrays}
+        the engine's own, then the DC step's."""
+        return {"engine": self.arrays, "fused": self._fused.arrays}
 
     # ------------------------------------------------------------------
     def _step_fn(self):
@@ -326,8 +312,8 @@ class Engine:
         Returns ``(state, keep, acc, touched, one_gather)``, the
         accumulators over ``n_pad + 1`` slots, the last the identity
         sentinel; ``one_gather`` is the fused kernel's report of its path
-        (None without a fused kernel).  ``stream=False`` skips the DC
-        stream, whose result is the identity when ``dc_mask`` is all
+        (None where the stream is skipped).  ``stream=False`` skips the
+        DC stream, whose result is the identity when ``dc_mask`` is all
         false.  The messages are not an output: the SC phase scatters
         its own, and on a TPU XLA keeps the fused kernel's masked table
         in on-chip memory only while the unmasked one is no output."""
@@ -347,37 +333,16 @@ class Engine:
         if not stream:
             return (state, keep, mono.identity_array((n_pad + 1,)),
                     jnp.zeros((n_pad + 1,), jnp.bool_), None)
-        if self._fused is not None:
-            # fused lowering: the kernel gathers each edge's source value
-            # from msgs_p itself and folds it straight into the
-            # accumulators — the [NM] bin buffer and the [NE] edge-value
-            # stream never materialize.  A vertex without out-edges feeds
-            # no edge: marking it invalid keeps its message (PageRank's
-            # 0.0, the add identity) from forcing the two-gather path
-            msgs_p = jnp.concatenate([msgs, mono.identity_array((1,))])
-            table_valid = jnp.concatenate(
-                [active & dc_v & (A["deg"] > 0),
-                 jnp.zeros((1,), jnp.bool_)])
-            acc, touched, one_gather = self._fused(msgs_p, table_valid,
-                                                   arrays=args["fused"])
-            return state, keep, acc, touched, one_gather
-        active_p = jnp.concatenate([active, jnp.zeros((1,), jnp.bool_)])
-        msg_data = self._scatter_kernel(msgs, active & dc_v,
-                                        arrays=args["scatter"])
-        dc_valid = active_p[A["png_src"]] & dc_mask[A["png_part"]]  # [NM]
-        msg_data_p = jnp.concatenate([msg_data, mono.identity_array((1,))])
-        dc_valid_p = jnp.concatenate([dc_valid, jnp.zeros((1,), jnp.bool_)])
-        edge_vals = msg_data_p[A["msg_slot"]]                 # [NE]
-        edge_valid = dc_valid_p[A["msg_slot"]]
-        if prog.apply_weight is not None and A["edge_w"] is not None:
-            edge_vals = prog.apply_weight(edge_vals, A["edge_w"])
-            edge_vals = jnp.where(edge_valid, edge_vals, mono.identity)
-        acc, touched = self._gather_kernel(
-            edge_vals, edge_valid, dc_mask.astype(jnp.int32),
-            arrays=args["gather"])
-        acc = jnp.concatenate([acc, mono.identity_array((1,))])
-        touched = jnp.concatenate([touched, jnp.zeros((1,), jnp.bool_)])
-        return state, keep, acc, touched, None
+        # the kernel gathers each edge's source value from msgs_p and
+        # folds it into the accumulators.  A vertex without out-edges
+        # feeds no edge: marking it invalid keeps its message (PageRank's
+        # 0.0, the add identity) from forcing the two-gather path
+        msgs_p = jnp.concatenate([msgs, mono.identity_array((1,))])
+        table_valid = jnp.concatenate(
+            [active & dc_v & (A["deg"] > 0), jnp.zeros((1,), jnp.bool_)])
+        acc, touched, one_gather = self._fused(msgs_p, table_valid,
+                                               arrays=args["fused"])
+        return state, keep, acc, touched, one_gather
 
     def _vertex_mask(self, dc_mask):
         """``dc_mask`` [k] per vertex: partition p holds vertices
@@ -537,7 +502,8 @@ class Engine:
         by stream, its SC budget (0: no SC stream) and the phase programs
         built for it (``new_programs``: their first call compiles).  The
         fused DC kernel's report of its path, a device scalar (None
-        without one), is kept as ``_dc_one_gather`` for the record."""
+        where the DC stream is skipped), is kept as ``_dc_one_gather``
+        for the record."""
         with obs.annotation("engine.split"):
             has_active = counts > 0
             if self.mode == "dc":
@@ -641,10 +607,10 @@ class Engine:
 
         ``states`` is a pytree whose leaves carry a leading query axis
         ``[B, ...]``; ``frontiers`` is ``[B, n_pad]`` bool.  Every kernel
-        launch (scatter / gather / fold) is amortized across the batch —
-        the serving-tier analogue of the paper's §5 repeated-query
-        argument: the O(E) layout is resident and shared, only the O(V)
-        per-query state is replicated.  The *union* frontier drives
+        launch (the DC step's gather and fold) is amortized across the
+        batch — the serving-tier analogue of the paper's §5
+        repeated-query argument: the O(E) layout is resident and shared,
+        only the O(V) per-query state is replicated.  The *union* frontier drives
         convergence (the loop runs until every lane drained); per-query
         done masks freeze converged lanes inside a step, and between
         steps converged lanes are compacted out of the batch entirely

@@ -5,11 +5,13 @@ The BSP structure of the paper maps 1:1 onto collectives (DESIGN.md §2):
   Scatter (per device, local)   -> message buffer out[D, S] (DC) or
                                    ragged compaction (SC)
   barrier + bin exchange        -> all_to_all / ragged_all_to_all
-  Gather (per device, local)    -> blocked segmented monoid fold over the
-                                   statically resident dc_bin adjacency
-                                   (registry kernel 'fold': the Pallas
-                                   kernel of repro.kernels.fold_block by
-                                   default — no jax.ops segment ops)
+  Gather (per device, local)    -> DC: registry kernel 'fused_dc' gathers
+                                   each edge's value from the received
+                                   table over the statically resident
+                                   dc_bin adjacency and folds it; SC:
+                                   registry kernel 'fold' (the blocked
+                                   Pallas folds by default — no jax.ops
+                                   segment ops)
 
 DC mode sends *values only* (+1 validity byte, see DESIGN.md); SC mode sends
 (value, dst-id) pairs with wire bytes proportional to active edges.  Mode
@@ -116,8 +118,10 @@ def dc_wire_bytes(meta: dict, value_itemsize: int, *,
     return batch * (val + flags)
 
 
-def _fold_lanes(fold, vals, valid, ids, ns):
-    """Per-lane segmented fold, unrolled over the lane axis at trace time.
+def _fold_lanes(fold, vals, valid, *shared, **kw):
+    """Per-lane fold, unrolled over the lane axis at trace time:
+    ``fold(vals[i], valid[i], *shared, **kw)`` for each lane ``i``, its
+    first two outputs (``acc``, ``touched``) stacked.
 
     The registry folds have no vmap batching rule (XLA's default scatter
     batching serializes ~100x on CPU), and flattening lanes into one
@@ -131,7 +135,7 @@ def _fold_lanes(fold, vals, valid, ids, ns):
     per pow2 lane width ever compiles)."""
     accs, touch = [], []
     for i in range(vals.shape[0]):
-        a, t = fold(vals[i], valid[i], ids[i], ns)
+        a, t = fold(vals[i], valid[i], *shared, **kw)[:2]
         accs.append(a)
         touch.append(t)
     return jnp.stack(accs), jnp.stack(touch)
@@ -174,43 +178,19 @@ def _resolve_fold(program: VertexProgram, backend=None, tile=None, q=None):
     return kregistry._tag_scope(fold, "fold", b.name), b.name
 
 
-def _resolve_fused(program: VertexProgram, backend=None, tile=None, q=None):
-    """Shard-local fused gather→fold (registry kernel ``fused_dc``), or
-    ``(None, None)`` when the composed slot-gather + fold path should run.
-
-    Mirrors :func:`_resolve_fold`'s selection (explicit ``backend=``, the
-    ``REPRO_KERNEL_BACKEND`` env, platform default) but with the fused
-    kernel's fallback rule: no per-call ``ref`` substitution — when
-    ``REPRO_FUSED=0`` or the selected backend does not lower the
-    ``(monoid, dtype)`` combination, the DC gather silently stays on the
-    composed path (which also remains the SC/hybrid lowering)."""
-    from ..kernels.fused_step import fused_enabled
-    if not fused_enabled():
-        return None, None
-    mono = program.monoid
-    platform = jax.default_backend()
-    if backend is None:
-        b = kregistry.BACKENDS[
-            kregistry.default_backend_name(platform, "fused_dc",
-                                           mono.name)]
-    elif isinstance(backend, str):
-        if backend not in kregistry.BACKENDS:
-            raise ValueError(
-                f"unknown backend {backend!r}; choose one of "
-                f"{kregistry.available_backends()}")
-        b = kregistry.BACKENDS[backend]
-    else:
-        b = backend
-    if not b.supports(platform, "fused_dc", mono.name, mono.dtype):
-        return None, None
-    fk = b.fused_stream(mono, tile=tile, q=q)
+def _resolve_dc(program: VertexProgram, backend=None, tile=None, q=None):
+    """Shard-local DC step (registry kernel ``fused_dc``): the stream
+    kernel that gathers each edge's value from the received bin table
+    and folds it, resolved as :func:`_resolve_fold` resolves the fold."""
+    b = kregistry.resolve("fused_dc", program.monoid, choice=backend)
+    fk = b.fused_stream(program.monoid, tile=tile, q=q)
     return kregistry._tag_scope(fk, "fused_dc", b.name), b.name
 
 
 def build_dc_step(program: VertexProgram, meta: dict,
                   axis_names: Sequence[str], dense_frontier: bool = False,
                   wire_bf16: bool = False, wire_bitmap: bool = False,
-                  fold=None, fused=None, batched: bool = False):
+                  fused=None, batched: bool = False):
     """Destination-centric distributed iteration (per-device body).
 
     dense_frontier: the app keeps every vertex active every iteration
@@ -223,21 +203,20 @@ def build_dc_step(program: VertexProgram, meta: dict,
     wire_bitmap: exchange the validity flags as a packed frontier bitmap
     (8x smaller than bool lanes on the wire, bit-exact).
     batched: the body carries a leading query-lane axis — state/active
-    arrive as ``[B, nv]`` shards, the bin exchange moves ``[B, D, S]`` in
-    ONE collective per payload, and the gather folds every lane through a
-    single flattened-segment-space fold (:func:`_fold_lanes`), so each
-    scatter/all_to_all/fold launch is amortized across the whole batch.
-    fused: a registry ``fused_dc`` stream kernel (:func:`_resolve_fused`);
-    when set, the gather side skips the ``[NEd]`` slot-gathered
-    edge-value stream entirely — the kernel gathers straight from the
-    received bin table and folds in one launch.  ``None`` keeps the
-    composed slot gather + fold."""
+    arrive as ``[B, nv]`` shards and the bin exchange moves ``[B, D, S]``
+    in ONE collective per payload, so each scatter and all_to_all is
+    amortized across the whole batch; the gather runs lane by lane
+    (:func:`_fold_lanes`).
+    fused: the registry ``fused_dc`` stream kernel (default: resolved by
+    :func:`_resolve_dc`).  The gather side runs it over the received bin
+    table: it gathers each edge's value straight from the table and
+    folds it in one launch, with no ``[NEd]`` edge-value stream."""
     mono = program.monoid
     nv, S, D = meta["nv"], meta["S"], meta["D"]
     weighted = meta["weighted"]
     axes = tuple(axis_names)
     compress = wire_bf16 and mono.dtype == jnp.float32
-    fold = fold if fold is not None else _resolve_fold(program)[0]
+    fused = fused if fused is not None else _resolve_dc(program)[0]
     # wire dtype used end-to-end from scatter through the gather-side slot
     # lookup: adjacent up/down-cast pairs around the collective get
     # cancelled by XLA's algebraic simplifier (observed), so the narrow
@@ -298,48 +277,24 @@ def build_dc_step(program: VertexProgram, meta: dict,
              jnp.full(lead + (1,), ident, wdt)], axis=-1)
 
         # ---- gather over the pre-written dc_bin ----
-        if fused is not None:
-            # fused lowering: the kernel gathers each edge's value from
-            # the received bin table itself — no [NEd] edge-value stream.
-            # The table is pre-cast off the wire dtype (the elementwise
-            # cast commutes with the gather, so parity with the composed
-            # ``rv[slot].astype`` is bit-exact); shard_layout keeps each
-            # device's edges in destination order, so the fold skips
-            # its sort
-            table = rv.astype(mono.dtype)
-            aw = (program.apply_weight
-                  if program.apply_weight is not None and weighted
-                  else None)
-            w = A["in_w"] if aw is not None else None
-            slot, evalid_s = A["in_msg_slot"], A["in_valid"]
-            dst_s = A["in_dst_local"]
-            if batched:
-                # per-lane unroll, same rationale as _fold_lanes (the
-                # static slot/validity/dst streams are shared)
-                accs, touch = [], []
-                for i in range(table.shape[0]):
-                    a, t, _ = fused(table[i], rf[i], slot, evalid_s,
-                                    dst_s, nv + 1, w=w, apply_weight=aw,
-                                    presorted=True)
-                    accs.append(a)
-                    touch.append(t)
-                acc, touched = jnp.stack(accs), jnp.stack(touch)
-            else:
-                acc, touched, _ = fused(table, rf, slot, evalid_s, dst_s,
-                                        nv + 1, w=w, apply_weight=aw,
-                                        presorted=True)
+        # the kernel gathers each edge's value from the received bin
+        # table itself — no [NEd] edge-value stream.  The table is
+        # pre-cast off the wire dtype (the elementwise cast commutes with
+        # the gather); shard_layout keeps each device's edges in
+        # destination order, so the fold skips its sort
+        table = rv.astype(mono.dtype)
+        aw = (program.apply_weight
+              if program.apply_weight is not None and weighted else None)
+        w = A["in_w"] if aw is not None else None
+        stream = (A["in_msg_slot"], A["in_valid"], A["in_dst_local"],
+                  nv + 1)
+        if batched:
+            # the static slot/validity/dst streams are shared by every lane
+            acc, touched = _fold_lanes(fused, table, rf, *stream, w=w,
+                                       apply_weight=aw, presorted=True)
         else:
-            slot = A["in_msg_slot"]
-            ev = rv[..., slot].astype(mono.dtype)             # [.., NEd]
-            evalid = rf[..., slot] & A["in_valid"]
-            if program.apply_weight is not None and weighted:
-                ev = vm(program.apply_weight, (0, None))(ev, A["in_w"])
-            ev = jnp.where(evalid, ev, mono.identity)
-            dst = jnp.where(evalid, A["in_dst_local"], nv)
-            if batched:
-                acc, touched = _fold_lanes(fold, ev, evalid, dst, nv + 1)
-            else:
-                acc, touched = fold(ev, evalid, dst, nv + 1)
+            acc, touched, _ = fused(table, rf, *stream, w=w,
+                                    apply_weight=aw, presorted=True)
         acc, touched = acc[..., :nv], touched[..., :nv]
 
         st3, activated = vm(program.apply_fn, (0, 0, 0, None))(
@@ -591,7 +546,7 @@ class DistEngine:
         fold, self.backend_name = _resolve_fold(
             program, backend, tile=getattr(sharded, "fold_tile", None),
             q=getattr(sharded, "fold_q", None))
-        fused, self.fused_backend_name = _resolve_fused(
+        fused, self.fused_backend_name = _resolve_dc(
             program, backend, tile=getattr(sharded, "fold_tile", None),
             q=getattr(sharded, "fold_q", None))
         self.fold, self.fused = fold, fused
@@ -609,7 +564,7 @@ class DistEngine:
         deg[:len(sharded.deg)] = sharded.deg
         self.deg = jax.device_put(deg, shard)
 
-        dc_body = build_dc_step(program, meta, self.axes, fold=fold,
+        dc_body = build_dc_step(program, meta, self.axes,
                                 fused=fused, wire_bf16=wire_bf16,
                                 wire_bitmap=wire_bitmap)
         sc_body = build_sc_step(program, meta, self.axes, fold=fold)
@@ -639,7 +594,7 @@ class DistEngine:
         # collective.  jit's shape cache provides the per-width
         # specializations _run_batched_loop asks for (<= log2(B) of them
         # thanks to the pow2 lane compaction)
-        dcb_body = build_dc_step(program, meta, self.axes, fold=fold,
+        dcb_body = build_dc_step(program, meta, self.axes,
                                  fused=fused, wire_bf16=wire_bf16,
                                  wire_bitmap=wire_bitmap, batched=True)
         bspec = P(None, tuple(mesh.axis_names))

@@ -15,8 +15,7 @@ atomic-free out of cache maps onto the kernel as:
     every partial combine is a register/VMEM operation — no scatter-add,
     no ``jax.ops.segment_*``, no atomics anywhere in the lowering;
   * block partials compose through the monoid because TPU grid steps
-    execute sequentially over a revisited output block (the same
-    accumulation contract :mod:`repro.kernels.segment_combine` relies on).
+    execute sequentially over a revisited output block.
 
 Because the fold is a plain ``pallas_call`` over per-shard arrays (no
 collectives, no layout capture), it traces cleanly inside ``shard_map``
@@ -34,9 +33,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-
-from .segment_combine import _identity_val, from_signed, to_signed
 
 DEFAULT_FOLD_TILE = 256
 ENV_FOLD_TILE = "REPRO_FOLD_TILE"
@@ -54,6 +52,46 @@ DEFAULT_FOLD_MAX_SEGMENTS = 4096
 ENV_FOLD_MAX_SEGMENTS = "REPRO_FOLD_MAX_SEGMENTS"
 _LANES = 128
 _BLOCK = 1024
+
+
+def _identity_val(monoid: str, dtype):
+    """The monoid's identity as a numpy scalar of ``dtype``.  With
+    :func:`to_signed` and :func:`from_signed` it is shared by both folds
+    and :mod:`repro.kernels.fused_step`."""
+    if monoid == "add":
+        return np.zeros((), dtype)
+    if monoid == "min":
+        return (np.array(np.inf, dtype) if jnp.issubdtype(dtype, jnp.floating)
+                else np.array(np.iinfo(dtype).max, dtype))
+    if monoid == "max":
+        return (np.array(-np.inf, dtype) if jnp.issubdtype(dtype, jnp.floating)
+                else np.array(np.iinfo(dtype).min, dtype))
+    raise ValueError(monoid)
+
+
+_SIGN = np.int32(-2**31)
+
+
+def to_signed(x, monoid: str):
+    """Order-preserving int32 view of a uint32 payload for the kernels.
+
+    Mosaic has no reductions over unsigned integers.  Flipping the sign
+    bit maps the unsigned order onto the signed one, so min/max commute
+    with the view; add wraps identically on the plain bitcast.  Other
+    dtypes pass through unchanged."""
+    if x.dtype != jnp.uint32:
+        return x
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits if monoid == "add" else bits ^ _SIGN
+
+
+def from_signed(x, monoid: str, dtype):
+    """Inverse of :func:`to_signed` back to ``dtype``."""
+    if jnp.dtype(dtype) != jnp.uint32:
+        return x
+    if monoid != "add":
+        x = x ^ _SIGN
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
 
 def default_fold_tile() -> int:

@@ -45,8 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fold_block import combine_tile, dma_block
-from .segment_combine import _identity_val, from_signed, to_signed
+from .fold_block import (_identity_val, combine_tile, dma_block,
+                         from_signed, to_signed)
 
 # Bucket width of the two-level fold: how many consecutive segments one
 # VMEM-resident sub-accumulator covers.  256 keeps the [fold_tile, q]

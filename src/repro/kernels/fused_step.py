@@ -1,12 +1,11 @@
 """Fused DC step — the Gather phase without the bin buffer.
 
-The composed DC lowering materializes every message twice: the Scatter
-kernel writes the full ``[NM]`` bin buffer (values only, the paper's
-pre-written ``dc_bin``), the slot gather re-reads it into a ``[NE]``
-edge-value stream, and only then does the segmented fold collapse it into
-the per-partition accumulators.
+The paper's DC stream writes every message into the ``[NM]`` bin buffer
+(values only, the pre-written ``dc_bin``) and the Gather phase re-reads
+it edge by edge.  On the chip that is two HBM round trips for data that
+is consumed once, so the engines' one DC lowering skips the buffer.
 
-This step goes straight from the vertex-message table to the fold: XLA
+The step goes straight from the vertex-message table to the fold: XLA
 gathers each edge's source value out of the ``[n_pad + 1]`` table
 (identity sentinel last), the optional edge function (``apply_weight``)
 runs on the gathered values, and the two-level Pallas fold
@@ -32,30 +31,16 @@ Mosaic lowers no vector gather from a table of this size inside a kernel
 A stream already in destination order (``presorted=True``: the
 layout-bound kernel sorts its static DC stream by destination once) folds
 without the fold's per-call sort.
-
-Env: ``REPRO_FUSED=0`` opts the engines out of fused selection entirely
-(they silently fall back to the composed scatter→fold path, which also
-remains the path for SC/hybrid streams and unsupported backends).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-from .fold_block import default_fold_tile
+from .fold_block import _identity_val, default_fold_tile
 from .fold_two_level import default_fold_q, two_level_segment_fold
-from .segment_combine import _identity_val
-
-ENV_FUSED = "REPRO_FUSED"
-
-
-def fused_enabled() -> bool:
-    """Engine-side opt-out: ``REPRO_FUSED=0`` disables fused DC selection
-    (the composed scatter→fold path runs instead).  Default: enabled."""
-    return os.environ.get(ENV_FUSED, "1") != "0"
 
 
 def _bits(x):
@@ -91,8 +76,7 @@ def fused_scatter_fold(table, table_valid, idx, edge_valid, dst,
                    ``nv + 1``; the overflow bin is the last segment).
       edge_tile:   messages per one-hot combine of the fold.
       apply_weight: optional static edge function ``f(vals, w)`` applied
-                   to the gathered values (the composed path applies it
-                   to the same inputs elementwise, so parity is exact).
+                   to the gathered values, before the fold.
       w:           [NE] edge weights; required iff apply_weight is set.
       presorted:   ``dst`` is non-decreasing (see
                    :func:`~repro.kernels.fold_two_level.two_level_segment_fold`).

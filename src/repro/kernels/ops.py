@@ -1,17 +1,21 @@
-"""Layout-bound jit wrappers around the PPM kernels.
+"""Engine-facing wrappers around the PPM kernels.
 
-``GatherKernel`` / ``ScatterKernel`` / ``SpmvKernel`` bind a
-:class:`repro.graph.layout.Layout` once (moving the static bin-grid geometry
-to device) and expose the engine-facing API over the Pallas bodies
-(``interpret=True`` runs them on CPU for validation; ``interpret=False``
-compiles to Mosaic on TPU).  ``RefGather`` / ``RefScatter`` / ``RefSpmv``
-are the pure-jnp implementations of the *same* engine-facing API, built on
-:mod:`repro.kernels.ref` — the semantic oracle and the fast CPU path.
+Two kernels, each with a Pallas lowering and a pure-jnp ``ref`` one of
+the same call contract:
+
+* ``fold`` — :class:`FoldKernel` (the blocked Pallas folds;
+  ``interpret=True`` runs them on a CPU, ``interpret=False`` compiles
+  them to Mosaic on a TPU) and :class:`RefFold`;
+* ``fused_dc`` — the DC step of :mod:`repro.kernels.fused_step`, bound
+  to a layout (:class:`FusedDCKernel`, :class:`RefFusedDC`) or fed a
+  stream per call (:class:`FusedStreamKernel`, :class:`RefFusedStream`,
+  the distributed engine's).
 
 Engines do not pick between them directly: construct kernels through
-:func:`repro.backend.registry.make_kernels` (or the :func:`make_kernels`
-convenience re-export below), which resolves the backend from the platform,
-the ``REPRO_KERNEL_BACKEND`` override, and per-combination support.
+:func:`repro.backend.registry.make_kernels` (or
+:func:`~repro.backend.registry.resolve`), which resolves the backend
+from the platform, the ``REPRO_KERNEL_BACKEND`` override, and
+per-combination support.
 
 Every layout-bound kernel keeps the layout arrays it reads in
 :attr:`LayoutBound.arrays` and takes them back as an ``arrays=`` call
@@ -25,15 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import tracing as obs_tracing
-from . import ref as kref
-from .dc_gather import dc_gather
 from .fold_block import (blocked_segment_fold, default_fold_tile,
                          max_fold_segments)
 from .fold_two_level import default_fold_q, two_level_segment_fold
-from .fused_step import (fused_enabled, fused_scatter_fold,
-                         ref_fused_scatter_fold)
-from .segment_combine import segment_combine, _identity_val
-from .spmv_block import spmv_block
+from .fused_step import fused_scatter_fold, ref_fused_scatter_fold
 
 
 class LayoutBound:
@@ -63,102 +62,6 @@ class LayoutBound:
 
     def _args(self, arrays):
         return self.arrays if arrays is None else arrays
-
-
-class GatherKernel(LayoutBound):
-    """Gather-phase fold bound to a layout (acc + touched over [n_pad])."""
-
-    def __init__(self, layout, monoid_name: str, dtype,
-                 interpret: bool = True):
-        super().__init__(layout)
-        self.monoid = monoid_name
-        self.dtype = jnp.dtype(dtype)
-        self.interpret = interpret
-        self.ident = _identity_val(monoid_name, self.dtype)
-
-    def host_arrays(self):
-        L = self.L
-        return {"tile_dst_part": L.tile_dst_part,
-                "tile_src_part": L.tile_src_part,
-                "tile_first": L.tile_first.astype(np.int32),
-                "edge_dst_local": L.edge_dst_local,
-                "has_tiles": L.part_has_tiles.astype(np.int32)[:, None]}
-
-    def __call__(self, edge_vals, edge_valid, part_active, arrays=None):
-        L, A = self.L, self._args(arrays)
-        with obs_tracing.kernel_scope(
-                getattr(self, "_obs_scope", "ppm.gather")):
-            acc, touched = segment_combine(
-                edge_vals, edge_valid, A["edge_dst_local"],
-                A["tile_dst_part"], A["tile_src_part"], A["tile_first"],
-                part_active, k=L.k, q=L.q, edge_tile=L.edge_tile,
-                monoid=self.monoid, interpret=self.interpret)
-            # destination partitions with no incoming tiles were never
-            # visited
-            acc = jnp.where(A["has_tiles"] > 0, acc, self.ident)
-            touched = jnp.where(A["has_tiles"] > 0, touched, 0)
-            return acc.reshape(-1), touched.reshape(-1) > 0
-
-
-class ScatterKernel(LayoutBound):
-    """DC scatter-phase message materialization bound to a layout."""
-
-    def __init__(self, layout, monoid_name: str, dtype,
-                 interpret: bool = True):
-        super().__init__(layout)
-        self.monoid = monoid_name
-        self.dtype = jnp.dtype(dtype)
-        self.interpret = interpret
-
-    def host_arrays(self):
-        L = self.L
-        return {"png_src_local": L.png_src_local,
-                "png_valid": (L.png_src < L.n_pad).astype(np.int32),
-                "png_tile_part": L.png_tile_part}
-
-    def __call__(self, x_flat, active_flat, arrays=None):
-        L, A = self.L, self._args(arrays)
-        with obs_tracing.kernel_scope(
-                getattr(self, "_obs_scope", "ppm.scatter")):
-            return dc_gather(
-                x_flat.reshape(L.k, L.q),
-                active_flat.astype(jnp.int32).reshape(L.k, L.q),
-                A["png_src_local"], A["png_valid"], A["png_tile_part"],
-                k=L.k, q=L.q, msg_tile=L.msg_tile, monoid=self.monoid,
-                interpret=self.interpret)
-
-
-class SpmvKernel(LayoutBound):
-    """Fused partition-centric SpMV bound to a layout (PageRank DC loop)."""
-
-    def __init__(self, layout, interpret: bool = True, weighted=None):
-        super().__init__(layout)
-        self.interpret = interpret
-        self.weighted = ((layout.weighted if weighted is None else weighted)
-                         and layout.edge_w is not None)
-
-    def host_arrays(self):
-        L = self.L
-        return {"edge_src_local": L.edge_src_local,
-                "edge_dst_local": L.edge_dst_local,
-                "edge_valid": L.edge_valid.astype(np.int32),
-                "edge_w": L.edge_w if self.weighted else None,
-                "tile_dst_part": L.tile_dst_part,
-                "tile_src_part": L.tile_src_part,
-                "tile_first": L.tile_first.astype(np.int32),
-                "has_tiles": L.part_has_tiles.astype(np.int32)[:, None]}
-
-    def __call__(self, x_flat, arrays=None):
-        L, A = self.L, self._args(arrays)
-        with obs_tracing.kernel_scope(
-                getattr(self, "_obs_scope", "ppm.spmv")):
-            y = spmv_block(
-                x_flat.reshape(L.k, L.q), A["edge_src_local"],
-                A["edge_dst_local"], A["edge_valid"], A["edge_w"],
-                A["tile_dst_part"], A["tile_src_part"], A["tile_first"],
-                k=L.k, q=L.q, edge_tile=L.edge_tile,
-                weighted=self.weighted, interpret=self.interpret)
-            return jnp.where(A["has_tiles"] > 0, y, 0.0).reshape(-1)
 
 
 class FoldKernel:
@@ -236,83 +139,6 @@ class RefFold:
             return acc, touched
 
 
-class RefGather(LayoutBound):
-    """Pure-jnp gather fold with GatherKernel's exact call contract.
-
-    Unlike the raw :func:`repro.kernels.ref.segment_combine_ref` oracle it
-    also applies the 2-level active list (tiles of inactive source
-    partitions contribute nothing) and masks invalid slots to the monoid
-    identity, so it is interchangeable with the Pallas kernels under the
-    engine and under parity tests.
-
-    The call carries a ``custom_vmap`` rule: under a leading query axis
-    (the batched multi-source engine path) XLA's default scatter batching
-    rule serializes catastrophically on CPU (~100x), so the batched fold
-    instead runs the *unbatched* segment ops over a flattened
-    ``lane * (n_pad+1) + dst`` segment space — per-lane cost identical to
-    the sequential fold, so batching only ever amortizes dispatch.
-    """
-
-    def __init__(self, layout, monoid):
-        super().__init__(layout)
-        self.monoid = monoid
-        self.n_pad = layout.n_pad
-        call = jax.custom_batching.custom_vmap(self._single)
-        call.def_vmap(self._vmap_rule)
-        self._call = call
-
-    def host_arrays(self):
-        L = self.L
-        # every edge tile lies inside one (p', p) block: per-edge source
-        # partition is the tile's, repeated
-        return {"edge_dst": L.edge_dst,
-                "edge_src_part": np.repeat(L.tile_src_part, L.edge_tile)}
-
-    def __call__(self, edge_vals, edge_valid, part_active, arrays=None):
-        with obs_tracing.kernel_scope(
-                getattr(self, "_obs_scope", "ppm.gather.ref")):
-            return self._call(edge_vals, edge_valid, part_active,
-                              self._args(arrays))
-
-    def _single(self, edge_vals, edge_valid, part_active, A):
-        mono = self.monoid
-        valid = (edge_valid.astype(bool)
-                 & (part_active[A["edge_src_part"]] > 0))
-        vals = jnp.where(valid, edge_vals.astype(mono.dtype), mono.identity)
-        acc = mono.segment_fold(vals, A["edge_dst"], self.n_pad + 1)
-        touched = jax.ops.segment_max(valid.astype(jnp.int32), A["edge_dst"],
-                                      num_segments=self.n_pad + 1) > 0
-        return acc[:self.n_pad], touched[:self.n_pad]
-
-    def _vmap_rule(self, axis_size, in_batched, edge_vals, edge_valid,
-                   part_active, A):
-        ev_b, evd_b, pa_b, a_b = in_batched
-        assert not any(jax.tree_util.tree_leaves(a_b)), \
-            "layout arrays are shared by every lane"
-        if not ev_b:
-            edge_vals = jnp.broadcast_to(
-                edge_vals, (axis_size,) + edge_vals.shape)
-        if not evd_b:
-            edge_valid = jnp.broadcast_to(
-                edge_valid, (axis_size,) + edge_valid.shape)
-        if not pa_b:
-            part_active = jnp.broadcast_to(
-                part_active, (axis_size,) + part_active.shape)
-        mono = self.monoid
-        edge_dst = A["edge_dst"]
-
-        def lanes(vals, valid, active):
-            valid = (valid.astype(bool)
-                     & (jnp.take(active, A["edge_src_part"], axis=1) > 0))
-            vals = jnp.where(valid, vals.astype(mono.dtype), mono.identity)
-            return vals, valid, edge_dst[None, :]
-
-        acc, touched = _fold_lanes_flat(
-            mono, lanes, (edge_vals, edge_valid, part_active),
-            self.n_pad + 1, edge_dst.shape[0])
-        return (acc[:, :self.n_pad], touched[:, :self.n_pad]), (True, True)
-
-
 #: elements of one ``[lanes, edges]`` stream in a batched ref fold: lanes
 #: fold in chunks of at most this many, one chunk after another, so a
 #: batch never holds ``B`` edge-sized streams at once (8 lanes of a
@@ -385,20 +211,19 @@ def _dst_order(layout) -> np.ndarray:
 class FusedDCKernel(LayoutBound):
     """Fused DC step bound to a layout (registry ``fused_dc``).
 
-    Replaces the composed scatter kernel + slot gather + gather fold of
-    the DC stream: each edge's source message is gathered straight from
-    the ``[n_pad + 1]`` vertex table (identity sentinel last) and folded
-    into the two-level ``[fold_q]`` sub-accumulators — no ``[NM]`` bin
-    buffer (see :mod:`repro.kernels.fused_step`).  The static edge stream
-    is kept in destination order, sorted once here, so the fold runs
-    without a per-call sort.  A call returns ``(acc, touched,
+    The engine's DC stream: each edge's source message is gathered
+    straight from the ``[n_pad + 1]`` vertex table (identity sentinel
+    last) and folded into the two-level ``[fold_q]`` sub-accumulators —
+    no ``[NM]`` bin buffer (see :mod:`repro.kernels.fused_step`).  The
+    static edge stream is kept in destination order, sorted once here,
+    so the fold runs without a per-call sort.  A call returns ``(acc, touched,
     one_gather)``, the last whether the step gathered each edge once (see
     :func:`~repro.kernels.fused_step.fused_scatter_fold`).
 
     ``apply_weight`` is engine-configured (the registry does not see the
     program): :class:`repro.core.engine.Engine` sets the attribute once,
-    before the step is traced, under the same condition the composed
-    path applies it.
+    before the step is traced, where the program has an edge function
+    and the layout has weights.
 
     Under ``vmap`` (the batched engine path) the lanes run one after
     another through the unbatched step, so a batch holds one lane's edge
@@ -456,16 +281,17 @@ class FusedDCKernel(LayoutBound):
 
 
 class RefFusedDC(LayoutBound):
-    """Pure-jnp fused DC step with FusedDCKernel's exact call contract —
-    the composed oracle collapsed to one gather + one segmented fold.
-    It gathers value and validity apart on every call, so its
-    ``one_gather`` is always false.
+    """Pure-jnp fused DC step with FusedDCKernel's exact call contract:
+    one gather and one segmented fold.  It gathers value and validity
+    apart on every call, so its ``one_gather`` is always false.
 
-    Carries the same ``custom_vmap`` rule as :class:`RefGather` (the
-    batched multi-source engine path): the table gather batches fine,
-    but the segment fold would hit XLA's catastrophic scatter batching
-    on CPU, so batched lanes fold through a flattened
-    ``lane * ns + dst`` segment space instead.
+    The call carries a ``custom_vmap`` rule (the batched multi-source
+    engine path): the table gather batches fine, but XLA's default
+    scatter batching rule serializes the segment fold catastrophically
+    on CPU (~100x), so batched lanes fold through a flattened
+    ``lane * ns + dst`` segment space instead (:func:`_fold_lanes_flat`)
+    — per-lane cost identical to the sequential fold, so batching only
+    ever amortizes dispatch.
     """
 
     def __init__(self, layout, monoid):
@@ -577,64 +403,7 @@ class RefFusedStream:
         return acc, touched, jnp.bool_(False)
 
 
-class RefScatter(LayoutBound):
-    """Pure-jnp DC scatter with ScatterKernel's exact call contract."""
-
-    def __init__(self, layout, monoid):
-        super().__init__(layout)
-        self.monoid = monoid
-        self.n_pad = layout.n_pad
-
-    def host_arrays(self):
-        L = self.L
-        return {"png_src": L.png_src, "png_valid": L.png_src < L.n_pad}
-
-    def __call__(self, x_flat, active_flat, arrays=None):
-        mono, A = self.monoid, self._args(arrays)
-        with obs_tracing.kernel_scope(
-                getattr(self, "_obs_scope", "ppm.scatter.ref")):
-            src = jnp.minimum(A["png_src"], self.n_pad - 1)
-            ok = A["png_valid"] & (active_flat.astype(bool)[src])
-            return jnp.where(ok, x_flat.astype(mono.dtype)[src],
-                             mono.identity)
-
-
-class RefSpmv(LayoutBound):
-    """Pure-jnp partition-centric SpMV with SpmvKernel's call contract."""
-
-    def __init__(self, layout, weighted=None):
-        super().__init__(layout)
-        self.n_pad = layout.n_pad
-        self.weighted = ((layout.weighted if weighted is None else weighted)
-                         and layout.edge_w is not None)
-
-    def host_arrays(self):
-        L = self.L
-        return {"msg_slot": L.msg_slot, "png_src": L.png_src,
-                "edge_dst": L.edge_dst, "edge_valid": L.edge_valid,
-                "edge_w": L.edge_w if self.weighted else None}
-
-    def __call__(self, x_flat, arrays=None):
-        A = self._args(arrays)
-        with obs_tracing.kernel_scope(
-                getattr(self, "_obs_scope", "ppm.spmv.ref")):
-            return kref.spmv_block_ref(
-                x_flat, A["msg_slot"], A["png_src"], A["edge_dst"],
-                A["edge_valid"], A["edge_w"], self.n_pad)
-
-
-def make_kernels(layout, monoid, backend=None, platform=None,
-                 with_spmv=False):
-    """Construct the engine-facing kernel set through the backend registry."""
-    from ..backend import registry
-    return registry.make_kernels(layout, monoid, backend=backend,
-                                 platform=platform, with_spmv=with_spmv)
-
-
-__all__ = ["LayoutBound", "GatherKernel", "ScatterKernel", "SpmvKernel",
-           "FoldKernel", "FusedDCKernel", "FusedStreamKernel", "RefGather", "RefScatter",
-           "RefSpmv", "RefFold", "RefFusedDC", "RefFusedStream",
-           "make_kernels", "segment_combine", "dc_gather", "spmv_block",
+__all__ = ["LayoutBound", "FoldKernel", "RefFold", "FusedDCKernel",
+           "RefFusedDC", "FusedStreamKernel", "RefFusedStream",
            "blocked_segment_fold", "two_level_segment_fold",
-           "fused_scatter_fold", "ref_fused_scatter_fold", "fused_enabled",
-           "kref"]
+           "fused_scatter_fold", "ref_fused_scatter_fold"]

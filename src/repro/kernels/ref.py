@@ -1,7 +1,8 @@
-"""Pure-jnp oracles for the Pallas kernels.
+"""Pure-jnp oracle for the segmented monoid fold.
 
-Each function is the semantic ground truth used by per-kernel allclose tests
-(interpret mode) and by the engine's non-Pallas path.
+The semantic ground truth the fold tests check the Pallas kernels
+against (interpret mode).  The ``ref`` backend's own kernels live in
+:mod:`repro.kernels.ops` (``RefFold``, ``RefFusedDC``).
 """
 from __future__ import annotations
 
@@ -42,30 +43,3 @@ def segment_combine_ref(vals, valid, ids, num_segments, monoid="add"):
     touched = jax.ops.segment_max(valid.astype(jnp.int32), ids,
                                   num_segments=num_segments) > 0
     return acc, touched
-
-
-def dc_gather_ref(msg_x, active, png_src, png_valid, monoid="add"):
-    """Scatter-phase DC message materialization: values of active sources,
-    monoid identity elsewhere (the paper's 'scatter whole partition' with
-    array-exact no-op semantics)."""
-    ident = _identity(monoid, msg_x.dtype)
-    n_pad = msg_x.shape[0]
-    src = jnp.minimum(png_src, n_pad - 1)
-    ok = png_valid.astype(bool) & active[src]
-    return jnp.where(ok, msg_x[src], ident)
-
-
-def spmv_block_ref(x, msg_slot, png_src, edge_dst, edge_valid, edge_w,
-                   n_pad):
-    """Fused partition-centric SpMV (PageRank DC inner loop):
-    y[dst] += w * x[src] over the static dc_bin layout."""
-    nm = png_src.shape[0]
-    src = jnp.minimum(png_src, n_pad - 1)
-    msg = jnp.where(png_src < n_pad, x[src], 0.0)
-    msg_p = jnp.concatenate([msg, jnp.zeros((1,), x.dtype)])
-    ev = msg_p[jnp.minimum(msg_slot, nm)]
-    if edge_w is not None:
-        ev = ev * edge_w
-    ev = jnp.where(edge_valid.astype(bool), ev, 0.0)
-    return jax.ops.segment_sum(ev, jnp.minimum(edge_dst, n_pad),
-                               num_segments=n_pad + 1)[:n_pad]

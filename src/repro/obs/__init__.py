@@ -46,7 +46,7 @@ What gets recorded
   ``run_batched`` of a hybrid or sc engine and ``Engine.run_fused`` open
   host spans (:func:`annotation`, a ``jax.profiler.TraceAnnotation``),
   so a capture shows what the host did while the device idled.
-* **Kernels** — every registry-constructed scatter/gather/fold/spmv
+* **Kernels** — every registry-constructed ``fused_dc`` / ``fold``
   call runs under a ``jax.named_scope`` tagged with the kernel and
   backend name, so a ``jax.profiler.trace()`` capture (see
   :func:`trace`) attributes device time to PPM phases.

@@ -17,6 +17,7 @@ from repro.backend import registry, tuning
 from repro.core import monoid as M
 from repro.core.engine import Engine
 from repro.graph import build_layout, rmat
+from repro.kernels import ops as kops
 
 ON_TPU = jax.default_backend() == "tpu"
 PARITY_BACKENDS = ["ref", "pallas-interpret"] + (["pallas-native"]
@@ -45,46 +46,36 @@ def _edge_vals(rng, L, dtype):
     return jnp.asarray(v.astype(np.dtype(dtype)))
 
 
-def _vertex_vals(rng, L, dtype):
-    v = rng.integers(0, 64, L.n_pad)
-    return jnp.asarray(v.astype(np.dtype(dtype)))
-
-
 # ----------------------------------------------------------------------
 # parity: ref / pallas-interpret / (TPU) pallas-native, bit-exact
 # ----------------------------------------------------------------------
 
+def _table(rng, L, dtype, p_valid=0.6):
+    """A DC step's message table over ``n_pad + 1`` slots (the sentinel
+    last, invalid) with a random validity per slot."""
+    table = jnp.asarray(rng.integers(0, 64, L.n_pad + 1)
+                        .astype(np.dtype(dtype)))
+    valid = jnp.asarray(rng.random(L.n_pad + 1) < p_valid).at[-1].set(False)
+    return table, valid
+
+
 @pytest.mark.parametrize("dtype", ["float32", "int32"])
 @pytest.mark.parametrize("monoid", ["add", "min", "max"])
 @pytest.mark.parametrize("backend", PARITY_BACKENDS)
-def test_gather_parity(layout, rng, backend, monoid, dtype):
+def test_fused_dc_parity(layout, rng, backend, monoid, dtype):
+    """The layout-bound DC step of every backend, built through the
+    registry, agrees bit-for-bit with ``ref`` on the module's layout:
+    the Pallas kernels fold the destination-sorted copy of the edge
+    stream, ``ref`` the stream in layout order."""
     mono = MONOIDS[(monoid, dtype)]()
-    b = registry.BACKENDS[backend]
-    gk = b.gather(layout, mono)
-    ref = registry.BACKENDS["ref"].gather(layout, mono)
-    ev = _edge_vals(rng, layout, dtype)
-    valid = jnp.asarray(layout.edge_valid) \
-        & jnp.asarray(rng.random(layout.num_edges) < 0.7)
-    pa = jnp.asarray((rng.random(layout.k) < 0.7).astype(np.int32))
-    acc, touched = gk(ev, valid, pa)
-    racc, rtouched = ref(ev, valid, pa)
+    kset = registry.make_kernels(layout, mono, backend=backend)
+    assert kset.names["fused_dc"] == backend
+    ref = registry.BACKENDS["ref"].fused_dc(layout, mono)
+    table, valid = _table(rng, layout, dtype)
+    acc, touched, _ = kset.fused(table, valid)
+    racc, rtouched, _ = ref(table, valid)
     assert np.array_equal(np.asarray(touched), np.asarray(rtouched))
     assert np.array_equal(np.asarray(acc), np.asarray(racc))
-
-
-@pytest.mark.parametrize("dtype", ["float32", "int32"])
-@pytest.mark.parametrize("monoid", ["add", "min", "max"])
-@pytest.mark.parametrize("backend", PARITY_BACKENDS)
-def test_scatter_parity(layout, rng, backend, monoid, dtype):
-    mono = MONOIDS[(monoid, dtype)]()
-    b = registry.BACKENDS[backend]
-    sk = b.scatter(layout, mono)
-    ref = registry.BACKENDS["ref"].scatter(layout, mono)
-    x = _vertex_vals(rng, layout, dtype)
-    active = jnp.asarray(
-        (rng.random(layout.n_pad) < 0.5).astype(np.int32))
-    assert np.array_equal(np.asarray(sk(x, active)),
-                          np.asarray(ref(x, active)))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int32"])
@@ -110,7 +101,7 @@ def test_fold_parity(layout, rng, backend, monoid, dtype):
 
 def test_fold_default_is_pallas_on_cpu(monkeypatch):
     """Acceptance: kernel 'fold' resolves to a Pallas-backed kernel by
-    default even on CPU hosts (interpret mode), while gather keeps ref.
+    default even on CPU hosts (interpret mode), while fused_dc keeps ref.
     'Default' means no override: neutralize the env var (the CI kernels
     lane re-runs this module under both REPRO_KERNEL_BACKEND settings)."""
     monkeypatch.delenv(registry.ENV_VAR, raising=False)
@@ -120,7 +111,7 @@ def test_fold_default_is_pallas_on_cpu(monkeypatch):
     assert isinstance(b.segment_fold("add"), FoldKernel)
     assert registry.default_backend_name("cpu", kernel="fold") \
         == "pallas-interpret"
-    assert registry.default_backend_name("cpu", kernel="gather") == "ref"
+    assert registry.default_backend_name("cpu", kernel="fused_dc") == "ref"
     assert registry.default_backend_name("tpu", kernel="fold") \
         == "pallas-native"
 
@@ -287,45 +278,32 @@ def test_stale_tuning_cache_is_a_miss(tmp_path):
     assert rec2["fold_q"] == geom2.fold_q
 
 
-@pytest.mark.parametrize("backend", PARITY_BACKENDS)
-def test_spmv_parity(layout, rng, backend):
-    b = registry.BACKENDS[backend]
-    vk = b.spmv(layout)
-    ref = registry.BACKENDS["ref"].spmv(layout)
-    x = _vertex_vals(rng, layout, "float32")
-    assert np.array_equal(np.asarray(vk(x)), np.asarray(ref(x)))
-
-
-def test_gather_int32_above_2_24(layout, rng):
-    """min/max/add over int32 state beyond the f32 mantissa must round-trip
-    exactly (the one-hot MXU path used to truncate through float32)."""
-    big = (1 << 24) + rng.integers(1, 1000, layout.num_edges)
-    ev = jnp.asarray(big.astype(np.int32))
-    valid = jnp.asarray(layout.edge_valid) \
-        & jnp.asarray(rng.random(layout.num_edges) < 0.05)
-    pa = jnp.ones((layout.k,), jnp.int32)
-    for name in ("min", "max", "add"):
-        mono = MONOIDS[(name, "int32")]()
-        gk = registry.BACKENDS["pallas-interpret"].gather(layout, mono)
-        acc, touched = gk(ev, valid, pa)
-        racc, rtouched = registry.BACKENDS["ref"].gather(layout, mono)(
-            ev, valid, pa)
-        assert np.array_equal(np.asarray(acc), np.asarray(racc)), name
-        # and the surviving values really are the un-truncated payloads
-        tm = np.asarray(touched)
-        if name in ("min", "max") and tm.any():
-            assert (np.asarray(acc)[tm] > (1 << 24)).all()
-
-
-def test_scatter_int32_above_2_24(layout, rng):
-    mono = MONOIDS[("min", "int32")]()
-    sk = registry.BACKENDS["pallas-interpret"].scatter(layout, mono)
-    big = (1 << 24) + rng.integers(1, 1000, layout.n_pad)
-    x = jnp.asarray(big.astype(np.int32))
-    active = jnp.ones((layout.n_pad,), jnp.int32)
-    msg = np.asarray(sk(x, active))
-    real = np.asarray(layout.png_src) < layout.n_pad
-    assert (msg[real] > (1 << 24)).all()
+@pytest.mark.parametrize("monoid", ["add", "max"])
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+def test_fused_dc_int32_above_2_24(layout, rng, backend, monoid):
+    """add and max over int32 payloads beyond the f32 mantissa fold
+    exactly through the DC step (an f32 round trip would truncate
+    them): the answer is numpy's, in int64 and then wrapped."""
+    mono = MONOIDS[(monoid, "int32")]()
+    kern = registry.BACKENDS[backend].fused_dc(layout, mono)
+    big = (1 << 24) + rng.integers(1, 1000, layout.n_pad + 1)
+    table = jnp.asarray(big.astype(np.int32))
+    valid = np.asarray(rng.random(layout.n_pad + 1) < 0.5)
+    valid[-1] = False
+    acc, touched, _ = kern(table, jnp.asarray(valid))
+    src = np.asarray(kops._edge_src_global(layout))
+    ok = valid[src] & layout.edge_valid
+    dst = np.asarray(layout.edge_dst)[ok]
+    want = np.full(layout.n_pad + 1,
+                   0 if monoid == "add" else np.iinfo(np.int32).min,
+                   np.int64)
+    fold = np.add if monoid == "add" else np.maximum
+    fold.at(want, dst, big[src[ok]].astype(np.int64))
+    reached = np.zeros(layout.n_pad + 1, bool)
+    reached[dst] = True
+    assert reached.any() and (want[reached] > (1 << 24)).all()
+    assert np.array_equal(np.asarray(touched), reached)
+    assert np.array_equal(np.asarray(acc), want.astype(np.int32))
 
 
 # ----------------------------------------------------------------------
@@ -346,10 +324,9 @@ def test_env_override_selects_backend(monkeypatch):
 def test_env_override_end_to_end(layout, monkeypatch, env):
     monkeypatch.setenv(registry.ENV_VAR, env)
     eng = Engine(layout, bfs_program())
-    # the override steers every kernel, including the fused DC step (bfs
-    # is min/uint32, which both Pallas backends and ref lower)
-    assert eng.backend_names == {"gather": env, "scatter": env,
-                                 "fold": env, "fused_dc": env}
+    # the override steers both kernels (bfs is min/uint32, which both
+    # Pallas backends and ref lower)
+    assert eng.backend_names == {"fold": env, "fused_dc": env}
     res = bfs(layout, source=3, engine=eng)
     ref = bfs(layout, source=3, backend="ref")
     assert np.array_equal(res["level"], ref["level"])
@@ -361,25 +338,82 @@ def test_unsupported_combo_falls_back_to_ref(layout):
     if ON_TPU:
         pytest.skip("fallback path is the non-TPU behaviour")
     with pytest.warns(RuntimeWarning, match="falling back to 'ref'"):
-        b = registry.resolve("gather", "add", jnp.float32,
+        b = registry.resolve("fused_dc", "add", jnp.float32,
                              choice="pallas-native")
     assert b.name == "ref"
     # a monoid outside the Pallas set falls back even for pallas-interpret
     with pytest.warns(RuntimeWarning, match="min_with_payload"):
-        b = registry.resolve("gather", M.min_with_payload(),
+        b = registry.resolve("fused_dc", M.min_with_payload(),
                              choice="pallas-interpret")
     assert b.name == "ref"
     # ... and the registry view agrees
-    assert registry.supported("cpu", "gather", "min_with_payload",
+    assert registry.supported("cpu", "fused_dc", "min_with_payload",
                               jnp.uint64) == ("ref",)
     with pytest.raises(ValueError, match="unknown backend"):
-        registry.resolve("gather", "add", choice="cuda")
+        registry.resolve("fused_dc", "add", choice="cuda")
+    # a name outside the registry's two kernels is refused
+    for gone in ("gather", "scatter", "spmv"):
+        with pytest.raises(ValueError, match="unknown kernel"):
+            registry.resolve(gone, "add")
+
+
+@pytest.mark.parametrize("how", ["backend", "env"])
+@pytest.mark.parametrize("monoid", ["or", "min_with_payload"])
+def test_fused_dc_falls_back_to_ref(layout, monkeypatch, monoid, how):
+    """A monoid no Pallas kernel folds sends the DC step to ``ref`` per
+    call, as it does the fold, and says so: whether the Pallas backend
+    was asked for by ``backend=`` or by ``REPRO_KERNEL_BACKEND``."""
+    choice = "pallas-interpret" if how == "backend" else None
+    if how == "env":
+        monkeypatch.setenv(registry.ENV_VAR, "pallas-interpret")
+    else:
+        monkeypatch.delenv(registry.ENV_VAR, raising=False)
+    with jax.enable_x64(True):              # the packed monoid is uint64
+        mono = M.REGISTRY[monoid]()
+        with pytest.warns(RuntimeWarning, match="falling back to 'ref'"):
+            assert registry.resolve("fused_dc", mono, platform="cpu",
+                                    choice=choice).name == "ref"
+        with pytest.warns(RuntimeWarning, match="kernel='fused_dc'"):
+            kset = registry.make_kernels(layout, mono, backend=choice)
+    assert kset.names == {"fold": "ref", "fused_dc": "ref"}
+    assert isinstance(kset.fused, kops.RefFusedDC)
+    assert not kset.any_pallas
+
+
+def test_sssp_parents_on_pallas_backend_matches_reference(monkeypatch):
+    """SSSP with parents folds the packed uint64 monoid, which no Pallas
+    kernel lowers: asked for ``pallas-interpret``, its engine runs the
+    ``ref`` DC step and fold, warns, and answers as scipy does, with a
+    tight parent edge under every reached vertex."""
+    import scipy.sparse.csgraph as csg
+    from repro.apps import sssp_with_parents
+    from repro.graph import to_scipy
+    monkeypatch.delenv(registry.ENV_VAR, raising=False)
+    g = rmat(7, 8, seed=5, weighted=True)
+    L = build_layout(g, k=4, edge_tile=32, msg_tile=16)
+    src = int(np.argmax(g.out_degrees()))
+    with pytest.warns(RuntimeWarning, match="kernel='fused_dc'"):
+        r = sssp_with_parents(L, src, mode="hybrid",
+                              backend="pallas-interpret")
+    ref = csg.shortest_path(to_scipy(g), method="D", indices=src)
+    fin = ~np.isinf(ref)
+    assert fin.sum() > 1
+    assert np.array_equal(np.isinf(r["dist"]), ~fin)
+    np.testing.assert_allclose(r["dist"][fin], ref[fin], atol=1e-4)
+    for v in np.nonzero(fin)[0]:
+        p = r["parent"][v]
+        if v == src:
+            assert p == src
+            continue
+        nbrs = slice(g.indptr[p], g.indptr[p + 1])
+        w = g.weights[nbrs][g.indices[nbrs] == v]
+        assert len(w) and abs(r["dist"][p] + w.min() - r["dist"][v]) < 1e-3
 
 
 def test_supported_matrix():
-    assert set(registry.supported("cpu", "gather", "add", jnp.float32)) \
+    assert set(registry.supported("cpu", "fused_dc", "add", jnp.float32)) \
         == {"ref", "pallas-interpret"}
-    assert set(registry.supported("tpu", "gather", "add", jnp.float32)) \
+    assert set(registry.supported("tpu", "fused_dc", "add", jnp.float32)) \
         == {"ref", "pallas-interpret", "pallas-native"}
     assert set(registry.supported("cpu", "fold", "add", jnp.float32)) \
         == {"ref", "pallas-interpret"}
@@ -388,8 +422,10 @@ def test_supported_matrix():
     # packed uint64 folds stay ref-only (no 8-byte Pallas lowering)
     assert registry.supported("cpu", "fold", "min_with_payload",
                               jnp.uint64) == ("ref",)
-    # spmv is an add/float kernel on every backend
-    assert registry.supported("cpu", "spmv", "min", jnp.float32) == ()
+    # the `or` monoid has no Pallas lowering either
+    assert registry.supported("tpu", "fused_dc", "or", jnp.uint32) \
+        == ("ref",)
+    assert registry.KERNELS == ("fold", "fused_dc")
 
 
 def test_tpu_defaults_follow_the_table(monkeypatch):
@@ -402,7 +438,7 @@ def test_tpu_defaults_follow_the_table(monkeypatch):
     # PageRank monoids, and pallas-native claims nothing off the TPU
     for kernel in registry.KERNELS:
         assert "pallas-native" in registry.supported(
-            "tpu", kernel, "min", jnp.uint32) or kernel == "spmv"
+            "tpu", kernel, "min", jnp.uint32)
         assert "pallas-native" not in registry.supported(
             "cpu", kernel, "min", jnp.uint32)
 
@@ -419,36 +455,25 @@ def test_tpu_fallback_to_ref_warns_even_for_defaults(monkeypatch):
         warnings.simplefilter("error")
         registry.resolve("fold", M.or_(), platform="cpu")
         for kernel in registry.KERNELS:
-            if kernel != "spmv":
-                assert registry.resolve(kernel, M.min_with_payload(),
-                                        platform="tpu").name == "ref"
+            assert registry.resolve(kernel, M.min_with_payload(),
+                                    platform="tpu").name == "ref"
         assert registry.default_backend_name(
             "tpu", "fold", "min_with_payload") == "ref"
     assert set(registry.TPU_REF_MONOIDS) == {"min_with_payload"}
 
 
-@pytest.mark.parametrize("kernel", ["gather", "fused_dc"])
+@pytest.mark.parametrize("kernel", ["fused_dc"])
 def test_batched_ref_fold_lane_chunks_agree(layout, rng, monkeypatch,
                                             kernel):
-    """The batched ref folds split lanes into memory-bounded chunks run
+    """The batched ref fold splits lanes into memory-bounded chunks run
     one after another; any chunking gives the one-chunk answer."""
-    from repro.kernels import ops as kops
     mono = M.min_(jnp.uint32)
-    ref = registry.BACKENDS["ref"]
     B, L = 5, layout
-    if kernel == "gather":
-        k = ref.gather(L, mono)
-        args = (jnp.asarray(rng.integers(0, 99, (B, L.num_edges))
-                            .astype(np.uint32)),
-                jnp.asarray(rng.random((B, L.num_edges)) < 0.6),
-                jnp.asarray((rng.random((B, L.k)) < 0.7).astype(np.int32)))
-        width = L.num_edges
-    else:
-        k = ref.fused_dc(L, mono)
-        args = (jnp.asarray(rng.integers(0, 99, (B, L.n_pad + 1))
-                            .astype(np.uint32)),
-                jnp.asarray(rng.random((B, L.n_pad + 1)) < 0.6))
-        width = L.num_edges
+    k = registry.BACKENDS["ref"].fused_dc(L, mono)
+    args = (jnp.asarray(rng.integers(0, 99, (B, L.n_pad + 1))
+                        .astype(np.uint32)),
+            jnp.asarray(rng.random((B, L.n_pad + 1)) < 0.6))
+    width = L.num_edges
     whole = jax.vmap(k)(*args)
     monkeypatch.setattr(kops, "LANE_CHUNK_ELEMS", 2 * width)
     chunked = jax.vmap(k)(*args)             # 3 chunks of 2, one padded
@@ -573,24 +598,25 @@ def test_check_bench_regression(tmp_path):
     spec.loader.exec_module(mod)
 
     # the guard must cover the over-cap two-level fold rows (fold2) and
-    # the fused DC step rows the same way it covers every other kernel row
-    kernels = ("gather", "scatter", "spmv", "fold", "fold2", "fused")
+    # the fused DC step rows the same way it covers the fold rows
+    rows = [(k, b) for b in ("ref", "pallas-interpret")
+            for k in ("fold", "fold2", "fused")]
 
     def doc(walls):
         return {"results": [
-            {"kernel": k, "backend": "ref", "monoid": "add", "scale": 6,
-             "wall_s": w} for k, w in zip(kernels, walls)]}
+            {"kernel": k, "backend": b, "monoid": "add", "scale": 6,
+             "wall_s": w} for (k, b), w in zip(rows, walls)]}
     flat = doc([0.010] * 6)
     assert mod.check(flat, flat, 2.0, 0.005) == 0
     # one kernel 3x while the rest hold: a real regression — including
     # when the regressed row is the two-level fold or the fused step
     assert mod.check(doc([0.030, 0.010, 0.010, 0.010, 0.010, 0.010]),
                      flat, 2.0, 0.005) == 1
-    assert mod.check(doc([0.010, 0.010, 0.010, 0.010, 0.030, 0.010]),
+    assert mod.check(doc([0.010, 0.030, 0.010, 0.010, 0.010, 0.010]),
                      flat, 2.0, 0.005) == 1
     assert mod.check(doc([0.010, 0.010, 0.010, 0.010, 0.010, 0.030]),
                      flat, 2.0, 0.005) == 1
-    # two of six kernels ~4x: the healthy rows must outvote them (a
+    # two of six rows ~4x: the healthy rows must outvote them (a
     # median calibration would forgive this as 'machine speed')
     assert mod.check(doc([0.039, 0.039, 0.010, 0.010, 0.010, 0.010]),
                      flat, 2.0, 0.005) == 1
@@ -600,7 +626,7 @@ def test_check_bench_regression(tmp_path):
     assert mod.check(doc([0.080] * 6), flat, 2.0, 0.005) == 1
     # sub-floor rows are dispatch jitter and never flag
     assert mod.check(doc([0.004] * 6), doc([0.001] * 6), 2.0, 0.005) == 0
-    other = {"results": [{"kernel": "spmv", "backend": "ref",
+    other = {"results": [{"kernel": "fold", "backend": "ref",
                           "monoid": "add", "scale": 8, "wall_s": 1.0}]}
     assert mod.check(flat, other, 2.0, 0.005) == 2              # no overlap
 
@@ -618,8 +644,7 @@ def test_bench_kernels_smoke(tmp_path):
     assert disk == doc
     assert disk["meta"]["platform"] == jax.default_backend()
     rows = disk["results"]
-    assert {r["kernel"] for r in rows} == {"gather", "scatter", "spmv",
-                                           "fold", "fold2", "fused"}
+    assert {r["kernel"] for r in rows} == {"fold", "fold2", "fused"}
     assert {r["backend"] for r in rows} == {"ref", "pallas-interpret"}
     assert all(r["wall_s"] > 0 for r in rows)
     assert all(r["fold_q"] > 0 for r in rows)

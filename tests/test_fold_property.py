@@ -136,18 +136,28 @@ def test_two_level_fold_out_of_range_ids_contribute_nothing():
         assert np.array_equal(np.asarray(touched), want > 0)
 
 
+@pytest.mark.parametrize("dtype", ["uint32", "int32", "float32"])
 @pytest.mark.parametrize("monoid", ["add", "min", "max"])
-def test_two_level_fold_chunks_a_long_stream(monoid, monkeypatch):
+def test_two_level_fold_chunks_a_long_stream(monoid, dtype, monkeypatch):
     """A stream whose work list would overflow SMEM folds chunk by chunk
-    (here 3 chunks of 2 message blocks) and still equals the oracle."""
+    (here 3 chunks of 2 message blocks) and still equals the oracle, for
+    each 4-byte dtype the kernels fold: uint32 through the sign-flipped
+    view, int32 over its whole range, float32 on integer values (exact
+    in any order)."""
     from kernel_harness import MONOIDS
     from repro.kernels import fold_two_level as ftl
-    mono = MONOIDS[(monoid, "uint32")]()
+    mono = MONOIDS[(monoid, dtype)]()
     ns, q, tile = 1000, 64, 64              # 16 buckets, 1024-message blocks
     monkeypatch.setattr(ftl, "WORK_LIST_SMEM", 16 * (16 + 2))
     rng = np.random.default_rng(7)
     n = 5000 + ["add", "min", "max"].index(monoid)   # a fresh trace each
-    vals = jnp.asarray(rng.integers(0, 2**32, n, dtype=np.uint32))
+    if dtype == "float32":
+        vals = jnp.asarray(rng.integers(-64, 64, n).astype(np.float32))
+    else:
+        info = np.iinfo(dtype)
+        vals = jnp.asarray(rng.integers(info.min, info.max, n,
+                                        dtype=np.dtype(dtype),
+                                        endpoint=True))
     valid = jnp.asarray(rng.random(n) < 0.8)
     ids = jnp.asarray(rng.integers(-2, ns + 2, n).astype(np.int32))
     ok = valid & (ids >= 0) & (ids < ns)

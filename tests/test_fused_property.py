@@ -1,11 +1,11 @@
 """Property tests (hypothesis): the fused scatter→fold DC step.
 
-Registry kernel ``fused_dc`` (:mod:`repro.kernels.fused_step`) replaces
-the composed scatter → slot gather → segmented fold of the DC stream
-with one Pallas launch.  Its contract must be BIT-exact against both the
-pure-jnp oracle (``ref_fused_scatter_fold``, what the ``ref`` backend
-registers) and the hand-composed gather→fold through the existing fold
-kernels, for ANY graph-shaped input: duplicate source slots, empty
+Registry kernel ``fused_dc`` (:mod:`repro.kernels.fused_step`) is the
+engines' one DC lowering: a table gather and one Pallas fold launch.
+Its contract must be BIT-exact against both the pure-jnp oracle
+(``ref_fused_scatter_fold``, what the ``ref`` backend registers) and an
+explicit gather→fold through the fold kernels, for ANY graph-shaped
+input: duplicate source slots, empty
 frontiers (all table slots invalid), all-invalid edge tiles, over-cap
 segment spaces (``ns > REPRO_FOLD_MAX_SEGMENTS``), non-power-of-two
 ``fold_q``, and edge streams that do not divide the edge tile.
@@ -15,9 +15,9 @@ comparator come from the shared differential harness
 (``tests/kernel_harness.py``); payloads are integer-valued so even the
 f32 add fold is exact and every comparison is bit-for-bit.
 
-Engine-level parity (``REPRO_FUSED=1`` vs ``0``) and the 2-device
-shard_map leg mirror ``test_apps_overcap.py``: exact for the
-order-independent CC min-monoid.
+Engine level: BFS, SSSP and CC through both engines against the scipy
+oracles, the add monoid through ``run_fused`` against numpy, and the
+partition mask of the DC stream.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -31,7 +31,7 @@ from kernel_harness import (NS_Q_PAIRS, NUM_SEGMENTS, assert_kernel_equiv,
                             segment_oracle)
 from repro.backend import registry
 from repro.kernels.fold_two_level import two_level_segment_fold
-from repro.kernels.fused_step import (ENV_FUSED, fused_scatter_fold,
+from repro.kernels.fused_step import (fused_scatter_fold,
                                       ref_fused_scatter_fold)
 
 EDGE_TILES = (8, 16)
@@ -83,7 +83,7 @@ def test_fused_presorted_matches_ref_oracle(data):
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_fused_matches_composed_gather_fold_overcap(data):
-    """fused ≡ the composed lowering it replaces (explicit table gather,
+    """fused ≡ the same dataflow spelled out (an explicit table gather,
     then the two-level fold kernel), across the over-cap NS_Q_PAIRS —
     the regime where both sides run the bucketed grid."""
     monoid, dtype, mono = draw_monoid(data)
@@ -178,104 +178,138 @@ def test_fused_out_of_range_dst_contributes_nothing():
 
 
 # ----------------------------------------------------------------------
-# engine-level parity: REPRO_FUSED=1 vs =0 must be invisible to results
+# engine level: the DC step against the graph oracles
 # ----------------------------------------------------------------------
 
-
-def _cc_labels(layout, mode):
-    from repro.apps.cc import connected_components
-    return connected_components(layout, mode=mode)["label"]
-
-
-def test_engine_fused_parity_cc(monkeypatch):
-    """Core engine: the fused DC lowering and the composed path produce
-    bit-identical CC labels (min/uint32 is order-independent), in pure-DC
-    and hybrid modes.  REPRO_FUSED is read at Engine construction, so
-    flipping the env between runs flips the lowering."""
-    from repro.graph import build_layout, rmat
-    g = rmat(7, 8, seed=3)
-    L = build_layout(g, k=4, edge_tile=32, msg_tile=16)
-    for mode in ("dc", "hybrid"):
-        monkeypatch.setenv(ENV_FUSED, "1")
-        fused = _cc_labels(L, mode)
-        monkeypatch.setenv(ENV_FUSED, "0")
-        composed = _cc_labels(L, mode)
-        assert np.array_equal(fused, composed)
+@pytest.fixture(scope="module")
+def oracle_graphs():
+    """A weighted rmat graph and its symmetrized view, each with a
+    layout, and the source BFS and SSSP start from (the top-degree
+    vertex, so a hybrid run takes DC supersteps too)."""
+    from repro.graph import build_layout, rmat, symmetrize
+    g = rmat(7, 8, seed=3, weighted=True)
+    gs = symmetrize(g)
+    return {"g": g, "L": build_layout(g, k=4, edge_tile=32, msg_tile=16),
+            "gs": gs, "Ls": build_layout(gs, k=4, edge_tile=32,
+                                         msg_tile=16),
+            "src": int(np.argmax(g.out_degrees()))}
 
 
-def test_engine_fused_parity_add_monoid(monkeypatch):
-    """Add-monoid parity through run_fused (PageRank's fixed-iteration DC
-    loop): integer-valued f32 payloads keep the sum exact under either
-    reduction order, so the comparison is bit-for-bit."""
-    import jax
-    from repro.core.engine import Engine
-    from repro.core.program import VertexProgram
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+@pytest.mark.parametrize("mode", ["dc", "hybrid"])
+@pytest.mark.parametrize("app", ["bfs", "sssp", "cc"])
+def test_engine_dc_matches_reference(oracle_graphs, app, mode, backend):
+    """BFS levels, SSSP distances and CC components through ``Engine``
+    with the DC step of each backend equal scipy's, in pure-DC and
+    hybrid modes; every run takes at least one DC superstep."""
+    import scipy.sparse.csgraph as csg
+    from repro.apps import bfs, connected_components, sssp
+    from repro.graph import to_scipy
+    G, src = oracle_graphs, oracle_graphs["src"]
+    if app == "bfs":
+        res = bfs(G["L"], src, mode=mode, backend=backend)
+        d = csg.shortest_path(to_scipy(G["g"]), method="D",
+                              unweighted=True, indices=src)
+        assert np.array_equal(res["level"],
+                              np.where(np.isinf(d), -1, d).astype(int))
+    elif app == "sssp":
+        res = sssp(G["L"], src, mode=mode, backend=backend)
+        d = csg.shortest_path(to_scipy(G["g"]), method="D", indices=src)
+        fin = ~np.isinf(d)
+        assert np.array_equal(np.isinf(res["dist"]), ~fin)
+        np.testing.assert_allclose(res["dist"][fin], d[fin], atol=1e-4)
+    else:
+        res = connected_components(G["Ls"], mode=mode, backend=backend)
+        ncc, want = csg.connected_components(to_scipy(G["gs"]),
+                                             directed=False)
+        label = res["label"]
+        assert len(np.unique(label)) == ncc > 1
+        for comp in range(ncc):
+            assert len(np.unique(label[want == comp])) == 1
+    assert any(s.dc_parts > 0 for s in res["stats"])
+
+
+def _sumprop():
+    """An add program: every touched vertex adds its in-neighbours'
+    values to its own."""
     from repro.core import monoid as M
-    from repro.graph import build_layout, rmat
-
-    def scatter_fn(state):
-        return state["x"]
+    from repro.core.program import VertexProgram
 
     def apply_fn(state, acc, touched, it):
         x = jnp.where(touched, state["x"] + acc, state["x"])
         return dict(state, x=x), touched
 
-    prog = VertexProgram(name="sumprop", monoid=M.add(jnp.float32),
-                         scatter_fn=scatter_fn, apply_fn=apply_fn)
+    return VertexProgram(name="sumprop", monoid=M.add(jnp.float32),
+                         scatter_fn=lambda s: s["x"], apply_fn=apply_fn)
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+def test_run_fused_add_monoid_matches_numpy(backend):
+    """``run_fused`` (PageRank's fixed-iteration DC loop) with the add
+    monoid: two rounds of in-neighbour sums from the frontier equal
+    numpy's, bit for bit (integer-valued f32 payloads keep every sum
+    exact)."""
+    from repro.core.engine import Engine
+    from repro.graph import build_layout, rmat
     g = rmat(6, 8, seed=2)
     L = build_layout(g, k=4, edge_tile=32, msg_tile=16)
     rng = np.random.default_rng(0)
-    x0 = jnp.asarray(rng.integers(0, 8, L.n_pad).astype(np.float32))
+    x0 = rng.integers(0, 8, L.n_pad).astype(np.float32)
     frontier = np.zeros(L.n_pad, bool)
     frontier[:L.n] = True
+    eng = Engine(L, _sumprop(), mode="dc", backend=backend)
+    assert eng.backend_names["fused_dc"] == backend
+    state, _ = eng.run_fused({"x": jnp.asarray(x0)}, frontier, iters=2)
+    src = np.repeat(np.arange(g.n), g.out_degrees())
+    want, active = x0.astype(np.float64), frontier
+    for _ in range(2):
+        # only active sources send; a vertex they reach is touched, adds
+        # their sum and is the next round's frontier
+        live = active[src]
+        acc = np.zeros(L.n_pad)
+        np.add.at(acc, g.indices[live], want[src[live]])
+        active = np.zeros(L.n_pad, bool)
+        active[g.indices[live]] = True
+        want = np.where(active, want + acc, want)
+    assert np.array_equal(np.asarray(state["x"]), want.astype(np.float32))
 
-    outs = {}
-    for flag in ("1", "0"):
-        monkeypatch.setenv(ENV_FUSED, flag)
-        eng = Engine(L, prog, mode="dc")
-        assert (eng._fused is not None) == (flag == "1")
-        state, _ = eng.run_fused({"x": x0}, frontier, iters=2)
-        outs[flag] = np.asarray(state["x"])
-    assert np.array_equal(outs["1"], outs["0"])
 
-
-@pytest.mark.slow
-def test_dist_cc_fused_parity_shard_map(monkeypatch):
-    """The fused kernel must trace inside shard_map: CC through DistEngine
-    on 2 virtual devices with the fold cap lowered (over-cap two-level
-    regime), REPRO_FUSED=1 vs =0 bit parity."""
+def test_dist_cc_matches_scipy_shard_map():
+    """The stream DC step traces inside shard_map: CC through
+    ``DistEngine`` on 2 virtual devices, with the fold cap lowered (the
+    over-cap two-level regime), finds scipy's components."""
     import os
     import subprocess
     import sys
     import textwrap
     code = """
-    import os
     import jax
     import numpy as np
     import jax.numpy as jnp
+    import scipy.sparse.csgraph as csg
     from jax.sharding import AxisType
-    from repro.graph import rmat, build_layout
+    from repro.graph import rmat, build_layout, symmetrize, to_scipy
     from repro.graph.shard import shard_layout
     from repro.dist.engine import DistEngine
     from repro.apps.cc import cc_program
     D = 2
     mesh = jax.make_mesh((D,), ("dev",), axis_types=(AxisType.Auto,))
-    g = rmat(8, 8, seed=5)
+    g = symmetrize(rmat(8, 8, seed=5))
     L = build_layout(g, k=4, edge_tile=64, msg_tile=32)
     SL = shard_layout(L, D)
     assert SL.nv + 1 > 16          # cap lowered to 16 via env below
     N = D * SL.nv
-    outs = {}
-    for flag in ("1", "0"):
-        os.environ["REPRO_FUSED"] = flag
-        eng = DistEngine(SL, cc_program(), mesh, mode="dc")
-        assert (eng.fused_backend_name is not None) == (flag == "1")
-        label = jnp.arange(N, dtype=jnp.uint32)
-        frontier = np.zeros(N, bool); frontier[:g.n] = True
-        state, _, _ = eng.run({"label": label}, frontier)
-        outs[flag] = np.asarray(state["label"])[:g.n]
-    assert np.array_equal(outs["1"], outs["0"])
-    print("dist fused parity ok")
+    eng = DistEngine(SL, cc_program(), mesh, mode="dc")
+    assert eng.fused_backend_name == "ref"
+    label = jnp.arange(N, dtype=jnp.uint32)
+    frontier = np.zeros(N, bool); frontier[:g.n] = True
+    state, _, _ = eng.run({"label": label}, frontier)
+    label = np.asarray(state["label"])[:g.n]
+    ncc, want = csg.connected_components(to_scipy(g), directed=False)
+    assert len(np.unique(label)) == ncc > 1
+    for comp in range(ncc):
+        assert len(np.unique(label[want == comp])) == 1
+    print("dist cc ok")
     """
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ,
@@ -284,12 +318,47 @@ def test_dist_cc_fused_parity_shard_map(monkeypatch):
                REPRO_FOLD_MAX_SEGMENTS="16",
                PYTHONPATH=os.path.join(repo, "src"))
     env.pop("REPRO_KERNEL_BACKEND", None)
-    env.pop("REPRO_FUSED", None)
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=env,
                        timeout=600)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
-    assert "dist fused parity ok" in r.stdout
+    assert "dist cc ok" in r.stdout
+
+
+@pytest.mark.parametrize("monoid", ["add", "min", "max"])
+def test_dc_phase_ignores_non_dc_partitions(oracle_graphs, monoid):
+    """``Engine._dc_phase`` with a ``dc_mask`` over every other
+    partition folds exactly the active sources of those partitions:
+    ``acc`` and ``touched`` equal the segment fold of their edges
+    alone (:func:`repro.kernels.ref.segment_combine_ref`)."""
+    import jax
+    from repro.core import monoid as M
+    from repro.core.engine import Engine
+    from repro.core.program import VertexProgram
+    from repro.kernels import ref as kref
+    L = oracle_graphs["L"]
+    mono = {"add": M.add, "min": M.min_, "max": M.max_}[monoid](jnp.int32)
+    prog = VertexProgram(name=f"fold_{monoid}", monoid=mono,
+                         scatter_fn=lambda s: s["x"],
+                         apply_fn=lambda s, acc, t, it: (s, t))
+    rng = np.random.default_rng(4)
+    x = rng.integers(-64, 64, L.n_pad).astype(np.int32)
+    active = rng.random(L.n_pad) < 0.7
+    dc_mask = np.arange(L.k) % 2 == 0
+    eng = Engine(L, prog, mode="dc", backend="pallas-interpret")
+    _, _, acc, touched, _ = jax.jit(eng._dc_phase)(
+        {"x": jnp.asarray(x)}, jnp.asarray(active), jnp.asarray(dc_mask),
+        0, eng._args())
+    src = np.asarray(L.png_src)[np.asarray(L.msg_slot).clip(
+        0, L.num_msgs - 1)]
+    src = np.clip(src, 0, L.n_pad - 1)
+    keep = (L.edge_valid & active[src] & dc_mask[src // L.q])
+    racc, rtouched = kref.segment_combine_ref(
+        jnp.asarray(x[src]), jnp.asarray(keep), jnp.asarray(L.edge_dst),
+        L.n_pad + 1, monoid)
+    assert np.asarray(rtouched).any()
+    assert np.array_equal(np.asarray(touched), np.asarray(rtouched))
+    assert np.array_equal(np.asarray(acc), np.asarray(racc))
 
 
 # ----------------------------------------------------------------------
@@ -452,7 +521,6 @@ def test_engine_reports_one_gather_on_every_dc_superstep(
     from repro import obs
     from repro.apps.bfs import bfs
     from repro.apps.pagerank import pagerank
-    monkeypatch.setenv(ENV_FUSED, "1")
     L = one_gather_layout
     out, paths = {}, {}
     for backend in ("pallas-interpret", "ref"):
@@ -490,7 +558,6 @@ def test_engine_identity_message_takes_two_gathers(one_gather_layout,
 
     prog = VertexProgram(name="sumprop", monoid=M.add(jnp.float32),
                          scatter_fn=lambda s: s["x"], apply_fn=apply_fn)
-    monkeypatch.setenv(ENV_FUSED, "1")
     L = one_gather_layout
     rng = np.random.default_rng(0)
     x0 = rng.integers(1, 8, L.n_pad).astype(np.float32)
@@ -521,7 +588,7 @@ def _unordered_fused(table, table_valid, idx, edge_valid, dst, ns, monoid,
     indices wrapped for negative values."""
     import jax
     from repro.kernels.fused_step import _bits
-    from repro.kernels.segment_combine import _identity_val
+    from repro.kernels.fold_block import _identity_val
     idx = jnp.clip(idx.astype(jnp.int32), 0, table.shape[0] - 1)
     table_valid = table_valid.astype(bool)
     ident = jnp.asarray(_identity_val(monoid, table.dtype))
@@ -552,7 +619,6 @@ def test_dc_phase_matches_unordered_table_construction(
     from repro.apps.bfs import bfs_program
     from repro.apps.pagerank import pagerank_program
     from repro.core.engine import Engine
-    monkeypatch.setenv(ENV_FUSED, "1")
     L = one_gather_layout
     rng = np.random.default_rng(1)
     sources = np.nonzero(L.deg[:L.n] > 0)[0][::5]

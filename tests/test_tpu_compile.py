@@ -1,7 +1,7 @@
 """Compile, for a described TPU v5e with no chip attached, every kernel
 the TPU table (``repro.backend.registry.TPU_DEFAULTS``) selects for
-``chip_smoke.py``'s phases, at the shapes of its scale-22 graph, and the
-Mosaic kernels off that path where a partition fits their one-hot.
+``chip_smoke.py``'s phases, at the shapes of its scale-22 graph, and
+every Mosaic kernel at a smaller graph's shapes.
 
 Nothing runs: the TPU compiler is asked to accept each program and to
 fit it in one chip's memory.  What it refuses here (block shapes against
@@ -27,12 +27,9 @@ from repro.backend import registry
 from repro.core import monoid as M
 from repro.core.engine import Engine
 from repro.kernels import ops as kops
-from repro.kernels.dc_gather import dc_gather
 from repro.kernels.fold_block import blocked_segment_fold
 from repro.kernels.fold_two_level import two_level_segment_fold
 from repro.kernels.fused_step import fused_scatter_fold
-from repro.kernels.segment_combine import segment_combine
-from repro.kernels.spmv_block import spmv_block
 
 #: chip_smoke.py's graph: 2^22 vertices, ~2^27 directed edges after
 #: symmetrizing (layout padding included), 8 serving lanes
@@ -280,41 +277,35 @@ def test_batched_dc_fused_ref_fits_one_chip(one_chip):
     assert mem.temp_size_in_bytes < 1.5 * one.temp_size_in_bytes
 
 
-#: a layout whose partitions a one-hot can span: k = 2048 partitions of
-#: q = 2048 vertices, 1024-edge tiles (XLA tiles 1-D arrays by 1024)
-K_SMALL, Q_SMALL, TILE = 2048, 2048, 1024
+#: a smaller graph: k = 2048 partitions of q = 2048 vertices, 2^20 edges
+K_SMALL, Q_SMALL = 2048, 2048
 
 
-@pytest.mark.parametrize("kernel", ["gather", "scatter", "spmv", "fold"])
+@pytest.mark.parametrize("kernel", ["fused_dc-add", "fused_dc-min",
+                                    "fused_dc-max", "fold"])
 def test_mosaic_kernels_compile_at_small_partitions(one_chip, kernel):
-    """Every Pallas kernel Mosaic lowers, off the table's default path:
-    the composed DC kernels where a partition fits their one-hot, and the
-    flat fold at its segment cap.  Each would have been refused before
-    (rank-1 and (1, q) blocks, bool relayouts, vector gathers)."""
+    """Every Pallas kernel Mosaic lowers, at a smaller graph's shapes:
+    the DC step for each monoid it folds (f32 add, u32 min, i32 max)
+    over ``K_SMALL * Q_SMALL`` vertices, and the flat fold at its
+    segment cap.  Each would have been refused before (rank-1 and
+    (1, q) blocks, bool relayouts, vector gathers)."""
     S = _spec(one_chip)
-    ne, nt = 1 << 20, (1 << 20) // TILE
-    i32, u32, f32 = jnp.int32, jnp.uint32, jnp.float32
-    edges, tiles = S((ne,), i32), S((nt,), i32)
-    fn, args = {
-        "gather": (functools.partial(
-            segment_combine, k=K_SMALL, q=Q_SMALL, edge_tile=TILE,
-            monoid="min", interpret=False),
-            (S((ne,), u32), edges, edges, tiles, tiles, tiles,
-             S((K_SMALL,), i32))),
-        "scatter": (functools.partial(
-            dc_gather, k=K_SMALL, q=Q_SMALL, msg_tile=TILE, monoid="min",
-            interpret=False),
-            (S((K_SMALL, Q_SMALL), u32), S((K_SMALL, Q_SMALL), i32), edges,
-             edges, tiles)),
-        "spmv": (functools.partial(
-            spmv_block, k=K_SMALL, q=Q_SMALL, edge_tile=TILE, weighted=True,
-            interpret=False),
-            (S((K_SMALL * Q_SMALL,), f32), edges, edges, edges,
-             S((ne,), f32), tiles, tiles, tiles)),
-        "fold": (functools.partial(
+    ne, nv = 1 << 20, K_SMALL * Q_SMALL
+    i32, f32 = jnp.int32, jnp.float32
+    edges = S((ne,), i32)
+    if kernel == "fold":
+        fn = functools.partial(
             blocked_segment_fold, num_segments=4096, monoid="add",
-            fold_tile=FOLD_TILE, interpret=False),
-            (S((ne,), f32), S((ne,), jnp.bool_), edges)),
-    }[kernel]
+            fold_tile=FOLD_TILE, interpret=False)
+        args = (S((ne,), f32), S((ne,), jnp.bool_), edges)
+    else:
+        monoid = kernel.split("-")[1]
+        dtype = {"add": f32, "min": jnp.uint32, "max": i32}[monoid]
+        fn = functools.partial(
+            fused_scatter_fold, num_segments=nv + 1, monoid=monoid,
+            edge_tile=FOLD_TILE, fold_q=FOLD_Q, interpret=False,
+            presorted=True)
+        args = (S((nv + 1,), dtype), S((nv + 1,), jnp.bool_), edges, edges,
+                edges)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
